@@ -1,5 +1,11 @@
 """Distributed pencil-FFT scaling sweep — packed/overlapped vs serial.
 
+A CPU tool: it measures nothing on a chip.  Each point runs in a child
+process forced to ``JAX_PLATFORMS=cpu`` with fake host devices, so its
+times are XLA-on-CPU times, and a chip held by this process would never
+reach the children.  The on-chip pencil path is ``chip_smoke.py
+--four-chips``.
+
 Runs the pencil path at 8/16/32/48 fake devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=N``, the
 test-suite's subprocess idiom, one fresh process per point so the device
@@ -64,11 +70,11 @@ spec = P("b", "x")
 
 
 def make(**kw):
-    fn = D.shard_map_compat(
+    fn = jax.shard_map(
         lambda xr, xi: D.pfft(
             xr, xi, n=n, axis_name="x", num_shards=d, **kw
         ),
-        mesh, in_specs=(spec, spec), out_specs=(spec, spec))
+        mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec), check_vma=False)
     return jax.jit(fn)
 
 

@@ -11,6 +11,8 @@ Emits ``name,...`` CSV rows (paper-table stand-ins documented per module).
 
 import sys
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 from benchmarks import (
     bench_bluestein,
     bench_fftconv,
@@ -56,6 +58,7 @@ SMOKE_SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     args = sys.argv[1:]
     smoke = "--smoke" in args
     picks = [a for a in args if a in SUITES] or list(SUITES)

@@ -56,7 +56,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import faults
 from repro.core import fft as fft_lib
@@ -76,7 +76,6 @@ __all__ = [
     "pifft_sharded",
     "pfft2d",
     "pconv_os_sharded",
-    "shard_map_compat",
 ]
 
 
@@ -650,29 +649,28 @@ def pfft2d(
     return xr, xi
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: ``jax.shard_map``/``check_vma`` on new JAX,
-    ``jax.experimental.shard_map``/``check_rep`` on older releases (including
-    the window where ``jax.shard_map`` exists but still takes ``check_rep``)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+def _auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  ``jax.make_mesh`` builds
+    ``Explicit`` axes, under which the shard_map results carry mesh-typed
+    shardings that eager code and autodiff outside a ``jax.set_mesh``
+    context reject; the pencil drivers place their own shardings, so they
+    run under ``Auto`` axes whatever mesh the caller built."""
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
 
 
 def _shard_wrap(fn, mesh: Mesh, axis: str):
+    mesh = _auto_mesh(mesh)
+
     def wrapper(xr, xi, **kw):
         nbatch = xr.ndim - 1
         pspec = P(*([None] * nbatch + [axis]))
         f = functools.partial(fn, axis_name=axis, **kw)
-        return shard_map_compat(
-            f, mesh, in_specs=(pspec, pspec), out_specs=(pspec, pspec)
+        return jax.shard_map(
+            f, mesh=mesh, in_specs=(pspec, pspec), out_specs=(pspec, pspec),
+            check_vma=False,
         )(xr, xi)
 
     return wrapper
@@ -806,8 +804,9 @@ def pconv_os_sharded(
     def body(fr, hr, hi):
         return ov.conv_frames(fr, hr, hi, overlap=overlap, backend=backend)
 
-    tails = shard_map_compat(
-        body, mesh, in_specs=(fspec, P(), P()), out_specs=fspec
+    tails = jax.shard_map(
+        body, mesh=_auto_mesh(mesh), in_specs=(fspec, P(), P()), out_specs=fspec,
+        check_vma=False,
     )(frames, Hr, Hi)
     lead = tails.shape[:-2]
     y = tails.reshape(*lead, nb * step)[..., :L_out]

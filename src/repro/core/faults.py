@@ -24,12 +24,14 @@ Fault-injection registry
     raises that site's typed error with ``injected=True``.
 
 Quarantine + degradation ledger
-    ``run_leaf`` wraps a claimed pallas/pallas_gpu leaf: one retry on
-    failure, then the failing ``(backend, pass-kind)`` pair is quarantined
-    for the rest of the process and the leaf executes through its traced
-    XLA fallback.  Each demotion is recorded on the owning plan's
+    ``run_leaf`` wraps a claimed pallas/pallas_gpu leaf.  An injected
+    fault gets one retry, then the ``(backend, pass-kind)`` pair is
+    quarantined for the rest of the process and the leaf executes through
+    its traced XLA fallback; each demotion is recorded on the owning plan's
     ``degradations`` list and in a process-global ledger surfaced by
-    ``ServeSession.health()``.
+    ``ServeSession.health()``.  A real kernel failure (a compiler refusal,
+    say) is never demoted: it raises ``KernelError`` with the compiler's
+    message, so no run silently times XLA in place of its kernels.
 
 Everything here is host-side Python: injection fires at trace time, never
 inside a jitted computation, so the no-fault jaxpr is byte-identical to a
@@ -328,15 +330,24 @@ def run_leaf(
     degradations: Optional[list] = None,
     index: Optional[int] = None,
 ):
-    """Execute one claimed pallas leaf with retry → quarantine → fallback.
+    """Execute one claimed pallas leaf; demote it only for injected faults.
 
     The happy path is ``attempt()`` guarded only by host-side Python — a
     dict probe and a try — so the traced jaxpr is identical to calling
-    ``attempt()`` directly.  On failure the leaf is retried once (a fault
-    armed with ``times=1`` recovers here with no degradation); a second
-    failure quarantines ``(backend, kind)`` for the process, records the
-    demotion, and runs ``fallback()`` — the traced XLA execution of the
-    same pass, numerically equivalent at float32 tolerance.
+    ``attempt()`` directly.
+
+    An injected fault (``injected=True``, armed through
+    :func:`inject_fault` or ``REPRO_FAULTS``) takes the drill path: the
+    leaf is retried once (a fault armed with ``times=1`` recovers here with
+    no degradation); a second injected failure quarantines
+    ``(backend, kind)`` for the process, records the demotion, and runs
+    ``fallback()`` — the traced XLA execution of the same pass.
+
+    Any other failure — a compiler refusal, a lowering error, a launch
+    error — raises :class:`KernelError` at once, carrying the original
+    message.  A kernel that does not compile is deterministic, so a retry
+    would only repeat it, and an XLA fallback would hide the device: a run
+    that exits 0 has run its kernels.
     """
     if is_quarantined(backend, kind):
         record_degradation(
@@ -346,18 +357,30 @@ def run_leaf(
     try:
         maybe_fail("kernel.launch", backend=backend, pass_kind=kind)
         return attempt()
-    except NotImplementedError:
-        raise  # a contract gate, not a kernel failure — never demote it
-    except Exception:
-        try:
-            maybe_fail("kernel.launch", backend=backend, pass_kind=kind)
-            return attempt()
-        except NotImplementedError:
-            raise
-        except Exception as err:  # second strike: demote this leaf for good
-            reason = f"{type(err).__name__}: {err}"
-            quarantine(backend, kind, reason)
-            record_degradation(
-                degradations, backend=backend, kind=kind, index=index, reason=reason
-            )
-            return fallback()
+    except Exception as err:
+        if not getattr(err, "injected", False):
+            _raise_kernel_error(err, backend, kind)
+    try:
+        maybe_fail("kernel.launch", backend=backend, pass_kind=kind)
+        return attempt()
+    except Exception as err:
+        if not getattr(err, "injected", False):
+            _raise_kernel_error(err, backend, kind)
+        # Second injected strike: demote this leaf for good.
+        reason = f"{type(err).__name__}: {err}"
+        quarantine(backend, kind, reason)
+        record_degradation(
+            degradations, backend=backend, kind=kind, index=index, reason=reason
+        )
+        return fallback()
+
+
+def _raise_kernel_error(err: Exception, backend: str, kind: str):
+    """Re-raise a real leaf failure: contract gates (``NotImplementedError``)
+    and typed engine errors as they are, anything else as
+    :class:`KernelError` with the original message."""
+    if isinstance(err, (NotImplementedError, ReproError)):
+        raise err
+    raise KernelError(
+        f"{type(err).__name__}: {err}", backend=backend, pass_kind=kind
+    ) from err

@@ -1239,6 +1239,8 @@ def _build_plan(
             cfg.get("direct_max", plan_lib.DIRECT_MAX) if cfg else plan_lib.DIRECT_MAX,
             pad=cfg.get("bluestein_pad") if cfg else None,
         )
+        if entry.name == "pallas" and platform == "tpu":
+            plan_lib.check_tpu_vmem(fft_plan.passes)
         return PlannedFFT(
             spec,
             entry,
@@ -1261,6 +1263,8 @@ def _build_plan(
             cfg["fused_max"] if cfg else plan_lib.FUSED_MAX,
             cfg.get("direct_max", plan_lib.DIRECT_MAX) if cfg else plan_lib.DIRECT_MAX,
         )
+        if entry.name == "pallas" and platform == "tpu":
+            plan_lib.check_tpu_vmem(fft_plan.passes)
         return PlannedFFT(
             spec,
             entry,

@@ -20,6 +20,10 @@ __all__ = [
     "FUSED_MAX",
     "OS_FACTOR",
     "VMEM_BUDGET",
+    "VMEM_LIMIT",
+    "SUBLANES",
+    "LANES",
+    "row_group",
     "GPU_SMEM_BUDGETS",
     "GPU_SMEM_DEFAULT",
     "BLUESTEIN_MIN",
@@ -33,9 +37,8 @@ __all__ = [
 DIRECT_MAX = 1024
 
 #: Largest N executed by the fused four-step kernel in one HBM round trip.
-#: 65536 = 256·256 keeps the per-block working set (signal tile + two DFT
-#: matrices + twiddle grid + scratch) under ~6 MB of VMEM — see
-#: :func:`repro.core.plan.vmem_bytes`.
+#: 65536 = 256·256: an 8-row tile of it compiles for v5e within
+#: :data:`VMEM_LIMIT` — see :func:`repro.core.plan.vmem_bytes`.
 FUSED_MAX = 65536
 
 #: Default overlap-save block multiplier: B = next_pow2(Lh) · OS_FACTOR.
@@ -45,10 +48,37 @@ FUSED_MAX = 65536
 #: This is the fixed heuristic ``tune="measure"`` searches past.
 OS_FACTOR = 8
 
-#: Per-grid-step VMEM working-set budget: half of the ~16 MB per core,
-#: leaving room for Mosaic's double buffering.  Binds the batch-tile and
-#: pass-chunk picks (and the tuner's candidate feasibility check).
-VMEM_BUDGET = 8 * 1024 * 1024
+#: Scoped-VMEM limit every TPU kernel is compiled with
+#: (``pltpu.CompilerParams(vmem_limit_bytes=...)``).  The compiler's default
+#: scoped limit is 16 MiB; a v5e core has 128 MiB of VMEM, and the rest is
+#: left to Mosaic's internal scratch.
+VMEM_LIMIT = 96 * 1024 * 1024
+
+#: Per-grid-step VMEM working-set budget for the modeled working set of
+#: :func:`repro.core.plan.vmem_bytes` / ``_pass_chunk_bytes``, which are
+#: calibrated against the scoped-VMEM sizes the v5e Mosaic compiler reports
+#: for each kernel.  Binds the batch-tile and pass-chunk picks (and the
+#: tuner's candidate feasibility check); a third of :data:`VMEM_LIMIT`, so
+#: a model error of 3x still compiles, and tiles stay small enough that
+#: Mosaic compiles each kernel in seconds (its code grows with the tile).
+VMEM_BUDGET = VMEM_LIMIT // 3
+
+#: The TPU's (sublane, lane) vector tile.  A Pallas block's last two dims
+#: must be multiples of these or the array's own dims, so batch tiles are
+#: at least ``SUBLANES`` rows and column-pass chunks at least ``LANES``
+#: columns, and a fused four-step leaf keeps its contiguous factor ``n2``
+#: at ``LANES`` or more (its in-kernel ``(bt, n1, n2)`` view splits lanes).
+SUBLANES = 8
+LANES = 128
+
+
+def row_group(n1: int) -> int:
+    """Signals per step of the four-step's row-group loop
+    (:func:`repro.kernels.fft4step.four_step_rows`, and the VMEM model of
+    :func:`repro.core.plan.vmem_bytes` calibrated against it): one once
+    ``n1`` fills a 128-row MXU operand, else ``SUBLANES`` batched through
+    the 3-D relayouts."""
+    return 1 if n1 >= LANES else SUBLANES
 
 #: Per-SM shared-memory budgets (bytes) for CUDA-class devices, keyed by a
 #: lowercase substring of ``jax.devices()[0].device_kind``.  These are the

@@ -2,7 +2,8 @@
 
 §2.3.2/§3 of the paper fix the number of *global-memory round trips* by data
 volume: one kernel call for N ≤ 1024 (whole transform in shared memory), two
-for N ≤ 32768, three or more beyond.  Here the fast tier is VMEM (~16 MB) and
+for N ≤ 32768, three or more beyond.  Here the fast tier is VMEM (128 MiB on
+a v5e core, of which a kernel may take ``VMEM_LIMIT``) and
 the slow tier is HBM, so the same schedule becomes:
 
 * ``direct``   — N ≤ DIRECT_MAX: one ``pallas_call``, a single DFT matmul
@@ -48,9 +49,13 @@ from repro.core import faults
 from repro.core.limits import (
     DIRECT_MAX,
     FUSED_MAX,
+    LANES,
+    SUBLANES,
     VMEM_BUDGET,
+    VMEM_LIMIT,
     bluestein_pad,
     memory_budget,
+    row_group,
 )
 
 __all__ = [
@@ -72,6 +77,7 @@ __all__ = [
     "pass_other",
     "program_hbm_bytes",
     "pick_pass_chunk",
+    "check_tpu_vmem",
     "describe",
     "describe_program",
 ]
@@ -207,16 +213,27 @@ class FFTPlan:
         raise KeyError(f"length {m} is not a leaf of the plan for n={self.n}")
 
 
+def four_step_split(n: int) -> tuple[int, int]:
+    """The in-VMEM ``(n1, n2)`` view of a fused four-step leaf: as square
+    as possible, but with the contiguous factor ``n2`` at least one
+    ``LANES``-wide vector tile — Mosaic splits a tile's lanes into
+    ``(n1, n2)`` only along whole 128-lane rows."""
+    n1, n2 = balanced_split(n)
+    while n2 < LANES and n1 > SUBLANES:
+        n1, n2 = n1 // 2, n2 * 2
+    return n1, n2
+
+
 def _leaf_pass(n: int, direct_max: int = DIRECT_MAX) -> Pass:
     """The leaf engine decision: a direct DFT matmul up to ``direct_max``
     (one GEMM, but an n² LUT), the fused four-step beyond (two √n-sized
     GEMMs + twiddle).  ``direct_max`` is the tuner's engine knob — lowering
     it trades the big DFT matrix stream for four-step arithmetic on leaves
-    near the boundary.  Lengths below 8 stay direct (a four-step split
-    would degenerate)."""
-    if n <= max(direct_max, 8):
+    near the boundary.  Lengths below ``SUBLANES·LANES`` stay direct: a
+    four-step view needs ``n1 ≥ 8`` sublanes by ``n2 ≥ 128`` lanes."""
+    if n <= direct_max or n < SUBLANES * LANES:
         return Pass(kind="direct", n=n)
-    n1, n2 = balanced_split(n)
+    n1, n2 = four_step_split(n)
     return Pass(kind="fused4", n=n, n1=n1, n2=n2)
 
 
@@ -534,47 +551,58 @@ def plan_fft2(
     )
 
 
-def vmem_bytes(p: Pass, batch_tile: int) -> int:
-    """Estimated VMEM working set of one grid step of a leaf pass.
+def _lut_bytes(p: Pass) -> int:
+    """The transform LUTs a leaf keeps resident (split-complex float32)."""
+    if p.kind == "direct":
+        return p.n * p.n * 8
+    return (p.n1 * p.n1 + p.n2 * p.n2 + p.n1 * p.n2) * 8
 
-    Split-complex float32 everywhere: signal tile in + out, DFT matrices,
-    twiddle grid, one intermediate.  Used by the kernel launcher to pick the
-    batch tile so the block fits comfortably in ~16 MB of VMEM (we budget
-    half of it, leaving room for Mosaic's double buffering).
+
+def _row_group_bytes(p: Pass) -> int:
+    """In-kernel intermediates of the four-step's row-group loop
+    (:func:`repro.kernels.fft4step.four_step_rows`): ~12 group-sized
+    split-complex arrays (see :func:`~repro.core.limits.row_group`)."""
+    return 12 * row_group(p.n1) * p.n * 8
+
+
+def vmem_bytes(p: Pass, batch_tile: int) -> int:
+    """Modeled scoped VMEM of one grid step of a leaf pass.
+
+    Calibrated against the smallest ``vmem_limit_bytes`` under which the
+    v5e Mosaic compiler accepts each kernel (split-complex float32 tile
+    ``sig``): the in and out blocks are double-buffered (4·sig), the LUTs
+    are resident, and the body's intermediates come on top — whole-tile
+    GEMM temporaries for the direct DFT (measured 11.3 MiB at n=1024,
+    bt=64, model 11.5), one row group's relayouts for the four-step
+    (measured 21.2 MiB at n=65536, bt=8, model 23.5; 10.8 MiB at n=4096,
+    bt=64, model 11.2).  Used to pick the batch tile against
+    :data:`~repro.core.limits.VMEM_BUDGET`.
     """
-    f32 = 4
     if p.kind == "bluestein":
-        # The chirp leaf's working set is pad-sized: the padded signal tile
-        # in/mid/out, the inner pad-FFT's LUTs (fwd/inv stages only), and
-        # the (1, n)/(1, M) chirp planes.
+        # Pad-sized in/out tiles, the (1, n)/(1, M) chirp planes, and — for
+        # the fused fwd/inv stages — the inner pad-FFT's LUTs and row
+        # group (measured 8.4 MiB at n=12288 fwd, bt=8, model 12.3; 21.7
+        # MiB at n=40000 post, bt=8, model 33).
         m_pad = p.n1
-        sig = batch_tile * m_pad * 2 * f32
-        chirps = (p.n + m_pad) * 2 * f32
-        mats = 0
+        sig = batch_tile * m_pad * 8
+        extra = (p.n + m_pad) * 8
         if p.stage in ("fwd", "inv"):
             inner = _leaf_pass(m_pad)
-            if inner.kind == "direct":
-                mats = m_pad * m_pad * 2 * f32
-            else:
-                mats = (
-                    inner.n1 * inner.n1 + inner.n2 * inner.n2
-                    + inner.n1 * inner.n2
-                ) * 2 * f32
-        return 3 * sig + mats + chirps
+            extra += _lut_bytes(inner)
+            if inner.kind == "fused4":
+                extra += _row_group_bytes(inner)
+        return 4 * sig + extra
+    sig = batch_tile * p.n * 8
     if p.kind == "direct":
-        sig = batch_tile * p.n * 2 * f32
-        mats = p.n * p.n * 2 * f32
-        return 2 * sig + mats
-    sig = batch_tile * p.n * 2 * f32             # x tile (= n1*n2 grid)
-    mats = (p.n1 * p.n1 + p.n2 * p.n2) * 2 * f32  # W1, W2
-    tw = p.n1 * p.n2 * 2 * f32                    # twiddle grid
-    return 3 * sig + mats + tw                    # in, intermediate, out
+        return 7 * sig + _lut_bytes(p)
+    return 4 * sig + _lut_bytes(p) + _row_group_bytes(p)
 
 
 def pick_batch_tile(p: Pass, budget: int = VMEM_BUDGET) -> int:
-    """Largest power-of-two batch tile whose working set fits the budget."""
+    """Largest power-of-two batch tile whose working set fits the budget,
+    and at least ``SUBLANES`` rows (the TPU block's sublane tile)."""
     bt = 512
-    while bt > 1 and vmem_bytes(p, bt) > budget:
+    while bt > SUBLANES and vmem_bytes(p, bt) > budget:
         bt //= 2
     return bt
 
@@ -704,19 +732,21 @@ def program_hbm_bytes(
 
 
 def _pass_chunk_bytes(p: Pass, c: int) -> int:
-    """VMEM working set of one grid step of a pencil pass with chunk ``c``."""
-    f32 = 4
+    """Modeled scoped VMEM of one grid step of a pencil pass with chunk
+    ``c``: the double-buffered (f, c) blocks, the in-kernel (c, f)
+    transposes and the four-step's scratch (8·sig in all), the LUTs, and a
+    double-buffered twiddle block.  Calibrated like :func:`vmem_bytes`,
+    at c=128: measured 17.7 MiB for f=1024 direct with twiddle, model 18;
+    for the four-step 33 MiB at f=4096 with twiddle (model 40.2), 46 and
+    66 at f=8192 without and with (model 64.2, 80.2), 81 and 121 at
+    f=16384 (model 128.4, 160.4)."""
     if p.kind == "bluestein":
         # Whole-signal chirp passes are batch-tiled, never chunked; charge
         # the tile model so a defensive caller still gets a sane bound.
         return vmem_bytes(p, c)
-    sig = p.n * c * 2 * f32
-    tw = sig if p.twiddle_after else 0
-    if p.kind == "direct":
-        luts = p.n * p.n * 2 * f32
-    else:
-        luts = (p.n1 * p.n1 + p.n2 * p.n2 + p.n1 * p.n2) * 2 * f32
-    return 3 * sig + tw + luts  # in, intermediate, out (+ twiddle slab)
+    sig = p.n * c * 8
+    tw = 2 * sig if p.twiddle_after else 0
+    return 8 * sig + tw + _lut_bytes(p)
 
 
 def pick_pass_chunk(
@@ -731,16 +761,57 @@ def pick_pass_chunk(
     start from the largest power of two below them; the executor pads the
     last partial chunk.
 
-    The budget is binding: for large factors the chunk drops below one
-    128-lane tile (padded sublanes beat a working set that Mosaic cannot
-    place in VMEM at all — interpret-mode CI would never catch that)."""
+    The chunk never drops below one ``LANES``-wide tile (or the whole
+    width, when narrower): it is the last dim of the kernel's blocks, and
+    Mosaic refuses narrower ones — interpret mode would never catch that.
+    At that floor the modeled step fits :data:`~repro.core.limits.VMEM_LIMIT`
+    only for factors up to 8192; :func:`check_tpu_vmem` refuses larger
+    ones at plan time."""
     if width is None:
         pencils, stride, _f = p.view_in
         width = stride if stride > 1 else pencils
     c = 1 << (max(width, 1).bit_length() - 1)  # largest pow2 <= width
-    while c > 1 and _pass_chunk_bytes(p, c) > budget:
+    while c > LANES and _pass_chunk_bytes(p, c) > budget:
         c //= 2
-    return max(c, 1)
+    return c
+
+
+def _chunked(p: Pass) -> bool:
+    """Whether the executor sweeps ``p`` in pencil chunks (a column pass,
+    or a row pass with the natural-order transpose fused into its write)
+    rather than in batch tiles of whole signals."""
+    if p.kind in ("reorder", "bluestein"):
+        return False
+    if p.axis == -2:
+        return True
+    pencils, stride, _f = p.view_in
+    return pencils > 1 and (stride > 1 or p.view_out != p.view_in)
+
+
+def check_tpu_vmem(passes: tuple[Pass, ...]) -> None:
+    """Refuse, at plan time, a pass program the TPU cannot compile.
+
+    A chunked pass never goes below one ``LANES``-wide chunk, so its
+    narrowest grid step costs ``_pass_chunk_bytes(p, LANES)``.  Past
+    :data:`~repro.core.limits.VMEM_LIMIT` Mosaic refuses the kernel, so
+    raise a :class:`~repro.core.faults.PlanError` naming the ceiling
+    instead.  The ceiling is a pencil factor of 8192: 1-D lengths up to
+    2²⁶, since 2²⁷ and 2²⁸ open with a 16384-point column pass whose
+    twiddled step the compiler puts at 121 MiB.  The model refuses a
+    twiddle-free 16384-point pass too (an in-place 2-D column pass of
+    16384 rows), which measured 81 MiB: it errs on the safe side."""
+    for p in passes:
+        if not _chunked(p):
+            continue
+        need = _pass_chunk_bytes(p, LANES)
+        if need > VMEM_LIMIT:
+            raise faults.PlanError(
+                f"pass of factor {p.n} needs {need / 2**20:.0f} MiB of VMEM at "
+                f"the narrowest {LANES}-lane chunk, over the "
+                f"{VMEM_LIMIT // 2**20} MiB TPU kernel limit; pencil "
+                f"factors up to 8192 fit (1-D lengths up to 2**26)",
+                pass_kind=p.kind,
+            )
 
 
 def describe_program(p: FFTPlan, batch: int = 1) -> str:

@@ -34,11 +34,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import triton as plt
 
 from repro.core.fft_xla import cmul
+from repro.core.limits import VMEM_LIMIT
 from repro.kernels.dft_matmul import dft_tile
-from repro.kernels.fft4step import four_step_tile
-from repro.kernels.pallas_compat import compiler_params, gpu_compiler_params
+from repro.kernels.fft4step import four_step_rows
 
 Planes = tuple[jax.Array, jax.Array]
 
@@ -55,9 +57,12 @@ def _params(gpu: bool, interpret: bool) -> dict:
     if gpu:
         if interpret:
             return {}
-        p = gpu_compiler_params()
-        return {} if p is None else {"compiler_params": p}
-    return {"compiler_params": compiler_params(dimension_semantics=("parallel",))}
+        return {"compiler_params": plt.CompilerParams(num_warps=4, num_stages=2)}
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
+        )
+    }
 
 
 def _inner_specs(inner_kind: str, m_pad: int, in1: int, in2: int) -> list:
@@ -72,14 +77,15 @@ def _inner_specs(inner_kind: str, m_pad: int, in1: int, in2: int) -> list:
     return [lut1, lut1, lutt, lutt, lut2, lut2]
 
 
-def _inner_transform(yr, yi, inner, inner_kind: str, in1: int, in2: int):
-    """The pad-length transform on a VMEM-resident tile — the same engines
-    every pow2 leaf runs, just called from inside the chirp kernel."""
+def _inner_transform(x_r, x_i, inner, inner_kind: str, in1: int, in2: int, write):
+    """The pad-length transform of the (bt, M) refs ``x_r``/``x_i`` — the
+    same engines every pow2 leaf runs, called from inside the chirp kernel
+    — handing each row group's result to ``write(rows, yr, yi)``."""
     if inner_kind == "direct":
-        return dft_tile(yr, yi, inner[0][...], inner[1][...])
-    return four_step_tile(
-        yr, yi, *(w[...] for w in inner), in1, in2, True
-    )
+        yr, yi = dft_tile(x_r[...], x_i[...], inner[0][...], inner[1][...])
+        write(slice(None), yr, yi)
+        return
+    four_step_rows(x_r, x_i, [w[...] for w in inner], in1, in2, write)
 
 
 def bluestein_fwd_call(
@@ -110,12 +116,17 @@ def bluestein_fwd_call(
         b_r, b_i, o_r, o_i = rest[-4:]
         yr, yi = cmul(x_r[...], x_i[...], a_r[...], a_i[...])
         zeros = jnp.zeros((yr.shape[0], m_pad - n), jnp.float32)
-        yr = jnp.concatenate([yr, zeros], axis=-1)
-        yi = jnp.concatenate([yi, zeros], axis=-1)
-        fr, fi = _inner_transform(yr, yi, inner, inner_kind, in1, in2)
-        fr, fi = cmul(fr, fi, b_r[...], b_i[...])
-        o_r[...] = fr
-        o_i[...] = fi
+        # The padded chirp-signal is staged in the output block, which the
+        # pad-length transform then overwrites in place.
+        o_r[...] = jnp.concatenate([yr, zeros], axis=-1)
+        o_i[...] = jnp.concatenate([yi, zeros], axis=-1)
+
+        def write(rows, fr, fi):
+            fr, fi = cmul(fr, fi, b_r[...], b_i[...])
+            o_r[rows, :] = fr
+            o_i[rows, :] = fi
+
+        _inner_transform(o_r, o_i, inner, inner_kind, in1, in2, write)
 
     sig_in = pl.BlockSpec((batch_tile, n), lambda i: (i, 0))
     sig_out = pl.BlockSpec((batch_tile, m_pad), lambda i: (i, 0))
@@ -165,13 +176,13 @@ def bluestein_inv_call(
     def kernel(x_r, x_i, *rest):
         inner = rest[: -4]
         p_r, p_i, o_r, o_i = rest[-4:]
-        gr, gi = _inner_transform(
-            x_r[...], x_i[...], inner, inner_kind, in1, in2
-        )
-        gr, gi = gr[:, :n], gi[:, :n]
-        gr, gi = cmul(gr, gi, p_r[...], p_i[...])
-        o_r[...] = gr
-        o_i[...] = gi
+
+        def write(rows, gr, gi):
+            gr, gi = cmul(gr[:, :n], gi[:, :n], p_r[...], p_i[...])
+            o_r[rows, :] = gr
+            o_i[rows, :] = gi
+
+        _inner_transform(x_r, x_i, inner, inner_kind, in1, in2, write)
 
     sig_in = pl.BlockSpec((batch_tile, m_pad), lambda i: (i, 0))
     sig_out = pl.BlockSpec((batch_tile, n), lambda i: (i, 0))

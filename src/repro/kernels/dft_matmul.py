@@ -29,9 +29,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft_xla import cmul
-from repro.kernels.pallas_compat import compiler_params
+from repro.core.limits import VMEM_LIMIT
 
 __all__ = ["dft_matmul_call", "dft_tile"]
 
@@ -41,7 +42,10 @@ def dft_tile(xr, xi, wr, wi):
 
     Pure jnp on arrays already in VMEM; callable from any Pallas kernel body.
     """
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(
+        jnp.dot, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     k1 = dot(xr + xi, wr)
     k2 = dot(xr, wi - wr)
     k3 = dot(xi, wr + wi)
@@ -104,8 +108,8 @@ def dft_matmul_call(
         out_specs=[sig_spec, sig_spec],
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel",)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
         ),
     )
     return tuple(fn(*operands))

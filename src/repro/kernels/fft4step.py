@@ -19,14 +19,18 @@ the output tile are co-resident in VMEM; the LUT operands are pinned to block
 The whole VMEM dataflow lives in :func:`four_step_tile` so the pass-program
 kernels (``repro.kernels.pencil``) embed the same four-step engine inside
 their strided-column and transposed-write passes — the tile function is the
-unit of fusion.  On top of the selectable output layout (``natural_order``),
+unit of fusion.  :func:`four_step_rows` sweeps it over a tile held in refs
+a row group at a time, which keeps what Mosaic unrolls (and its compile
+time) at one group, however large the batch tile.  On top of the selectable output layout (``natural_order``),
 ``fft4step_call`` accepts a post-GEMM per-bin twiddle (``twiddle_after``)
 applied in the epilogue before the write, so a multiplicative phase stage
 (modulation, delay, inter-level twiddle of a follow-on factor) costs zero
 extra HBM passes.
 
 Both GEMMs are plain 2-D contractions with 128-aligned operand shapes for
-n1, n2 ≥ 128 (N ≥ 16384); smaller factors pad sublanes but stay correct.
+n1, n2 ≥ 128 (N ≥ 16384); below that the split keeps n2 = 128 lanes and
+n1 ≥ 8 sublanes.  The GEMMs run at ``Precision.HIGHEST`` (float32 on the
+MXU) — the FFT's 1e-3 accuracy contract needs more than one bf16 pass.
 Inverse transforms use conjugated LUTs with 1/N folded into W2 — the
 scaled table *is* the LUT, no extra pass (paper §2.3.1 spirit).
 """
@@ -38,16 +42,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft_xla import cmul
-from repro.kernels.pallas_compat import compiler_params
+from repro.core.limits import VMEM_LIMIT, row_group
 
-__all__ = ["fft4step_call", "four_step_tile", "cgemm_tile"]
+__all__ = ["fft4step_call", "four_step_rows", "four_step_tile", "cgemm_tile"]
 
 
 def cgemm_tile(ar, ai, br, bi):
     """Karatsuba complex GEMM on split planes: 3 real MXU GEMMs."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(
+        jnp.dot, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     k1 = dot(ar + ai, br)
     k2 = dot(ar, bi - br)
     k3 = dot(ai, br + bi)
@@ -60,12 +68,23 @@ def four_step_tile(
     """The four-step dataflow on a VMEM-resident (bt, n1·n2) tile.
 
     Pure jnp on arrays already in VMEM — callable from any Pallas kernel
-    body (this file's batch kernel, the pencil pass kernels) or traced
-    directly for reference.  Returns (yr, yi) of shape (bt, n1·n2), in
-    natural or pencil (k1-major) order.
+    body (through :func:`four_step_rows`) or traced directly for reference.
+    Returns (yr, yi) of shape (bt, n1·n2), in natural or pencil (k1-major)
+    order.  Every relayout splits or merges whole 128-lane rows (``n2`` is
+    at least ``LANES``, see :func:`repro.core.plan.four_step_split`), the
+    only lane reshapes Mosaic lowers.
     """
     bt = xr.shape[0]
     n = n1 * n2
+    if bt == 1:
+        # One signal: the (n1, n2) view is a plain 2-D matrix.
+        ar, ai = cgemm_tile(w1r, w1i, xr.reshape(n1, n2), xi.reshape(n1, n2))
+        br = ar * tr - ai * ti
+        bi = ar * ti + ai * tr
+        cr, ci = cgemm_tile(br, bi, w2r, w2i)
+        if natural_order:
+            return cr.T.reshape(1, n), ci.T.reshape(1, n)
+        return cr.reshape(1, n), ci.reshape(1, n)
     # (bt, n) → (n1, bt·n2): put the contracted factor on rows.
     xr = xr.reshape(bt, n1, n2).transpose(1, 0, 2).reshape(n1, bt * n2)
     xi = xi.reshape(bt, n1, n2).transpose(1, 0, 2).reshape(n1, bt * n2)
@@ -91,22 +110,66 @@ def four_step_tile(
     return cr.transpose(1, 0, 2).reshape(bt, n), ci.transpose(1, 0, 2).reshape(bt, n)
 
 
+def four_step_rows(x_r, x_i, luts, n1: int, n2: int, write, natural_order=True):
+    """Run :func:`four_step_tile` over every row of the (bt, n) refs
+    ``x_r``/``x_i``, a row group per ``fori_loop`` step, and hand each
+    group's result to ``write(rows, yr, yi)``.
+
+    The loop keeps the unrolled kernel body at one group's size, whatever
+    the tile: a group is one signal once ``n1`` fills a 128-row MXU
+    operand (the 2-D path), else ``SUBLANES`` signals batched through the
+    3-D relayouts (a one-row (n2, n1) → (1, n) merge below 128 lanes is not
+    lowered).  A tile of fewer rows than a group is zero-padded to one; a
+    ragged tail is computed as the tile's last full group, of which only
+    the tail rows are written — so ``write`` may target the input refs
+    themselves (an in-place sweep): rows are independent, and no row is
+    read after it was written.  ``luts`` = (w1r, w1i, tr, ti, w2r, w2i),
+    loaded values."""
+    bt, n = x_r.shape
+    g = row_group(n1)
+    if bt < g:
+        pad = jnp.zeros((g - bt, n), jnp.float32)
+        yr, yi = four_step_tile(
+            jnp.concatenate([x_r[...], pad]), jnp.concatenate([x_i[...], pad]),
+            *luts, n1, n2, natural_order,
+        )
+        write(slice(None), yr[:bt], yi[:bt])
+        return
+
+    def group(start):
+        rows = pl.ds(start, g)
+        return four_step_tile(
+            x_r[rows, :], x_i[rows, :], *luts, n1, n2, natural_order
+        )
+
+    def body(s, carry):
+        start = pl.multiple_of(s * g, g)
+        write(pl.ds(start, g), *group(start))
+        return carry
+
+    jax.lax.fori_loop(0, bt // g, body, 0)
+    tail = bt % g
+    if tail:
+        yr, yi = group(bt - g)
+        write(pl.ds(bt - tail, tail), yr[g - tail:], yi[g - tail:])
+
+
 def _make_kernel(n1: int, n2: int, natural_order: bool, has_epilogue: bool):
     def kernel(x_r, x_i, w1_r, w1_i, t_r, t_i, w2_r, w2_i, *rest):
         if has_epilogue:
             e_r, e_i, o_r, o_i = rest
         else:
             o_r, o_i = rest
-        yr, yi = four_step_tile(
-            x_r[...], x_i[...],
-            w1_r[...], w1_i[...], t_r[...], t_i[...], w2_r[...], w2_i[...],
-            n1, n2, natural_order,
-        )
-        if has_epilogue:
-            # Post-GEMM per-position twiddle: y[b, j] *= e[j] (split complex).
-            yr, yi = cmul(yr, yi, e_r[...], e_i[...])
-        o_r[...] = yr
-        o_i[...] = yi
+        luts = [r[...] for r in (w1_r, w1_i, t_r, t_i, w2_r, w2_i)]
+
+        def write(rows, yr, yi):
+            if has_epilogue:
+                # Post-GEMM per-position twiddle: y[b, j] *= e[j].
+                yr, yi = cmul(yr, yi, e_r[...], e_i[...])
+            o_r[rows, :] = yr
+            o_i[rows, :] = yi
+
+        four_step_rows(x_r, x_i, luts, n1, n2, write, natural_order)
 
     return kernel
 
@@ -166,8 +229,8 @@ def fft4step_call(
         out_specs=[sig, sig],
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel",)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
         ),
     )
     return tuple(fn(*operands))

@@ -8,9 +8,8 @@ schedule; what changes on CUDA-class devices is only the launch surface:
 
 * BlockSpecs stay (they are the tiling), but the index maps must be
   Triton-friendly — no ``dimension_semantics`` or other Mosaic-only
-  compiler params (``kernels.pallas_compat.gpu_compiler_params`` supplies
-  ``num_warps``/``num_stages`` instead, or ``None`` when no Triton
-  lowering is available);
+  compiler params (``plt.CompilerParams`` supplies ``num_warps``/
+  ``num_stages`` instead, and interpret mode takes none);
 * batch tiles are picked against the per-SM shared-memory budget
   (:func:`repro.core.plan.pick_batch_tile_gpu` /
   :func:`repro.core.limits.memory_budget`) rather than ``VMEM_BUDGET`` —
@@ -44,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import triton as plt
 
 from repro.core import faults
 from repro.core import plan as plan_lib
@@ -51,7 +51,6 @@ from repro.core.fft_xla import cmul
 from repro.kernels import ops, pencil
 from repro.kernels.dft_matmul import dft_tile
 from repro.kernels.fft4step import four_step_tile
-from repro.kernels.pallas_compat import gpu_compiler_params
 
 Planes = Tuple[jax.Array, jax.Array]
 
@@ -90,8 +89,7 @@ def _call_kwargs(interpret: bool) -> dict:
     (the interpreter has no backend to hand them to)."""
     if interpret:
         return {}
-    params = gpu_compiler_params()
-    return {} if params is None else {"compiler_params": params}
+    return {"compiler_params": plt.CompilerParams(num_warps=4, num_stages=2)}
 
 
 def dft_matmul_gpu_call(
@@ -203,7 +201,7 @@ def rows_natural_gpu_call(
         kind, f, n1, n2, lambda i, j: (0, 0)
     )
     fn = pl.pallas_call(
-        pencil._make_rows_kernel(kind, n1, n2, len(luts)),
+        pencil._make_rows_kernel(kind, n1, n2, len(luts), scratch=False),
         grid=(b, p // chunk),
         in_specs=in_specs,
         out_specs=[out_sig, out_sig],

@@ -40,6 +40,7 @@ import numpy as np
 from repro.core import faults
 from repro.core import plan as plan_lib
 from repro.core import twiddle as tw
+from repro.core.limits import LANES, SUBLANES
 from repro.kernels.dft_matmul import dft_matmul_call
 from repro.kernels.fft4step import fft4step_call
 from repro.kernels import pencil
@@ -88,6 +89,15 @@ def _pass_twiddle_luts(n_bins: int, n_phases: int, inverse: bool):
     """Host-cached inter-factor twiddle grid for a program pass's epilogue
     (served to the kernel chunk-by-chunk through its BlockSpec)."""
     return tw.pass_twiddle(n_bins, n_phases, inverse)
+
+
+@functools.lru_cache(maxsize=64)
+def _pass_twiddle_rows(n_bins: int, n_phases: int, inverse: bool):
+    """The same grid transposed to ``(n_phases, n_bins)``: one phase row per
+    strip-mined column chunk, broadcast across the image width in VMEM."""
+    return tuple(
+        np.ascontiguousarray(t.T) for t in _pass_twiddle_luts(n_bins, n_phases, inverse)
+    )
 
 
 def _transform_luts(p: plan_lib.Pass, inverse: bool):
@@ -148,6 +158,7 @@ def _bluestein_pass(
     from repro.kernels import bluestein as bk
 
     n, m_pad = p.n, p.n1
+    bt = _batch_tile(bt, xr.shape[0])
     xr, xi, b, pad = _pad_batch(xr, xi, bt)
     luts = _bluestein_luts(p, inverse)
     kw = dict(n=n, m_pad=m_pad, batch_tile=bt, interpret=interpret, gpu=gpu)
@@ -160,6 +171,13 @@ def _bluestein_pass(
     else:
         yr, yi = bk.bluestein_elem_call(xr, xi, luts, stage=p.stage, **kw)
     return (yr, yi) if pad == 0 else (yr[:b], yi[:b])
+
+
+def _batch_tile(bt: int, b: int) -> int:
+    """Rows per grid step: the whole batch when it fits one tile (the block
+    then equals the array's own dims), else at least ``SUBLANES`` rows —
+    a Pallas TPU block's second-to-last dim is a multiple of 8 or whole."""
+    return b if b <= bt else max(bt, SUBLANES)
 
 
 def _pad_batch(xr, xi, bt):
@@ -185,7 +203,7 @@ def _leaf_kernel(
         return _bluestein_pass(xr, xi, p, inverse, interpret, _tile_for(p, batch_tiles))
     if p.n == 1:
         return xr, xi
-    bt = _tile_for(p, batch_tiles)
+    bt = _batch_tile(_tile_for(p, batch_tiles), xr.shape[0])
     xr, xi, b, pad = _pad_batch(xr, xi, bt)
     if p.kind == "direct":
         wr, wi = _direct_luts(p.n, inverse)
@@ -220,8 +238,9 @@ def _apply_pass(
     overrides the VMEM-heuristic grid-step width (the tuner's hook).
 
     Kernel passes run under :func:`repro.core.faults.run_leaf`: a leaf
-    that fails to trace/compile is retried once, then the (pallas, kind)
-    pair is quarantined and the pass demotes to the traced-XLA fallback,
+    that fails to trace/compile raises ``KernelError`` with the compiler's
+    message.  Only an injected fault is retried, then quarantines the
+    (pallas, kind) pair and demotes the pass to the traced-XLA fallback,
     recorded on ``degradations``.  The no-fault jaxpr is untouched."""
     # A pass may pin its own direction (the Bluestein inner conv is always
     # forward-then-inverse regardless of the outer transform's direction).
@@ -307,18 +326,26 @@ def image_chunk(p: plan_lib.Pass, w: int) -> int:
     m+1 half-spectrum bins of rfft2): a chunk near the width would nearly
     double the pass (pow2-floored chunk + 1 ragged column → a whole extra
     chunk of padding), so shrink until the padding is under half a chunk —
-    but not below one 128-lane tile."""
+    but not below one 128-lane tile.  An image at most one lane tile wide
+    is one whole-width chunk."""
+    if w <= LANES:
+        return w
     chunk = plan_lib.pick_pass_chunk(p, width=w)
-    while chunk > 128 and (-w) % chunk >= chunk // 2:
+    while chunk > LANES and (-w) % chunk >= chunk // 2:
         chunk //= 2
     return chunk
 
 
 def _fit_chunk(c: int, w: int, p: plan_lib.Pass) -> int:
     """Clamp a (possibly tuned) chunk to the width and the VMEM budget —
-    a cache entry tuned for one shape must not break another."""
-    c = max(1, min(c, 1 << (max(w, 1).bit_length() - 1)))
-    while c > 1 and plan_lib._pass_chunk_bytes(p, c) > plan_lib.VMEM_BUDGET:
+    a cache entry tuned for one shape must not break another.  The chunk
+    stays at least one 128-lane tile (or the whole width below that):
+    Mosaic refuses narrower column blocks."""
+    if w <= LANES:
+        return w
+    top = 1 << (w.bit_length() - 1)
+    c = max(LANES, min(c, top))
+    while c > LANES and plan_lib._pass_chunk_bytes(p, c) > plan_lib.VMEM_BUDGET:
         c //= 2
     return c
 
@@ -327,9 +354,8 @@ def _cols_image_pass(
     xr, xi, p: plan_lib.Pass, inverse, interpret, chunk=None,
     degradations=None, index=None,
 ) -> Planes:
-    """Column pass of a 2-D program, with the same retry → quarantine →
-    traced-XLA degradation protocol as the row passes (see
-    :func:`_apply_pass`)."""
+    """Column pass of a 2-D program, under the same fault protocol as the
+    row passes (see :func:`_apply_pass`)."""
     return faults.run_leaf(
         "pallas",
         p.kind,
@@ -409,7 +435,7 @@ def _cols_image_kernel(xr, xi, p: plan_lib.Pass, inverse, interpret, chunk=None)
         x3i = xi.reshape(b, f, stride * wp)
         twiddle = None
         if p.twiddle_after is not None:
-            twiddle = _pass_twiddle_luts(*p.twiddle_after, inverse)
+            twiddle = _pass_twiddle_rows(*p.twiddle_after, inverse)
         yr, yi = pencil.cols_pass_call(
             x3r, x3i, luts, twiddle, kind=p.kind, n1=p.n1, n2=p.n2,
             chunk=chunk, interpret=interpret, tw_every=wp,

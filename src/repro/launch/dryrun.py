@@ -278,8 +278,9 @@ def _lower_fft(fft_shape, mesh, par):
                 xr, xi, n=n, axis_name="model", num_shards=model_n
             )
 
-        fn = dist.shard_map_compat(
-            body, mesh, in_specs=(spec, spec), out_specs=(spec, spec)
+        fn = jax.shard_map(
+            body, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+            check_vma=False,
         )
         jfn = jax.jit(fn, in_shardings=(NamedSharding(mesh, spec),) * 2)
         return jfn.lower(x_sds, x_sds)
@@ -294,8 +295,9 @@ def _lower_fft(fft_shape, mesh, par):
                 xr, xi, n1=n1, n2=n2, axis_name="model", num_shards=model_n
             )
 
-        fn = dist.shard_map_compat(
-            body2, mesh, in_specs=(spec, spec), out_specs=(spec, spec)
+        fn = jax.shard_map(
+            body2, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+            check_vma=False,
         )
         jfn = jax.jit(fn, in_shardings=(NamedSharding(mesh, spec),) * 2)
         return jfn.lower(x_sds, x_sds)
@@ -321,9 +323,10 @@ def _lower_fft(fft_shape, mesh, par):
                 from_pencil=True,
             )
 
-        fn = dist.shard_map_compat(
-            bodyc, mesh, in_specs=(spec, spec, hspec, hspec),
+        fn = jax.shard_map(
+            bodyc, mesh=mesh, in_specs=(spec, spec, hspec, hspec),
             out_specs=(spec, spec),
+            check_vma=False,
         )
         jfn = jax.jit(
             fn,
@@ -414,8 +417,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, force: bool = False) -
 
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older JAX: one dict per device
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         # Loop-aware costs from our own HLO walk (XLA's cost_analysis counts
         # while bodies once — verified; see analysis/hlo.py).

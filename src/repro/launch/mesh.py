@@ -9,6 +9,7 @@ then calls it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import ParallelConfig
 
@@ -18,12 +19,18 @@ __all__ = ["make_production_mesh", "parallel_config_for", "make_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (4, 2) on 8 CPU devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh for tests/examples (e.g. (4, 2) on 8 CPU devices).
+
+    Axes are ``Auto``: the training and sharding code places arrays with
+    ``NamedSharding``/``with_sharding_constraint`` and lets the compiler
+    propagate, which ``jax.make_mesh``'s default ``Explicit`` axes reject."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def parallel_config_for(mesh, *, fsdp: bool = False, sequence_parallel: bool = False) -> ParallelConfig:

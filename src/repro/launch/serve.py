@@ -20,11 +20,13 @@ from repro.configs.base import get_config
 from repro.core import faults
 from repro.configs.reduce import make_reduced
 from repro.models import model as model_lib
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import Engine, ServeConfig
 from repro.serving.spectral_serve import sweep_once
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -40,12 +40,14 @@ from repro.configs.reduce import make_reduced
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import shardings as sh_lib
 from repro.launch.mesh import make_mesh, parallel_config_for
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import StepWatchdog, StragglerStats, with_retries
 from repro.sharding.logical import mesh_context
 from repro.train.train_loop import init_train_state, make_train_step
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true", help="smoke-scale config")

@@ -90,6 +90,7 @@ _GLUE = {
     "convert_element_type",
     "broadcast_in_dim",
     "pjit",
+    "jit",
 }
 
 

@@ -51,9 +51,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 n1, n2 = 128, 256
 img = (np.random.randn(2, n1, n2) + 1j*np.random.randn(2, n1, n2)).astype(np.complex64)
 spec = P(None, 'x', None)
-fn = D.shard_map_compat(
+fn = jax.shard_map(
     lambda xr, xi: D.pfft2d(xr, xi, n1=n1, n2=n2, axis_name='x', num_shards=8),
-    mesh, in_specs=(spec, spec), out_specs=(spec, spec))
+    mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec), check_vma=False)
 yr, yi = fn(jnp.asarray(img.real), jnp.asarray(img.imag))
 ref2 = np.fft.fft2(img)
 rel = np.abs((np.asarray(yr)+1j*np.asarray(yi)) - ref2).max() / np.abs(ref2).max()
@@ -143,8 +143,9 @@ n = 8192
 x = jnp.zeros((n,), jnp.float32)
 
 def n_a2a(fn):
-    sm = D.shard_map_compat(fn, mesh, in_specs=(P('x'), P('x')),
-                            out_specs=(P('x'), P('x')))
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(P('x'), P('x')),
+                            out_specs=(P('x'), P('x')),
+                            check_vma=False)
     return str(jax.make_jaxpr(sm)(x, x)).count('all_to_all')
 
 def fwd(natural, **kw):
@@ -179,11 +180,12 @@ assert n_a2a(inv(False, pack=False)) == 6
 # pfft2d: one packed a2a per transpose (2), per-plane legacy 4.
 img = jnp.zeros((8, 128, 256), jnp.float32)
 def n_a2a_2d(pack):
-    sm = D.shard_map_compat(
+    sm = jax.shard_map(
         lambda xr, xi: D.pfft2d(xr, xi, n1=128, n2=256, axis_name='x',
                                 num_shards=8, pack=pack),
-        mesh, in_specs=(P(None, 'x'), P(None, 'x')),
-        out_specs=(P(None, 'x'), P(None, 'x')))
+        mesh=mesh, in_specs=(P(None, 'x'), P(None, 'x')),
+        out_specs=(P(None, 'x'), P(None, 'x')),
+        check_vma=False)
     return str(jax.make_jaxpr(sm)(img, img)).count('all_to_all')
 assert n_a2a_2d(True) == 2, n_a2a_2d(True)
 assert n_a2a_2d(False) == 4, n_a2a_2d(False)

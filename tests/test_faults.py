@@ -171,6 +171,28 @@ def test_contract_gates_are_never_demoted():
     assert faults.quarantined() == ()
 
 
+def test_real_kernel_failure_raises_and_is_never_demoted():
+    """A failure that was not injected — a compiler refusal, say — raises
+    KernelError at once with the original message: no retry, no
+    quarantine, no fallback, nothing on the degradation ledger."""
+    calls = []
+
+    def attempt():
+        calls.append(1)
+        raise ValueError("block shape (1, 1024) is not tile-aligned")
+
+    def fallback():
+        raise AssertionError("a real failure must not reach the fallback")
+
+    with pytest.raises(faults.KernelError, match="not tile-aligned") as info:
+        faults.run_leaf("pallas", "direct", attempt, fallback)
+    assert info.value.backend == "pallas" and info.value.pass_kind == "direct"
+    assert isinstance(info.value.__cause__, ValueError)
+    assert calls == [1]
+    assert faults.quarantined() == ()
+    assert faults.degradation_log() == ()
+
+
 # ---------------------------------------------------------------------------
 # no-fault invariant: the machinery leaves no trace in the jaxpr
 # ---------------------------------------------------------------------------

@@ -101,6 +101,7 @@ _GLUE = {
     "convert_element_type",
     "broadcast_in_dim",
     "pjit",
+    "jit",
 }
 
 
@@ -128,6 +129,34 @@ def test_claimed_leaf_jaxpr_is_pallas_call_plus_reshapes(n):
     assert n_calls == len(p.passes), (n_calls, len(p.passes))
     stray = [q for q in prims if q != "pallas_call" and q not in _GLUE]
     assert not stray, f"claimed leaf leaked XLA math outside the kernel: {stray}"
+
+
+def _pallas_eqns(jaxpr, acc):
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            acc.append(e)
+            continue
+        for v in e.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                _pallas_eqns(inner, acc)
+    return acc
+
+
+# n → the GPU kernels its plan runs: dft_matmul (256), fused four-step
+# (4096), rows_natural direct (2^17) and fused4 (2^22), Bluestein fused
+# fwd/inv (2029) and split-regime chirp stages (40000).
+@pytest.mark.parametrize("n", [256, 4096, 2**17, 2**22, 2029, 40000])
+def test_gpu_kernels_take_no_scratch(n):
+    # The Triton lowering refuses scratch memory (interpret mode accepts
+    # it), so no GPU pallas_call may ask for any.
+    p = fft_lib.plan(fft_lib.FFTSpec(n=n), backend="pallas_gpu", tune="off")
+    x = jax.ShapeDtypeStruct((1, n), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda a, b: p.apply_planes(a, b))(x, x)
+    calls = _pallas_eqns(jaxpr.jaxpr, [])
+    assert calls, n
+    scratch = [e.params["grid_mapping"].num_scratch_operands for e in calls]
+    assert scratch == [0] * len(calls), (n, scratch)
 
 
 # ---------------------------------------------------------------------------

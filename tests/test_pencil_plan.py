@@ -171,7 +171,7 @@ def test_single_shard_zero_collectives_jaxpr():
     from jax.sharding import PartitionSpec as P
 
     for natural in (True, False):
-        fn = D.shard_map_compat(
+        fn = jax.shard_map(
             lambda xr, xi: D.pfft(
                 xr,
                 xi,
@@ -180,9 +180,10 @@ def test_single_shard_zero_collectives_jaxpr():
                 num_shards=1,
                 natural_order=natural,
             ),
-            mesh,
+            mesh=mesh,
             in_specs=(P("x"), P("x")),
             out_specs=(P("x"), P("x")),
+            check_vma=False,
         )
         jx = str(jax.make_jaxpr(fn)(jnp.zeros(n), jnp.zeros(n)))
         for coll in ("all_to_all", "all_gather", "psum", "ppermute"):

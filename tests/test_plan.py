@@ -2,6 +2,7 @@
 
 import pytest
 
+from repro.core import faults
 from repro.core import plan as P
 
 
@@ -57,8 +58,8 @@ def test_vmem_budget_respected():
     for n in (2048, 65536):
         p = P.plan_fft(n).leaf_passes[-1]
         bt = P.pick_batch_tile(p)
-        assert bt >= 1
-        assert P.vmem_bytes(p, bt) <= 8 * 1024 * 1024 or bt == 1
+        assert bt >= P.SUBLANES  # the TPU block's sublane tile
+        assert P.vmem_bytes(p, bt) <= P.VMEM_BUDGET
 
 
 def test_describe_smoke():
@@ -137,22 +138,44 @@ def test_pick_pass_chunk_ragged_widths():
 
 
 def test_pick_pass_chunk_chunk1_degenerate():
-    # A binding budget collapses to chunk=1 (padded sublanes beat a working
-    # set that cannot be placed at all) — with and without width override.
+    # A binding budget floors at one 128-lane tile, the narrowest column
+    # block Mosaic accepts — with and without width override; a width below
+    # one tile is taken whole.
     p = P.plan_fft(2**18).passes[0]
-    assert P.pick_pass_chunk(p, budget=1) == 1
-    assert P.pick_pass_chunk(p, budget=1, width=65537) == 1
-    assert P.pick_pass_chunk(p, budget=1, width=3 << 8) == 1
+    assert P.pick_pass_chunk(p, budget=1) == P.LANES
+    assert P.pick_pass_chunk(p, budget=1, width=65537) == P.LANES
+    assert P.pick_pass_chunk(p, budget=1, width=3 << 8) == P.LANES
+    assert P.pick_pass_chunk(p, budget=1, width=64) == 64
 
 
 def test_pick_pass_chunk_fits_budget():
-    # The VMEM budget is binding (a chunk below one 128-lane tile beats a
-    # working set Mosaic cannot place at all) — incl. huge factors like 2²⁶'s
-    # 8192×8192 program, which interpret-mode CI would never surface.
+    # The VMEM budget is binding down to one 128-lane tile, the narrowest
+    # block Mosaic accepts — incl. huge factors like 2²⁶'s 8192×8192
+    # program, which interpret-mode CI would never surface.
     for n in (2**17, 2**18, 2**20, 2**26):
         for p in P.plan_fft(n).passes:
             c = P.pick_pass_chunk(p)
-            assert c >= 1
+            assert c >= P.LANES
             axis = p.view_in[1] if p.view_in[1] > 1 else p.view_in[0]
             assert axis % c == 0
-            assert P._pass_chunk_bytes(p, c) <= 8 * 1024 * 1024 or c == 1
+            assert P._pass_chunk_bytes(p, c) <= P.VMEM_BUDGET or c == P.LANES
+            # and never past the limit every kernel is compiled with
+            assert P._pass_chunk_bytes(p, c) <= P.VMEM_LIMIT
+        P.check_tpu_vmem(P.plan_fft(n).passes)
+
+
+@pytest.mark.parametrize("n", [2**27, 2**28, 2**32])
+def test_tpu_vmem_ceiling_is_a_plan_error(n):
+    # Past a pencil factor of 8192 even the narrowest chunk overflows the
+    # kernel VMEM limit: the plan is refused with the ceiling named, not
+    # left for the chip's compiler to reject.
+    with pytest.raises(faults.PlanError, match="factors up to 8192"):
+        P.check_tpu_vmem(P.plan_fft(n).passes)
+
+
+def test_tpu_vmem_ceiling_checked_when_planning_for_the_chip():
+    from repro.core import fft as F
+
+    spec = F.FFTSpec(n=2**27)
+    with pytest.raises(faults.PlanError, match="VMEM"):
+        F._build_plan(spec, "pallas", "tpu", "off")
