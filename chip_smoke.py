@@ -11,8 +11,9 @@ and once under ``jax.jit``, compares both with ``np.fft`` in float64 on
 sampled rows at 1e-3·max|ref|, counts the ``tpu_custom_call`` kernels in
 the compiled HLO (at least one per planned pass), and checks that no leaf
 was demoted (``faults.degradation_log()`` stays empty).  It prints one JSON
-line per phase; the wall time in it is for information only.  The last
-line of a passing run is ``{"ok": true, "device": {...}}``.
+line per phase, with the compile time.  It times no transform: that is
+``chipbench/run.py``'s work.  The last line of a passing run is
+``{"ok": true, "device": {...}}``.
 
 The script refuses to run — before any phase, printing no result — when
 JAX finds no TPU, when the Pallas kernels would run in interpret mode, or
@@ -79,9 +80,7 @@ def _run_phase(jax, name, fn, x, ref_fn, rows, planned, note=""):
     the compiled program must hold."""
     from repro.core import faults
 
-    t0 = time.perf_counter()
     eager = jax.block_until_ready(fn(x))
-    t_eager = time.perf_counter() - t0
     planned = planned()
     for p in planned:
         assert p.backend.name == "pallas", (name, p.backend.name)
@@ -90,9 +89,7 @@ def _run_phase(jax, name, fn, x, ref_fn, rows, planned, note=""):
     compiled = jax.jit(fn).lower(x).compile()
     t_compile = time.perf_counter() - t0
     kernels = _hlo_kernels(compiled)
-    t0 = time.perf_counter()
     out = jax.block_until_ready(compiled(x))
-    t_jit = time.perf_counter() - t0
     ref = ref_fn(rows)
     err_e, tol = _max_err(np.asarray(eager[rows], np.complex128), ref)
     err_j, _ = _max_err(np.asarray(out[rows], np.complex128), ref)
@@ -109,7 +106,6 @@ def _run_phase(jax, name, fn, x, ref_fn, rows, planned, note=""):
         "tol": tol,
         "compile_s": round(t_compile, 3),
         "degradations": degraded,
-        "wall_s_info": {"eager_incl_compile": round(t_eager, 3), "jit": round(t_jit, 4)},
     }
     if note:
         rec["note"] = note
@@ -240,9 +236,7 @@ def four_chips(jax, devices):
         compiled = jax.jit(fn).lower(*args).compile()
         t_compile = time.perf_counter() - t0
         hlo = compiled.as_text()
-        t0 = time.perf_counter()
         yr, yi = jax.block_until_ready(compiled(*args))
-        t_run = time.perf_counter() - t0
         on = sorted(d.id for d in yr.sharding.device_set)
         got = np.asarray(yr[rows], np.float64) + 1j * np.asarray(yi[rows], np.float64)
         ref = ref_fn(rows)
@@ -259,7 +253,6 @@ def four_chips(jax, devices):
             "tol": tol,
             "compile_s": round(t_compile, 3),
             "degradations": len(faults.degradation_log()),
-            "wall_s_info": round(t_run, 4),
         }
         if note:
             rec["note"] = note

@@ -761,8 +761,13 @@ class PlannedFFT:
         This is the raw entry point used by the distributed pencil driver and
         the conv layer; :meth:`__call__` adds complex-array packing on top.
         An ``axis=-2`` complex plan executes as an in-place column pass on
-        axis-capable backends — no materialized transpose.
+        axis-capable backends — no materialized transpose.  The work is
+        named by the spec's kind, as in :meth:`__call__`.
         """
+        with jax.named_scope(self.spec.kind):
+            return self._planes(xr, xi)
+
+    def _planes(self, xr: jax.Array, xi: jax.Array) -> Planes:
         kind = self.spec.kind
         if kind in ("fft2", "ifft2"):
             return self._fft2_planes(xr, xi)
@@ -793,20 +798,30 @@ class PlannedFFT:
         conservation (complex kinds) at :data:`PARSEVAL_RTOL` — a cheap
         structured detector for silent corruption on degraded or unfamiliar
         hardware paths.
+
+        The compiled program names the transform's work by the spec's kind
+        (``fft``, ``rfft2``, ...), the complex boundary ``to_planes`` /
+        ``from_planes``, the Hermitian epilogue ``recomb`` and each pass
+        ``p{i}_rows`` / ``p{i}_cols`` (``jax.named_scope``: metadata that a
+        profiler trace carries, nothing at run time).  Child plans run
+        inside their parent's scope.
         """
         kind = self.spec.kind
-        if kind in _COMPLEX_KINDS or kind in ("fft2", "ifft2"):
-            xr, xi, was_c = _split(x)
-            yr, yi = self.apply_planes(xr, xi)
-            out = _join(yr, yi, was_c)
-        elif kind == "rfft":
-            out = self._rfft(x)
-        elif kind == "irfft":
-            out = self._irfft(x)
-        elif kind == "rfft2":
-            out = self._rfft2(x)
-        else:
-            out = self._irfft2(x)
+        with jax.named_scope(kind):
+            if kind in _COMPLEX_KINDS or kind in ("fft2", "ifft2"):
+                with jax.named_scope("to_planes"):
+                    xr, xi, was_c = _split(x)
+                yr, yi = self._planes(xr, xi)
+                with jax.named_scope("from_planes"):
+                    out = _join(yr, yi, was_c)
+            elif kind == "rfft":
+                out = self._rfft(x)
+            elif kind == "irfft":
+                out = self._irfft(x)
+            elif kind == "rfft2":
+                out = self._rfft2(x)
+            else:
+                out = self._irfft2(x)
         if check is not None:
             self._run_check(x, out, check)
         return out
@@ -979,6 +994,7 @@ class PlannedFFT:
         (pallas backend) instead of traced XLA glue."""
         return self.backend.name == "pallas" and self.epilogue is not None
 
+    @jax.named_scope("recomb")
     def _recomb_fwd(self, Zr, Zi) -> Planes:
         """Forward Hermitian recombination over the last axis: the packed
         (..., m) spectrum → (..., m+1) real-FFT bins.  One Pallas epilogue
@@ -1012,6 +1028,7 @@ class PlannedFFT:
         wr, wi = jnp.asarray(wr_np), jnp.asarray(wi_np)
         return fft_xla.rfft_recomb(Zr, Zi, wr, wi)
 
+    @jax.named_scope("recomb")
     def _recomb_inv(self, Xr, Xi) -> Planes:
         """Inverse recombination over the last axis: (..., m+1) bins → the
         packed (..., m) spectrum (mirror of :meth:`_recomb_fwd`)."""

@@ -120,6 +120,7 @@ def _resolve_block(
     return tuning.tuned_block(L, filter_len, batch, backend, mode, chunk=chunk)
 
 
+@jax.named_scope("os_frame")
 def frame_signal(
     x: jax.Array, block: int, step: int, num_blocks: int
 ) -> jax.Array:
@@ -145,6 +146,7 @@ def frame_signal(
     return jnp.take(xp, jnp.asarray(idx, np.int32), axis=-1, mode="clip")
 
 
+@jax.named_scope("os_filter")
 def filter_spectrum(
     h: jax.Array, block: int, backend: Optional[str] = None
 ) -> Planes:
@@ -174,15 +176,18 @@ def conv_frames(
     first ``overlap`` samples of each block alias history that belongs to
     the previous block.  Returns ``(..., nb, B − overlap)``.  Also the body
     of the sharded variant — it is collective-free, so blocks shard over a
-    mesh axis with no all-to-alls.
+    mesh axis with no all-to-alls.  The product and the discard are named
+    ``os_product`` and ``os_discard``; the transforms by their kinds.
     """
     block = frames.shape[-1]
     fwd = fft_lib.plan(fft_lib.FFTSpec(n=block, kind="rfft"), backend=backend)
     inv = fft_lib.plan(fft_lib.FFTSpec(n=block, kind="irfft"), backend=backend)
     Fr, Fi = fwd(frames)
-    Yr, Yi = cmul(Fr, Fi, Hr, Hi)
+    with jax.named_scope("os_product"):
+        Yr, Yi = cmul(Fr, Fi, Hr, Hi)
     y = inv((Yr, Yi))
-    return y[..., overlap:]
+    with jax.named_scope("os_discard"):
+        return y[..., overlap:]
 
 
 def fft_conv_os(
@@ -226,11 +231,12 @@ def fft_conv_os(
     frames = frame_signal(x, B, step, nb)
     Hr, Hi = filter_spectrum(h, B, backend)
     tails = conv_frames(frames, Hr, Hi, overlap=overlap, backend=backend)
-    lead = tails.shape[:-2]
-    y = tails.reshape(*lead, nb * step)[..., :L_out]
-    if axis != -1:
-        y = jnp.moveaxis(y, -1, axis)
-    return y.astype(out_dtype)
+    with jax.named_scope("os_tail"):
+        lead = tails.shape[:-2]
+        y = tails.reshape(*lead, nb * step)[..., :L_out]
+        if axis != -1:
+            y = jnp.moveaxis(y, -1, axis)
+        return y.astype(out_dtype)
 
 
 def _stream_conv(
@@ -263,9 +269,10 @@ def _stream_conv(
     nb = -(-L // step)
     frames = frame_signal(xin, block, step, nb)
     tails = conv_frames(frames, Hr, Hi, overlap=overlap, backend=backend)
-    lead = tails.shape[:-2]
-    y = tails.reshape(*lead, nb * step)[..., :L]
-    return y[..., overlap:]
+    with jax.named_scope("os_tail"):
+        lead = tails.shape[:-2]
+        y = tails.reshape(*lead, nb * step)[..., :L]
+        return y[..., overlap:]
 
 
 def stream_lookahead(

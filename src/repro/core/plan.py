@@ -80,11 +80,47 @@ __all__ = [
     "check_tpu_vmem",
     "describe",
     "describe_program",
+    "KERNEL_NAMES",
+    "kernel_name",
+    "pass_scope",
 ]
 
 # DIRECT_MAX / FUSED_MAX / VMEM_BUDGET are defined in repro.core.limits (the
 # single source for every regime threshold) and re-exported here because the
 # planner is where the rest of the codebase historically imported them from.
+
+
+#: The name of every ``pallas_call`` under ``repro.kernels``, one per
+#: kernel family (``_gpu``: the Triton-shaped variants).  The compiled HLO
+#: names each kernel instruction after it, so a profiler trace says which
+#: kernel ran; the scopes of :func:`pass_scope` and the transform kinds
+#: say which pass of which transform.
+KERNEL_NAMES = (
+    "dft_direct",
+    "fft4step",
+    "pencil_cols",
+    "pencil_rows_natural",
+    "pencil_cols_natural",
+    "recomb_fwd",
+    "recomb_inv",
+    "bluestein_fwd",
+    "bluestein_inv",
+    "bluestein_elem",
+    "dft_direct_gpu",
+    "fft4step_gpu",
+    "pencil_rows_natural_gpu",
+    "bluestein_fwd_gpu",
+    "bluestein_inv_gpu",
+    "bluestein_elem_gpu",
+)
+
+
+def kernel_name(family: str, gpu: bool = False) -> str:
+    """The ``pallas_call`` name of a kernel family, from :data:`KERNEL_NAMES`."""
+    name = f"{family}_gpu" if gpu else family
+    if name not in KERNEL_NAMES:
+        raise ValueError(f"{name!r} is not a kernel name of KERNEL_NAMES")
+    return name
 
 
 def _is_pow2(n: int) -> bool:
@@ -211,6 +247,17 @@ class FFTPlan:
             if p.n == m:
                 return p
         raise KeyError(f"length {m} is not a leaf of the plan for n={self.n}")
+
+
+def pass_scope(index: int, p: Pass) -> str:
+    """The named scope of pass ``index`` of a program: ``p{index}_reorder``,
+    ``p{index}_cols`` for a strided-column pass (axis -2, or a 1-D pass over
+    an interleaved column view), else ``p{index}_rows``."""
+    if p.kind == "reorder":
+        return f"p{index}_reorder"
+    if p.axis == -2 or (p.view_in and p.view_in[1] > 1):
+        return f"p{index}_cols"
+    return f"p{index}_rows"
 
 
 def four_step_split(n: int) -> tuple[int, int]:

@@ -39,6 +39,7 @@ from jax.experimental.pallas import triton as plt
 
 from repro.core.fft_xla import cmul
 from repro.core.limits import VMEM_LIMIT
+from repro.core.plan import kernel_name
 from repro.kernels.dft_matmul import dft_tile
 from repro.kernels.fft4step import four_step_rows
 
@@ -137,6 +138,7 @@ def bluestein_fwd_call(
     in_specs += [spec, spec]
     fn = pl.pallas_call(
         kernel,
+        name=kernel_name("bluestein_fwd", gpu),
         grid=(b // batch_tile,),
         in_specs=in_specs,
         out_specs=[sig_out, sig_out],
@@ -192,6 +194,7 @@ def bluestein_inv_call(
     in_specs += [chirp, chirp]
     fn = pl.pallas_call(
         kernel,
+        name=kernel_name("bluestein_inv", gpu),
         grid=(b // batch_tile,),
         in_specs=in_specs,
         out_specs=[sig_out, sig_out],
@@ -250,6 +253,7 @@ def bluestein_elem_call(
     lut = pl.BlockSpec((1, w_lut), lambda i: (0, 0))
     fn = pl.pallas_call(
         kernel,
+        name=kernel_name("bluestein_elem", gpu),
         grid=(b // batch_tile,),
         in_specs=[sig_in, sig_in, lut, lut],
         out_specs=[sig_out, sig_out],

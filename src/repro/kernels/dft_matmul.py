@@ -33,6 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft_xla import cmul
 from repro.core.limits import VMEM_LIMIT
+from repro.core.plan import kernel_name
 
 __all__ = ["dft_matmul_call", "dft_tile"]
 
@@ -103,6 +104,7 @@ def dft_matmul_call(
     ]
     fn = pl.pallas_call(
         _make_kernel(twiddle is not None),
+        name=kernel_name("dft_direct"),
         grid=grid,
         in_specs=in_specs,
         out_specs=[sig_spec, sig_spec],

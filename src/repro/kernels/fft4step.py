@@ -46,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft_xla import cmul
 from repro.core.limits import VMEM_LIMIT, row_group
+from repro.core.plan import kernel_name
 
 __all__ = ["fft4step_call", "four_step_rows", "four_step_tile", "cgemm_tile"]
 
@@ -224,6 +225,7 @@ def fft4step_call(
     ]
     fn = pl.pallas_call(
         _make_kernel(n1, n2, natural_order, twiddle_after is not None),
+        name=kernel_name("fft4step"),
         grid=grid,
         in_specs=in_specs,
         out_specs=[sig, sig],

@@ -119,6 +119,7 @@ def dft_matmul_gpu_call(
     lut = pl.BlockSpec((n, n), lambda i: (0, 0))
     fn = pl.pallas_call(
         kernel,
+        name=plan_lib.kernel_name("dft_direct", gpu=True),
         grid=(b // batch_tile,),
         in_specs=[sig, sig, lut, lut],
         out_specs=[sig, sig],
@@ -167,6 +168,7 @@ def fft4step_gpu_call(
     lut2 = pl.BlockSpec((n2, n2), lambda i: (0, 0))
     fn = pl.pallas_call(
         kernel,
+        name=plan_lib.kernel_name("fft4step", gpu=True),
         grid=(b // batch_tile,),
         in_specs=[sig, sig, lut1, lut1, lutt, lutt, lut2, lut2],
         out_specs=[sig, sig],
@@ -202,6 +204,7 @@ def rows_natural_gpu_call(
     )
     fn = pl.pallas_call(
         pencil._make_rows_kernel(kind, n1, n2, len(luts), scratch=False),
+        name=plan_lib.kernel_name("pencil_rows_natural", gpu=True),
         grid=(b, p // chunk),
         in_specs=in_specs,
         out_specs=[out_sig, out_sig],

@@ -14,11 +14,18 @@ program pass is exactly one ``pallas_call`` HBM round trip:
   when the natural-order transpose is fused into its strided write, or the
   plain leaf kernel for pencil-order output.
 
-Between passes the executor only reshapes (row-major views — no data
-movement); there are **zero** standalone HBM ``swapaxes``/transpose or
-twiddle ``cmul`` ops in the schedule, which is what makes the split regime
-match the paper's §2.3.2 call-count discipline (and beat it: two round trips
-cover every N ≤ 2³²).  The tests assert this over the jaxpr.
+Between passes the executor only reshapes; there are **zero** standalone
+``swapaxes``/transpose or twiddle ``cmul`` ops in the schedule, which is
+what makes the split regime match the paper's §2.3.2 call-count discipline
+(and beat it: two round trips cover every N ≤ 2³²).  The tests assert this
+over the jaxpr.  A reshape is free only where XLA can keep the array's
+tiled TPU layout ((8, 128) tiles over the last two dims): flattening a
+leading dim that does not fill whole tiles into the batch, or an input
+that its producer left in another layout, makes XLA copy the array through
+HBM.  The overlap-save convolution hits both (its frames, 9 per channel,
+come from a gather); the compiled program names those copies by the scope
+they run in (``rfft/reshape``, ``irfft/concatenate``), and each pass runs
+under ``p{i}_rows`` / ``p{i}_cols`` (:func:`repro.core.plan.pass_scope`).
 
 Responsibilities handled here so kernels stay minimal: batch flattening and
 tile padding, LUT construction (host-cached, inverse scaling folded into W2 /
@@ -475,21 +482,23 @@ def execute_program(
 ) -> Planes:
     """Walk a linearized pass program over 2-D (B, n) split planes.
 
-    One ``pallas_call`` per pass; the only ops between passes are row-major
-    reshapes (views, no HBM traffic).  ``chunks`` (pass index → grid-step
-    width) carries the tuner's per-pass picks; unlisted passes fall back to
-    the VMEM-budget heuristic.  ``degradations`` (a plan's ledger) collects
+    One ``pallas_call`` per pass, under the scope ``p{i}_rows`` /
+    ``p{i}_cols``; the only ops between passes are row-major reshapes
+    (free where the tiled layout allows, see the module docstring).
+    ``chunks`` (pass index → grid-step width) carries the tuner's per-pass
+    picks; unlisted passes fall back to the VMEM-budget heuristic.  ``degradations`` (a plan's ledger) collects
     any leaf demoted to the traced-XLA fallback.
     """
     if interpret is None:
         interpret = should_interpret()
     fs = [q.n for q in passes if q.kind != "reorder"]
     for i, p in enumerate(passes):
-        xr, xi = _apply_pass(
-            xr, xi, p, fs, inverse, interpret, batch_tiles,
-            chunk=chunks.get(i) if chunks else None,
-            degradations=degradations, index=i,
-        )
+        with jax.named_scope(plan_lib.pass_scope(i, p)):
+            xr, xi = _apply_pass(
+                xr, xi, p, fs, inverse, interpret, batch_tiles,
+                chunk=chunks.get(i) if chunks else None,
+                degradations=degradations, index=i,
+            )
     return xr, xi
 
 
@@ -522,19 +531,20 @@ def execute_program2d(
         # mid-program (n → pad → n).
         b, rows, n = xr.shape
         chunk = chunks.get(i) if chunks else None
-        if p.axis == -2:
-            xr, xi = _cols_image_pass(
-                xr, xi, p, inverse, interpret, chunk=chunk,
+        with jax.named_scope(plan_lib.pass_scope(i, p)):
+            if p.axis == -2:
+                xr, xi = _cols_image_pass(
+                    xr, xi, p, inverse, interpret, chunk=chunk,
+                    degradations=degradations, index=i,
+                )
+                continue
+            xr2, xi2 = _apply_pass(
+                xr.reshape(b * rows, n), xi.reshape(b * rows, n),
+                p, fs, inverse, interpret, batch_tiles, chunk=chunk,
                 degradations=degradations, index=i,
             )
-            continue
-        xr2, xi2 = _apply_pass(
-            xr.reshape(b * rows, n), xi.reshape(b * rows, n),
-            p, fs, inverse, interpret, batch_tiles, chunk=chunk,
-            degradations=degradations, index=i,
-        )
-        w = xr2.shape[-1]
-        xr, xi = xr2.reshape(b, rows, w), xi2.reshape(b, rows, w)
+            w = xr2.shape[-1]
+            xr, xi = xr2.reshape(b, rows, w), xi2.reshape(b, rows, w)
     return xr, xi
 
 
@@ -614,10 +624,11 @@ def execute_plan(
         b = int(np.prod(lead)) if lead else 1
         if len(fft_plan.passes) == 1 and fft_plan.n > 1:
             p = _cols_plan_pass(fft_plan, q)
-            yr, yi = _cols_image_pass(
-                xr.reshape(b, n, q), xi.reshape(b, n, q), p, inverse, interpret,
-                degradations=degradations,
-            )
+            with jax.named_scope(plan_lib.pass_scope(0, p)):
+                yr, yi = _cols_image_pass(
+                    xr.reshape(b, n, q), xi.reshape(b, n, q), p, inverse, interpret,
+                    degradations=degradations,
+                )
             return yr.reshape(*lead, n, q), yi.reshape(*lead, n, q)
         xr, xi = jnp.swapaxes(xr, -1, -2), jnp.swapaxes(xi, -1, -2)
         yr, yi = execute_plan(

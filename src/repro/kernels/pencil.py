@@ -48,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fft_xla import cmul
 from repro.core.limits import LANES, SUBLANES, VMEM_LIMIT
+from repro.core.plan import kernel_name
 from repro.kernels.dft_matmul import dft_tile
 from repro.kernels.fft4step import four_step_rows, four_step_tile
 
@@ -208,6 +209,7 @@ def cols_pass_call(
         _make_cols_kernel(
             kind, n1, n2, len(luts), tw, chunk, tw_every or 0, tw_rows
         ),
+        name=kernel_name("pencil_cols"),
         grid=grid,
         in_specs=in_specs,
         out_specs=[sig, sig],
@@ -263,6 +265,7 @@ def rows_natural_call(
     ]
     fn = pl.pallas_call(
         _make_rows_kernel(kind, n1, n2, len(luts)),
+        name=kernel_name("pencil_rows_natural"),
         grid=grid,
         in_specs=in_specs,
         out_specs=[out_sig, out_sig],
@@ -329,6 +332,7 @@ def cols_natural_call(
     ]
     fn = pl.pallas_call(
         _make_cols_natural_kernel(kind, n1, n2, len(luts)),
+        name=kernel_name("pencil_cols_natural"),
         grid=grid,
         in_specs=in_specs,
         out_specs=[out_sig, out_sig],
@@ -431,6 +435,7 @@ def _recomb_tiled(fwd, zr, zi, wr, wi, m, interpret):
 
     fn = pl.pallas_call(
         kernel,
+        name=kernel_name("recomb_fwd" if fwd else "recomb_inv"),
         grid=(pl.cdiv(b, bt), pl.cdiv(w_out, c)),
         in_specs=[blk(here)] * 2 + [blk(lo)] * 2 + [blk(hi)] * 2 + [w_spec] * 2,
         out_specs=[blk(lambda i, j: (i, j))] * 2,
@@ -475,6 +480,7 @@ def _recomb_small(fwd, zr, zi, wr, wi, m, interpret):
 
     fn = pl.pallas_call(
         kernel,
+        name=kernel_name("recomb_fwd" if fwd else "recomb_inv"),
         grid=(pl.cdiv(b, bt),),
         in_specs=[sig_in, sig_in, mat, mat, w_spec, w_spec],
         out_specs=[sig_out, sig_out],

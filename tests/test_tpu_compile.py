@@ -5,7 +5,10 @@ block tiling or VMEM; the TPU compiler does, and it is installed here.  Each
 case compiles one kernel family at a real width, with ``interpret=False``,
 for a v5e that is described rather than attached, and asserts that the
 compiled program holds the kernel (``tpu_custom_call``).  Nothing runs, so
-these cases say nothing about results or time.
+these cases say nothing about results or time.  The benchmark cells'
+programs, compiled the same way at test size, must name every kernel
+instruction by its family and put it under its pass's scope, which is what
+a profiler trace of the chip shows.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and the suite runs in several).
@@ -13,11 +16,15 @@ only one process may load the TPU library, and the suite runs in several).
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import fft as F
+from repro.core import overlap
 from repro.core import plan as P
 from repro.core import twiddle as tw
 from repro.kernels import ops, pencil
@@ -117,4 +124,83 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     # The compiled program is the kernel plus layout glue: no XLA FFT or
     # dot stands in for it.
     assert " dot(" not in text and " fft(" not in text, case
+
+
+
+# -- the benchmark cells' programs: kernels and passes named ---------------
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", re.M)
+_KERNEL = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*custom_call_target="tpu_custom_call"'
+    r'.*?metadata=\{op_name="([^"]*)"',
+    re.M,
+)
+_OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
+_WHERE = re.compile(r"p\d+_(rows|cols)|recomb")
+OS_SCOPES = {"os_frame", "os_filter", "os_product", "os_discard", "os_tail"}
+
+
+def _planned(spec, shape, dtype, sharding):
+    fn = jax.jit(F.plan(spec, backend="pallas"))
+    return fn, (jax.ShapeDtypeStruct(shape, dtype, sharding=sharding),)
+
+
+def _conv(sharding):
+    fn = jax.jit(lambda x, h: overlap.fft_conv_os(x, h, block=4096, backend="pallas"))
+    return fn, (
+        jax.ShapeDtypeStruct((8, 20000), jnp.float32, sharding=sharding),
+        jax.ShapeDtypeStruct((257,), jnp.float32, sharding=sharding),
+    )
+
+
+# cell → (maker of (jitted program, argument shapes) at test size, the
+# pass scopes its kernels run under)
+CELLS = {
+    "sar_fft2": (
+        lambda sh: _planned(F.FFTSpec(n=2048, kind="fft2", n2=1024), (2, 1024, 2048), jnp.complex64, sh),
+        {"p0_rows", "p1_cols"},
+    ),
+    "sar_range_fft": (
+        lambda sh: _planned(F.FFTSpec(n=8192), (64, 8192), jnp.complex64, sh),
+        {"p0_rows"},
+    ),
+    "conv_os_4097": (_conv, {"p0_rows", "recomb"}),
+}
+
+
+@pytest.fixture
+def real_kernels(monkeypatch):
+    """Lower the Pallas kernels for the chip, not for the interpreter."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_program_names_kernels_and_passes(cell, one_chip, no_persistent_cache, real_kernels):
+    build, want = CELLS[cell]
+    fn, args = build(one_chip)
+    text = fn.lower(*args).compile().as_text()
+    unnamed = [n for n in _INSTR.findall(text) if n.startswith(("_unknown_", "_lambda_"))]
+    assert not unnamed, unnamed
+    kernels = _KERNEL.findall(text)
+    assert kernels and len(kernels) == text.count('custom_call_target="tpu_custom_call"'), cell
+    seen = set()
+    for name, path in kernels:
+        family = re.sub(r"\.\d+$", "", name)
+        assert family in P.KERNEL_NAMES, name
+        scopes = path.split("/")[:-1]  # the last part is the primitive
+        assert scopes[-1] == family, path
+        where = [s for s in scopes if _WHERE.fullmatch(s)]
+        assert where, path
+        seen.add(where[-1])
+        # a row pass runs a row kernel, a column pass a strided-column
+        # kernel, the Hermitian epilogue a recomb kernel
+        if where[-1] == "recomb":
+            assert family.startswith("recomb_"), path
+        elif where[-1].endswith("_cols"):
+            assert family.startswith("pencil_cols"), path
+        else:
+            assert not family.startswith(("pencil_cols", "recomb_")), path
+    assert seen == want, (cell, seen)
+    named = {s for found in _OP_NAME.findall(text) for p in found.split(";") for s in p.split("/")}
+    assert OS_SCOPES <= named if cell == "conv_os_4097" else not OS_SCOPES & named
 
