@@ -271,6 +271,17 @@ def four_step_split(n: int) -> tuple[int, int]:
     return n1, n2
 
 
+def four_step_group(p: Pass) -> str:
+    """The row group a fused four-step leaf runs
+    (:func:`repro.kernels.fft4step.four_step_rows`), as ``describe()``
+    names it: one signal at a time once ``n1`` fills a 128-row MXU
+    operand, else a lane group of ``n2 // n1`` signals per GEMM."""
+    g = row_group(p.n1, p.n2)
+    if g == 1:
+        return "one-signal group"
+    return f"lane group: {p.n2 // p.n1} signals per GEMM, {g} a step"
+
+
 def _leaf_pass(n: int, direct_max: int = DIRECT_MAX) -> Pass:
     """The leaf engine decision: a direct DFT matmul up to ``direct_max``
     (one GEMM, but an n² LUT), the fused four-step beyond (two √n-sized
@@ -599,9 +610,12 @@ def plan_fft2(
 
 
 def _lut_bytes(p: Pass) -> int:
-    """The transform LUTs a leaf keeps resident (split-complex float32)."""
+    """The transform LUTs a leaf keeps resident (split-complex float32);
+    a lane group's (``n1 < LANES``) are three (n2, n2) grids."""
     if p.kind == "direct":
         return p.n * p.n * 8
+    if row_group(p.n1, p.n2) > 1:
+        return 3 * p.n2 * p.n2 * 8
     return (p.n1 * p.n1 + p.n2 * p.n2 + p.n1 * p.n2) * 8
 
 
@@ -609,7 +623,7 @@ def _row_group_bytes(p: Pass) -> int:
     """In-kernel intermediates of the four-step's row-group loop
     (:func:`repro.kernels.fft4step.four_step_rows`): ~12 group-sized
     split-complex arrays (see :func:`~repro.core.limits.row_group`)."""
-    return 12 * row_group(p.n1) * p.n * 8
+    return 12 * row_group(p.n1, p.n2) * p.n * 8
 
 
 def vmem_bytes(p: Pass, batch_tile: int) -> int:
@@ -620,9 +634,10 @@ def vmem_bytes(p: Pass, batch_tile: int) -> int:
     ``sig``): the in and out blocks are double-buffered (4·sig), the LUTs
     are resident, and the body's intermediates come on top — whole-tile
     GEMM temporaries for the direct DFT (measured 11.3 MiB at n=1024,
-    bt=64, model 11.5), one row group's relayouts for the four-step
-    (measured 21.2 MiB at n=65536, bt=8, model 23.5; 10.8 MiB at n=4096,
-    bt=64, model 11.2).  Used to pick the batch tile against
+    bt=64, model 11.5), one row group's intermediates for the four-step
+    (measured 20.9 MiB at n=65536, bt=8, model 23.5; lane groups 20.7 MiB
+    at n=8192, bt=64, model 28.4, and 19.9 MiB at n=4096, bt=128, model
+    22.4).  Used to pick the batch tile against
     :data:`~repro.core.limits.VMEM_BUDGET`.
     """
     if p.kind == "bluestein":
@@ -890,7 +905,7 @@ def describe_program(p: FFTPlan, batch: int = 1) -> str:
         algo = (
             f"direct DFT n={f}"
             if ps.kind == "direct"
-            else f"fused four-step n={f} ({ps.n1} x {ps.n2})"
+            else f"fused four-step n={f} ({ps.n1} x {ps.n2}, {four_step_group(ps)})"
         )
         if ps.axis == -2 and pencils > 1:
             layout = (

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas import triton as plt
@@ -66,16 +67,9 @@ def _params(gpu: bool, interpret: bool) -> dict:
     }
 
 
-def _inner_specs(inner_kind: str, m_pad: int, in1: int, in2: int) -> list:
+def _inner_specs(inner_luts) -> list:
     """BlockSpecs of the pad-length transform's LUT operands."""
-    pin = lambda i: (0, 0)  # noqa: E731
-    if inner_kind == "direct":
-        lut = pl.BlockSpec((m_pad, m_pad), pin)
-        return [lut, lut]
-    lut1 = pl.BlockSpec((in1, in1), pin)
-    lutt = pl.BlockSpec((in1, in2), pin)
-    lut2 = pl.BlockSpec((in2, in2), pin)
-    return [lut1, lut1, lutt, lutt, lut2, lut2]
+    return [pl.BlockSpec(np.shape(a), lambda i: (0, 0)) for a in inner_luts]
 
 
 def _inner_transform(x_r, x_i, inner, inner_kind: str, in1: int, in2: int, write):
@@ -134,7 +128,7 @@ def bluestein_fwd_call(
     chirp = pl.BlockSpec((1, n), lambda i: (0, 0))
     spec = pl.BlockSpec((1, m_pad), lambda i: (0, 0))
     in_specs = [sig_in, sig_in, chirp, chirp]
-    in_specs += _inner_specs(inner_kind, m_pad, in1, in2)
+    in_specs += _inner_specs(luts[2:-2])
     in_specs += [spec, spec]
     fn = pl.pallas_call(
         kernel,
@@ -190,7 +184,7 @@ def bluestein_inv_call(
     sig_out = pl.BlockSpec((batch_tile, n), lambda i: (i, 0))
     chirp = pl.BlockSpec((1, n), lambda i: (0, 0))
     in_specs = [sig_in, sig_in]
-    in_specs += _inner_specs(inner_kind, m_pad, in1, in2)
+    in_specs += _inner_specs(luts[:-2])
     in_specs += [chirp, chirp]
     fn = pl.pallas_call(
         kernel,

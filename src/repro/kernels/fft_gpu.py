@@ -149,9 +149,11 @@ def fft4step_gpu_call(
 ) -> Planes:
     """Triton-shaped fused four-step FFT, x (B, n1·n2) split-complex."""
     b, n = xr.shape
-    n1, n2 = w1r.shape[0], w2r.shape[0]
+    n2 = twr.shape[1]
+    n1 = n // n2
     assert n == n1 * n2, (n, n1, n2)
     assert b % batch_tile == 0, (b, batch_tile)
+    luts = [w1r, w1i, twr, twi, w2r, w2i]
 
     def kernel(x_r, x_i, w1_r, w1_i, t_r, t_i, w2_r, w2_i, o_r, o_i):
         yr, yi = four_step_tile(
@@ -163,14 +165,11 @@ def fft4step_gpu_call(
         o_i[...] = yi
 
     sig = pl.BlockSpec((batch_tile, n), lambda i: (i, 0))
-    lut1 = pl.BlockSpec((n1, n1), lambda i: (0, 0))
-    lutt = pl.BlockSpec((n1, n2), lambda i: (0, 0))
-    lut2 = pl.BlockSpec((n2, n2), lambda i: (0, 0))
     fn = pl.pallas_call(
         kernel,
         name=plan_lib.kernel_name("fft4step", gpu=True),
         grid=(b // batch_tile,),
-        in_specs=[sig, sig, lut1, lut1, lutt, lutt, lut2, lut2],
+        in_specs=[sig, sig] + pencil._lut_specs(luts, lambda i: (0, 0)),
         out_specs=[sig, sig],
         out_shape=[
             jax.ShapeDtypeStruct((b, n), jnp.float32),
@@ -179,7 +178,7 @@ def fft4step_gpu_call(
         interpret=interpret,
         **_call_kwargs(interpret),
     )
-    return tuple(fn(xr, xi, w1r, w1i, twr, twi, w2r, w2i))
+    return tuple(fn(xr, xi, *luts))
 
 
 def rows_natural_gpu_call(
@@ -199,9 +198,7 @@ def rows_natural_gpu_call(
     assert p % chunk == 0, (p, chunk)
     in_sig = pl.BlockSpec((1, chunk, f), lambda i, j: (i, j, 0))
     out_sig = pl.BlockSpec((1, f, chunk), lambda i, j: (i, 0, j))
-    in_specs = [in_sig, in_sig] + pencil._lut_specs(
-        kind, f, n1, n2, lambda i, j: (0, 0)
-    )
+    in_specs = [in_sig, in_sig] + pencil._lut_specs(luts, lambda i, j: (0, 0))
     fn = pl.pallas_call(
         pencil._make_rows_kernel(kind, n1, n2, len(luts), scratch=False),
         name=plan_lib.kernel_name("pencil_rows_natural", gpu=True),
@@ -239,7 +236,7 @@ def _leaf_kernel_gpu(
             batch_tile=bt, interpret=interpret,
         )
     else:
-        w1r, w1i, tr, ti, w2r, w2i = ops._fused_luts(p.n1, p.n2, inverse)
+        w1r, w1i, tr, ti, w2r, w2i = ops._fused_luts(p.n1, p.n2, inverse, natural_order)
         yr, yi = fft4step_gpu_call(
             xr, xi,
             jnp.asarray(w1r), jnp.asarray(w1i),
@@ -310,10 +307,11 @@ def _xla_pass(xr, xi, p: plan_lib.Pass, fs, inverse) -> Planes:
     if p.kind == "bluestein":
         return _bluestein_xla_pass(xr, xi, p, inverse)
     pencils, stride, f = p.view_in if p.view_in else (1, 1, p.n)
-    luts = ops._transform_luts(p, inverse)
     if pencils == 1:
-        yr, yi = _row_transform_xla(xr, xi, p, luts, natural=p.order == "natural")
-        return yr, yi
+        natural = p.order == "natural"
+        luts = ops._transform_luts(p, inverse, natural)
+        return _row_transform_xla(xr, xi, p, luts, natural=natural)
+    luts = ops._transform_luts(p, inverse)
     if stride == 1:
         rr = xr.reshape(b * pencils, f)
         ri = xi.reshape(b * pencils, f)

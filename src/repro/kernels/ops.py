@@ -47,9 +47,9 @@ import numpy as np
 from repro.core import faults
 from repro.core import plan as plan_lib
 from repro.core import twiddle as tw
-from repro.core.limits import LANES, SUBLANES
+from repro.core.limits import LANES, SUBLANES, row_group
 from repro.kernels.dft_matmul import dft_matmul_call
-from repro.kernels.fft4step import fft4step_call
+from repro.kernels.fft4step import fft4step_call, lane_group_luts
 from repro.kernels import pencil
 
 Planes = Tuple[jax.Array, jax.Array]
@@ -81,13 +81,18 @@ def _direct_luts(n: int, inverse: bool):
 
 
 @functools.lru_cache(maxsize=256)
-def _fused_luts(n1: int, n2: int, inverse: bool):
+def _fused_luts(n1: int, n2: int, inverse: bool, natural_order: bool = True):
+    """The fused four-step leaf's LUTs (W1, T, W2), 1/N folded into W2 for
+    the inverse; a natural-order lane group (``n1 < LANES``) takes them
+    rearranged by :func:`~repro.kernels.fft4step.lane_group_luts`."""
     w1r, w1i = tw.dft_matrix(n1, inverse)
     tr, ti = tw.twiddle_grid(n1, n2, inverse)
     w2r, w2i = tw.dft_matrix(n2, inverse)
     if inverse:
         s = np.float32(1.0 / (n1 * n2))
         w2r, w2i = w2r * s, w2i * s
+    if natural_order and row_group(n1, n2) > 1:
+        return lane_group_luts(w1r, w1i, tr, ti, w2r, w2i)
     return w1r, w1i, tr, ti, w2r, w2i
 
 
@@ -107,10 +112,10 @@ def _pass_twiddle_rows(n_bins: int, n_phases: int, inverse: bool):
     )
 
 
-def _transform_luts(p: plan_lib.Pass, inverse: bool):
+def _transform_luts(p: plan_lib.Pass, inverse: bool, natural_order: bool = True):
     if p.kind == "direct":
         return _direct_luts(p.n, inverse)
-    return _fused_luts(p.n1, p.n2, inverse)
+    return _fused_luts(p.n1, p.n2, inverse, natural_order)
 
 
 def _bluestein_luts(p: plan_lib.Pass, inverse: bool):
@@ -218,7 +223,7 @@ def _leaf_kernel(
             xr, xi, jnp.asarray(wr), jnp.asarray(wi), batch_tile=bt, interpret=interpret
         )
     else:
-        w1r, w1i, tr, ti, w2r, w2i = _fused_luts(p.n1, p.n2, inverse)
+        w1r, w1i, tr, ti, w2r, w2i = _fused_luts(p.n1, p.n2, inverse, natural_order)
         yr, yi = fft4step_call(
             xr,
             xi,
@@ -386,9 +391,9 @@ def _cols_image_xla(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
     if pencils == 1 or f == rows:
         # Whole-column transform (incl. the distributed driver's synthetic
         # (q, q, n) pass): one natural-order row transform of length rows.
-        luts = _transform_luts(p, inverse)
+        natural = p.order == "natural"
         yr, yi = fft_gpu._row_transform_xla(
-            xt_r, xt_i, p, luts, natural=p.order == "natural"
+            xt_r, xt_i, p, _transform_luts(p, inverse, natural), natural=natural
         )
     else:
         # Strip-mined column factor: the re-tagged 1-D split program of the

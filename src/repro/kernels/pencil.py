@@ -102,14 +102,9 @@ def _split_rest(rest, n_luts: int, kind: str, scratch: bool = True):
     )
 
 
-def _lut_specs(kind: str, f: int, n1: int, n2: int, index_map):
-    if kind == "direct":
-        return [pl.BlockSpec((f, f), index_map)] * 2
-    return (
-        [pl.BlockSpec((n1, n1), index_map)] * 2
-        + [pl.BlockSpec((n1, n2), index_map)] * 2
-        + [pl.BlockSpec((n2, n2), index_map)] * 2
-    )
+def _lut_specs(luts, index_map):
+    """Whole-array BlockSpecs of a pass's LUT operands, one block each."""
+    return [pl.BlockSpec(np.shape(a), index_map) for a in luts]
 
 
 def _as_ops(luts):
@@ -182,7 +177,7 @@ def cols_pass_call(
     assert s % chunk == 0, (s, chunk)
     grid = (r, s // chunk)
     sig = pl.BlockSpec((1, f, chunk), lambda i, j: (i, 0, j))
-    in_specs = [sig, sig] + _lut_specs(kind, f, n1, n2, lambda i, j: (0, 0))
+    in_specs = [sig, sig] + _lut_specs(luts, lambda i, j: (0, 0))
     operands = [xr, xi] + _as_ops(luts)
     tw, tw_rows = None, 0
     if twiddle is not None and tw_every is not None:
@@ -257,7 +252,7 @@ def rows_natural_call(
     grid = (b, p // chunk)
     in_sig = pl.BlockSpec((1, chunk, f), lambda i, j: (i, j, 0))
     out_sig = pl.BlockSpec((1, f, chunk), lambda i, j: (i, 0, j))
-    in_specs = [in_sig, in_sig] + _lut_specs(kind, f, n1, n2, lambda i, j: (0, 0))
+    in_specs = [in_sig, in_sig] + _lut_specs(luts, lambda i, j: (0, 0))
     operands = [xr, xi] + _as_ops(luts)
     out_shape = [
         jax.ShapeDtypeStruct((b, f, p), jnp.float32),
@@ -322,9 +317,7 @@ def cols_natural_call(
     out_sig = pl.BlockSpec(
         (1, f, chunk), lambda i, q, j: (i, 0, q * per_row + j)
     )
-    in_specs = [in_sig, in_sig] + _lut_specs(
-        kind, f, n1, n2, lambda i, q, j: (0, 0)
-    )
+    in_specs = [in_sig, in_sig] + _lut_specs(luts, lambda i, q, j: (0, 0))
     operands = [xr, xi] + _as_ops(luts)
     out_shape = [
         jax.ShapeDtypeStruct((b, f, p * w), jnp.float32),

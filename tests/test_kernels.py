@@ -38,45 +38,42 @@ def test_dft_matmul_vs_naive(n, batch, rng):
     np.testing.assert_allclose(np.asarray(yi), refv.imag, atol=2e-4 * scale)
 
 
-@pytest.mark.parametrize("n1,n2", [(64, 32), (64, 64), (128, 64)])
-@pytest.mark.parametrize("batch_tile", [1, 2])
-def test_fft4step_vs_four_step_ref(n1, n2, batch_tile, rng):
-    n = n1 * n2
-    b = 2 * batch_tile
-    xr, xi = _rand(rng, (b, n))
-    w1r, w1i = tw.dft_matrix(n1)
-    tr, ti = tw.twiddle_grid(n1, n2)
-    w2r, w2i = tw.dft_matrix(n2)
-    yr, yi = fft4step_call(
-        jnp.asarray(xr), jnp.asarray(xi),
-        jnp.asarray(w1r), jnp.asarray(w1i),
-        jnp.asarray(tr), jnp.asarray(ti),
-        jnp.asarray(w2r), jnp.asarray(w2i),
-        batch_tile=batch_tile, interpret=True,
+def _fft4step(xr, xi, n1, n2, batch_tile, natural_order=True, **kw):
+    """fft4step_call with the leaf's own LUTs for that order."""
+    luts = ops._fused_luts(n1, n2, False, natural_order)
+    return fft4step_call(
+        jnp.asarray(xr), jnp.asarray(xi), *(jnp.asarray(a) for a in luts),
+        batch_tile=batch_tile, natural_order=natural_order, interpret=True, **kw,
     )
+
+
+# (n1, n2): the lane groups of n = 2048, 4096 and 8192 (p = 8, 4, 2 signals
+# per 128-lane GEMM), one of p = 1, and a one-signal group (n1 ≥ 128).
+# (b, batch_tile): one signal; tiles smaller than a group; one full group
+# and a ragged tail; two grid steps of two full groups.
+@pytest.mark.parametrize(
+    "n1,n2", [(16, 128), (32, 128), (64, 128), (64, 64), (128, 64)]
+)
+@pytest.mark.parametrize("b,batch_tile", [(1, 1), (2, 1), (4, 2), (13, 13), (32, 16)])
+def test_fft4step_vs_four_step_ref(n1, n2, b, batch_tile, rng):
+    n = n1 * n2
+    xr, xi = _rand(rng, (b, n))
+    yr, yi = _fft4step(xr, xi, n1, n2, batch_tile)
     refv = ref.four_step_ref(xr + 1j * xi, n1, n2)
-    refv2 = ref.naive_dft(xr + 1j * xi)
+    oracle = ref.naive_dft if n <= 4096 else np.fft.fft
+    refv2 = oracle(xr + 1j * xi)
     scale = np.abs(refv).max()
     np.testing.assert_allclose(refv, refv2, atol=1e-9 * scale)  # ref self-check
     np.testing.assert_allclose(np.asarray(yr), refv.real, atol=3e-4 * scale)
     np.testing.assert_allclose(np.asarray(yi), refv.imag, atol=3e-4 * scale)
 
 
-def test_fft4step_pencil_layout(rng):
-    n1, n2 = 64, 64
+@pytest.mark.parametrize("n1,n2", [(64, 64), (32, 128), (64, 128)])
+def test_fft4step_pencil_layout(n1, n2, rng):
     n = n1 * n2
     xr, xi = _rand(rng, (2, n))
-    w1r, w1i = tw.dft_matrix(n1)
-    tr, ti = tw.twiddle_grid(n1, n2)
-    w2r, w2i = tw.dft_matrix(n2)
-    yr, yi = fft4step_call(
-        jnp.asarray(xr), jnp.asarray(xi),
-        jnp.asarray(w1r), jnp.asarray(w1i),
-        jnp.asarray(tr), jnp.asarray(ti),
-        jnp.asarray(w2r), jnp.asarray(w2i),
-        batch_tile=2, natural_order=False, interpret=True,
-    )
-    refv = ref.naive_dft(xr + 1j * xi)
+    yr, yi = _fft4step(xr, xi, n1, n2, 2, natural_order=False)
+    refv = np.fft.fft(xr + 1j * xi)
     # pencil (k1-major): y.reshape(n1, n2)[k1, k2] == X[k1 + n1*k2]
     y = (np.asarray(yr) + 1j * np.asarray(yi)).reshape(2, n1, n2)
     perm = refv.reshape(2, n2, n1).transpose(0, 2, 1)
@@ -84,7 +81,7 @@ def test_fft4step_pencil_layout(rng):
 
 
 @pytest.mark.parametrize("inverse", [False, True])
-@pytest.mark.parametrize("n", [16, 1024, 4096, 16384])
+@pytest.mark.parametrize("n", [16, 1024, 2048, 4096, 8192, 16384])
 def test_ops_fft_all_regimes(n, inverse, rng):
     xr, xi = _rand(rng, (3, n))
     yr, yi = ops.fft(jnp.asarray(xr), jnp.asarray(xi), inverse=inverse, interpret=True)
@@ -141,37 +138,37 @@ def test_dft_matmul_twiddle_epilogue(rng):
     np.testing.assert_allclose(np.asarray(yi), refv.imag, atol=3e-4 * scale)
 
 
-def test_fft4step_twiddle_after_epilogue(rng):
-    n1 = n2 = 64
+@pytest.mark.parametrize("n1,n2", [(64, 64), (16, 128), (64, 128)])
+def test_fft4step_twiddle_after_epilogue(n1, n2, rng):
     n = n1 * n2
     xr, xi = _rand(rng, (2, n))
-    w1r, w1i = tw.dft_matrix(n1)
-    tr, ti = tw.twiddle_grid(n1, n2)
-    w2r, w2i = tw.dft_matrix(n2)
     er, ei = tw.rfft_recomb_twiddle(2 * n)
     er, ei = er[:n], ei[:n]
-    yr, yi = fft4step_call(
-        jnp.asarray(xr), jnp.asarray(xi),
-        jnp.asarray(w1r), jnp.asarray(w1i),
-        jnp.asarray(tr), jnp.asarray(ti),
-        jnp.asarray(w2r), jnp.asarray(w2i),
-        batch_tile=2, twiddle_after=(er, ei), interpret=True,
-    )
-    refv = ref.naive_dft(xr + 1j * xi) * (er + 1j * ei)[None]
+    yr, yi = _fft4step(xr, xi, n1, n2, 2, twiddle_after=(er, ei))
+    refv = np.fft.fft(xr + 1j * xi) * (er + 1j * ei)[None]
     scale = np.abs(refv).max()
     np.testing.assert_allclose(np.asarray(yr), refv.real, atol=4e-4 * scale)
     np.testing.assert_allclose(np.asarray(yi), refv.imag, atol=4e-4 * scale)
 
 
-@pytest.mark.parametrize("n,b", [(4096, 13), (2048, 3), (2029, 11)])
-def test_four_step_ragged_and_short_tiles(n, b, rng):
+@pytest.mark.parametrize(
+    "n,b,n2",
+    [(4096, 13, None), (2048, 3, None), (2029, 11, None), (8192, 1, None),
+     (8192, 13, None), (2048, 21, None), (128, 3, 4096)],
+)
+def test_four_step_ragged_and_short_tiles(n, b, n2, rng):
     """Row-group sweep of the four-step: a tile of fewer rows than a group
     (zero-padded) and a ragged tail (the last full group recomputed, only
-    the tail written) — also in place, through the Bluestein fwd stage."""
-    xr, xi = _rand(rng, (b, n))
-    p = F.plan(F.FFTSpec(n=n), backend="pallas")
+    the tail written) — also in place, through the Bluestein fwd stage and
+    (``n2``) through a 2-D program's in-place column pass of length n2."""
+    if n2 is None:
+        xr, xi = _rand(rng, (b, n))
+        p = F.plan(F.FFTSpec(n=n), backend="pallas")
+    else:
+        xr, xi = _rand(rng, (b, n2, n))
+        p = F.plan(F.FFTSpec(n=n, n2=n2, kind="fft2"), backend="pallas")
     yr, yi = p.apply_planes(jnp.asarray(xr), jnp.asarray(xi))
-    refv = np.fft.fft(xr + 1j * xi)
+    refv = np.fft.fftn(xr + 1j * xi, axes=(-1,) if n2 is None else (-2, -1))
     np.testing.assert_allclose(
         np.asarray(yr) + 1j * np.asarray(yi), refv, atol=3e-4 * np.abs(refv).max()
     )

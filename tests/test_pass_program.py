@@ -126,13 +126,10 @@ def test_cols_pass_matches_axis_fft(f, s, rng):
 
 def test_cols_pass_fused4_kind(rng):
     f, s = 2048, 128  # f > DIRECT_MAX → in-VMEM four-step per pencil
-    n1, n2 = P.balanced_split(f)
+    n1, n2 = P.four_step_split(f)  # a lane group: (16, 128)
     xr, xi = _rand(rng, (1, f, s))
-    w1r, w1i = tw.dft_matrix(n1)
-    tr, ti = tw.twiddle_grid(n1, n2)
-    w2r, w2i = tw.dft_matrix(n2)
     yr, yi = pencil.cols_pass_call(
-        jnp.asarray(xr), jnp.asarray(xi), (w1r, w1i, tr, ti, w2r, w2i),
+        jnp.asarray(xr), jnp.asarray(xi), ops._fused_luts(n1, n2, False),
         kind="fused4", n1=n1, n2=n2, chunk=s, interpret=True,
     )
     ref = np.fft.fft(xr + 1j * xi, axis=1)

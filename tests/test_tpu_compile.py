@@ -97,6 +97,7 @@ CASES = {
     "fused4_4096": lambda: _leaf(4096, 256),
     "fused4_4096_ragged_13_rows": lambda: _leaf(4096, 13),
     "fused4_2048_one_row": lambda: _leaf(2048, 1),
+    "fused4_8192": lambda: _leaf(8192, 64),
     "fused4_65536": lambda: _leaf(65536, 16),
     "cols_pass_2^20": lambda: _row_pass(2**20, 0, 2),
     "rows_natural_2^20": lambda: _row_pass(2**20, 1, 2),
