@@ -46,6 +46,14 @@ path's 6.
 These functions use raw ``jax.lax`` collectives and must run inside a
 ``shard_map`` body (or under jit with the axis bound); :func:`pfft_sharded`
 is the standalone convenience wrapper.
+
+The compiled program names each step (``jax.named_scope``: metadata a
+profiler trace carries, nothing at run time), all inside ``pencil``: the
+transposes ``a2a{step}`` in the order they run, each strip-mined chunk's
+as ``a2a{step}/c{k}`` (its reassembly ``a2a{step}/merge``), the leaves
+``n1_cols`` and ``n2_rows`` (around the leaf plans' own scopes), the
+``twiddle``, the natural-order ``reorder``, and ``pack`` / ``unpack`` for
+stacking and splitting the split-complex pair.
 """
 
 from __future__ import annotations
@@ -318,6 +326,7 @@ def _middle_pipelined(
     k: int,
     la: int,
     compute: Callable,
+    step: int,
 ) -> jax.Array:
     """The pencil schedule's middle section on the packed (2, ..., p, n2)
     stack: transpose to column slabs, run ``compute`` on each column chunk,
@@ -328,7 +337,8 @@ def _middle_pipelined(
 
     ``compute(chunk, col_start, width)`` maps a (2, ..., n1, width) column
     chunk (``col_start`` the traced global column offset of this device's
-    window) to its transformed chunk of the same shape.
+    window) to its transformed chunk of the same shape.  The transposes
+    in and out are the schedule's ``a2a{step}`` and ``a2a{step + 1}``.
     """
     lead = z.shape[:-1]  # (2, *batch, p)
     qk = q // k
@@ -339,23 +349,32 @@ def _middle_pipelined(
         # Columns {j·q + c·qk .. j·q + (c+1)·qk} for every destination j —
         # exactly the slices whose tiled all-to-all lands as this chunk's
         # contiguous (n1, qk) column slab on device j.
-        sl = jax.lax.slice_in_dim(zs, c * qk, (c + 1) * qk, axis=zs.ndim - 1)
-        return _a2a(sl.reshape(*lead, d * qk), axis_name, la + 1, la)
+        with jax.named_scope(f"a2a{step}"), jax.named_scope(f"c{c}"):
+            sl = jax.lax.slice_in_dim(zs, c * qk, (c + 1) * qk, axis=zs.ndim - 1)
+            return _a2a(sl.reshape(*lead, d * qk), axis_name, la + 1, la)
 
     recv = send(0)
     outs = []
     for c in range(k):
         nxt = send(c + 1) if c + 1 < k else None  # next transfer in flight
         y = compute(recv, didx * q + c * qk, qk)
-        outs.append(_a2a(y, axis_name, la, la + 1))  # back to row slabs
+        with jax.named_scope(f"a2a{step + 1}"), jax.named_scope(f"c{c}"):
+            outs.append(_a2a(y, axis_name, la, la + 1))  # back to row slabs
         recv = nxt
-    outs = [o.reshape(*lead, d, qk) for o in outs]
-    out = jnp.stack(outs, axis=-2)  # (..., p, d, k, qk): chunk-major columns
-    return out.reshape(*lead, d * q)
+    with jax.named_scope(f"a2a{step + 1}"), jax.named_scope("merge"):
+        outs = [o.reshape(*lead, d, qk) for o in outs]
+        out = jnp.stack(outs, axis=-2)  # (..., p, d, k, qk): chunk-major columns
+        return out.reshape(*lead, d * q)
 
 
+@jax.named_scope("pack")
 def _pack2(xr, xi):
     return jnp.stack([xr, xi])
+
+
+@jax.named_scope("unpack")
+def _unpack2(z):
+    return z[0], z[1]
 
 
 # ---------------------------------------------------------------------------
@@ -363,6 +382,7 @@ def _pack2(xr, xi):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("pencil")
 def pfft(
     xr: jax.Array,
     xi: jax.Array,
@@ -436,32 +456,40 @@ def pfft(
     lz = la + 1
 
     def col_chunk(chunk, col_start, width):
-        cr, ci = pl.plan_n1.apply_planes(chunk[0], chunk[1])
-        twr, twi = tw.traced_twiddle(
-            n1, n2, inverse, col_start=col_start, col_count=width
-        )
-        cr, ci = cmul(cr, ci, twr, twi)
+        cr, ci = _unpack2(chunk)
+        with jax.named_scope("n1_cols"):
+            cr, ci = pl.plan_n1.apply_planes(cr, ci)
+        with jax.named_scope("twiddle"):
+            twr, twi = tw.traced_twiddle(
+                n1, n2, inverse, col_start=col_start, col_count=width
+            )
+            cr, ci = cmul(cr, ci, twr, twi)
         return _pack2(cr, ci)
 
     z = _middle_pipelined(
         z, axis_name=axis_name, d=d, q=q, k=pl.a2a_chunks, la=lz,
-        compute=col_chunk,
+        compute=col_chunk, step=0,
     )
     # after the transposes back: (2, *lead, p, n2) with full rows.
     # FFT over n2 (last axis, local).  (For inverse=True the two leaf
     # transforms already contribute 1/n1 · 1/n2 = 1/n scaling.)
-    zr, zi = pl.plan_n2.apply_planes(z[0], z[1])
+    zr, zi = _unpack2(z)
+    with jax.named_scope("n2_rows"):
+        zr, zi = pl.plan_n2.apply_planes(zr, zi)
     if not natural_order:
         return zr.reshape(*lead, p * n2), zi.reshape(*lead, p * n2)
     # Final a2a transpose → natural order: C (p, n2) → C^T slab (q2, n1) —
     # one packed collective even though no chunk-overlap applies here.
     q2 = n2 // d
-    z = _a2a(_pack2(zr, zi), axis_name, lz + 1, lz)  # (2, ..., n1, q2)
-    z = jnp.swapaxes(z, -1, -2)  # (q2, n1) = C^T rows = natural order
-    return (
-        z[0].reshape(*lead, q2 * n1),
-        z[1].reshape(*lead, q2 * n1),
-    )
+    z = _pack2(zr, zi)
+    with jax.named_scope("a2a2"):
+        z = _a2a(z, axis_name, lz + 1, lz)  # (2, ..., n1, q2)
+    with jax.named_scope("reorder"):
+        z = jnp.swapaxes(z, -1, -2)  # (q2, n1) = C^T rows = natural order
+        return (
+            z[0].reshape(*lead, q2 * n1),
+            z[1].reshape(*lead, q2 * n1),
+        )
 
 
 def _pfft_serial_unpacked(
@@ -490,6 +518,7 @@ def _pfft_serial_unpacked(
     return xr.reshape(*lead, q2 * n1), xi.reshape(*lead, q2 * n1)
 
 
+@jax.named_scope("pencil")
 def pifft(
     xr: jax.Array,
     xi: jax.Array,
@@ -549,28 +578,36 @@ def pifft(
         # Natural order: device holds C^T rows (q, n1); transpose to pencil
         # with one packed collective.
         z = _pack2(xr.reshape(*lead, q, n1), xi.reshape(*lead, q, n1))
-        z = _a2a(z, axis_name, la + 2, la + 1)  # (2, ..., n2_slab rows, p)
-        z = jnp.swapaxes(z, -1, -2)  # (2, ..., p, n2)
+        with jax.named_scope("a2a0"):
+            z = _a2a(z, axis_name, la + 2, la + 1)  # (2, ..., n2_slab rows, p)
+        with jax.named_scope("reorder"):
+            z = jnp.swapaxes(z, -1, -2)  # (2, ..., p, n2)
     else:
         z = _pack2(xr.reshape(*lead, p, n2), xi.reshape(*lead, p, n2))
     lz = la + 1
     # Mirror of pfft: inverse FFT over n2 (rows, local)...
-    zr, zi = pl.plan_n2.apply_planes(z[0], z[1])
+    zr, zi = _unpack2(z)
+    with jax.named_scope("n2_rows"):
+        zr, zi = pl.plan_n2.apply_planes(zr, zi)
     z = _pack2(zr, zi)
 
     def col_chunk(chunk, col_start, width):
-        twr, twi = tw.traced_twiddle(
-            n1, n2, True, col_start=col_start, col_count=width
-        )
-        cr, ci = cmul(chunk[0], chunk[1], twr, twi)
-        cr, ci = pl.plan_n1.apply_planes(cr, ci)
+        with jax.named_scope("twiddle"):
+            twr, twi = tw.traced_twiddle(
+                n1, n2, True, col_start=col_start, col_count=width
+            )
+            cr, ci = _unpack2(chunk)
+            cr, ci = cmul(cr, ci, twr, twi)
+        with jax.named_scope("n1_cols"):
+            cr, ci = pl.plan_n1.apply_planes(cr, ci)
         return _pack2(cr, ci)
 
     z = _middle_pipelined(
         z, axis_name=axis_name, d=d, q=q, k=pl.a2a_chunks, la=lz,
-        compute=col_chunk,
+        compute=col_chunk, step=0 if from_pencil else 1,
     )
-    return z[0].reshape(*lead, p * n2), z[1].reshape(*lead, p * n2)
+    with jax.named_scope("unpack"):
+        return z[0].reshape(*lead, p * n2), z[1].reshape(*lead, p * n2)
 
 
 def _pifft_serial_unpacked(
@@ -599,6 +636,7 @@ def _pifft_serial_unpacked(
     return xr.reshape(*lead, p * n2), xi.reshape(*lead, p * n2)
 
 
+@jax.named_scope("pencil")
 def pfft2d(
     xr: jax.Array,
     xi: jax.Array,
@@ -632,15 +670,22 @@ def pfft2d(
     )
 
     # (1) row passes of the joint program over n2 — local and contiguous.
-    xr, xi = joint.apply_rows(xr, xi)
+    with jax.named_scope("n2_rows"):
+        xr, xi = joint.apply_rows(xr, xi)
     if pack:
         # (2) ONE packed a2a transpose: (p, n2) → (n1, q) column slabs.
-        z = _a2a(_pack2(xr, xi), axis_name, la + 2, la + 1)
+        z = _pack2(xr, xi)
+        with jax.named_scope("a2a0"):
+            z = _a2a(z, axis_name, la + 2, la + 1)
         # (3) column passes over n1 — in place down axis -2 of the slab.
-        xr, xi = joint.apply_cols(z[0], z[1])
+        xr, xi = _unpack2(z)
+        with jax.named_scope("n1_cols"):
+            xr, xi = joint.apply_cols(xr, xi)
         # (4) one packed a2a back to row slabs (p, n2).
-        z = _a2a(_pack2(xr, xi), axis_name, la + 1, la + 2)
-        return z[0], z[1]
+        z = _pack2(xr, xi)
+        with jax.named_scope("a2a1"):
+            z = _a2a(z, axis_name, la + 1, la + 2)
+        return _unpack2(z)
     xr = _a2a(xr, axis_name, la + 1, la)
     xi = _a2a(xi, axis_name, la + 1, la)
     xr, xi = joint.apply_cols(xr, xi)

@@ -205,3 +205,41 @@ def test_cell_program_names_kernels_and_passes(cell, one_chip, no_persistent_cac
     named = {s for found in _OP_NAME.findall(text) for p in found.split(";") for s in p.split("/")}
     assert OS_SCOPES <= named if cell == "conv_os_4097" else not OS_SCOPES & named
 
+
+
+# -- the four-chip cell's program: the pencil steps named ------------------
+
+_PENCIL_STEP = re.compile(r"pencil/(a2a\d|n1_cols|n2_rows)(/|$)")
+
+
+def test_pencil_cell_program_names_its_steps(topo, no_persistent_cache, real_kernels):
+    """``pfft_sharded`` over a described v5e 2x2 at test size, K = 4: nine
+    collectives, each under its ``pencil/a2a{step}`` scope; the column leaf
+    runs strided-column kernels under ``pencil/n1_cols``, the row leaf row
+    kernels under ``pencil/n2_rows``."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import distributed as D
+
+    mesh = Mesh(np.array(topo.devices), ("x",), axis_types=(AxisType.Auto,))
+    arg = jax.ShapeDtypeStruct(
+        (8, 2**16), jnp.float32, sharding=NamedSharding(mesh, PartitionSpec(None, "x"))
+    )
+    with F.use_backend("pallas"):
+        fn = jax.jit(lambda a, b: D.pfft_sharded(a, b, mesh, "x", chunks=4))
+        text = fn.lower(arg, arg).compile().as_text()
+    steps = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        where = _OP_NAME.search(line)
+        if m is None or where is None:
+            continue
+        if " all-to-all(" in line or 'custom_call_target="tpu_custom_call"' in line:
+            step = _PENCIL_STEP.search(where.group(1))
+            assert step, line
+            steps.setdefault(step.group(1), []).append(re.sub(r"\.\d+$", "", m.group(1)))
+    a2a = {s: len(v) for s, v in steps.items() if s.startswith("a2a")}
+    assert a2a == {"a2a0": 4, "a2a1": 4, "a2a2": 1}, a2a
+    assert steps["n1_cols"] and all(f.startswith("pencil_cols") for f in steps["n1_cols"])
+    assert steps["n2_rows"] and not any(f.startswith("pencil_cols") for f in steps["n2_rows"])

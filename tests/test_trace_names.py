@@ -12,6 +12,12 @@ for a v5e.
 
 from __future__ import annotations
 
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -176,3 +182,105 @@ def test_apply_planes_is_scoped_by_kind():
     z = jnp.zeros((4, 256), jnp.float32)
     text = _locations(planned.apply_planes, z, z)
     assert "ifft/p0_rows/dft_direct/pallas_call" in text
+
+
+# -- the pencil path: pencil/<step> scopes, metadata only -------------------
+
+_PENCIL_BODY = r"""
+import contextlib, importlib, json
+import jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.core import distributed as D
+from repro.core import fft as F
+
+mesh = jax.make_mesh((4,), ('x',))
+n = 4096
+sig = jnp.zeros((2, n), jnp.float32)
+img = jnp.zeros((2, 128, 256), jnp.float32)
+row_spec = P(None, 'x', None)
+
+
+def programs(mod):
+    cases = {
+        'pfft': (lambda a, b: mod.pfft_sharded(a, b, mesh, 'x', chunks=4), sig),
+        'pifft': (lambda a, b: mod.pifft_sharded(a, b, mesh, 'x', chunks=4), sig),
+        'pfft2d': (jax.shard_map(
+            lambda a, b: mod.pfft2d(a, b, n1=128, n2=256, axis_name='x', num_shards=4),
+            mesh=mesh, in_specs=(row_spec, row_spec), out_specs=(row_spec, row_spec),
+            check_vma=False), img),
+    }
+    out = {}
+    with F.use_backend('pallas'):
+        for name, (fn, x) in cases.items():
+            jaxpr = str(jax.make_jaxpr(fn)(x, x))
+            lowered = jax.jit(fn).lower(x, x)
+            out[name] = {
+                'jaxpr': jaxpr,
+                'a2a': jaxpr.count('all_to_all'),
+                'text': lowered.as_text(),
+                'located': lowered.as_text(debug_info=True),
+            }
+    return out
+
+
+class _Bare(contextlib.ContextDecorator):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+scoped = programs(D)
+real = jax.named_scope
+jax.named_scope = lambda name: _Bare()  # the same code with no scopes
+bare = programs(importlib.reload(D))
+jax.named_scope = real
+print(json.dumps({'scoped': scoped, 'bare': bare}))
+"""
+
+PENCIL_PATHS = {
+    "pfft": (
+        "pencil/pack/", "pencil/a2a0/c0/all_to_all", "pencil/a2a0/c3/all_to_all",
+        "pencil/unpack/", "pencil/n1_cols/fft/p0_cols/", "pencil/twiddle/",
+        "pencil/a2a1/c0/all_to_all", "pencil/a2a1/c3/all_to_all", "pencil/a2a1/merge/",
+        "pencil/n2_rows/fft/p0_rows/", "pencil/a2a2/all_to_all", "pencil/reorder/",
+    ),
+    "pifft": (
+        "pencil/pack/", "pencil/a2a0/all_to_all", "pencil/reorder/",
+        "pencil/n2_rows/ifft/p0_rows/", "pencil/a2a1/c0/all_to_all", "pencil/twiddle/",
+        "pencil/n1_cols/ifft/p0_cols/", "pencil/a2a2/c3/all_to_all", "pencil/a2a2/merge/",
+        "pencil/unpack/",
+    ),
+    "pfft2d": (
+        "pencil/n2_rows/p0_rows/", "pencil/pack/", "pencil/a2a0/all_to_all",
+        "pencil/unpack/", "pencil/n1_cols/p0_cols/", "pencil/a2a1/all_to_all",
+    ),
+}
+#: Collectives of each program: 2K + 1 natural-order transposes at K = 4;
+#: the 2-D transform's two.
+PENCIL_A2A = {"pfft": 9, "pifft": 9, "pfft2d": 2}
+
+
+@pytest.fixture(scope="module")
+def pencil_programs():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _PENCIL_BODY], env=env, capture_output=True, text=True, timeout=600
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", sorted(PENCIL_PATHS))
+def test_scopes_name_the_pencil_steps_and_change_no_equation(case, pencil_programs):
+    scoped, bare = pencil_programs["scoped"][case], pencil_programs["bare"][case]
+    for path in PENCIL_PATHS[case]:
+        assert path in scoped["located"], (case, path)
+    assert "pencil/" not in bare["located"], case
+    # metadata only: the same equations and the same program, scopes aside
+    assert scoped["jaxpr"] == bare["jaxpr"], case
+    assert scoped["text"] == bare["text"], case
+    assert scoped["a2a"] == bare["a2a"] == PENCIL_A2A[case], case
