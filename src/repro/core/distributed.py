@@ -804,11 +804,13 @@ def pconv_os_sharded(
     the fused one-round-trip regime, where the pencil leaves may not).
 
     ``x``: (..., L) replicated input; ``h`` broadcasts like ``fft_conv``.
-    The block count is padded up to a multiple of the mesh axis size with
-    zero frames (their outputs fall past ``L_out`` and are sliced away).
-    Returns the (..., L) causal output (or L + Lh − 1 with
-    ``causal=False``), replicated — the framing gather and tail scatter run
-    outside the ``shard_map`` body.
+    The schedule is :func:`repro.core.overlap.overlap_save`'s: where it
+    pairs two frames per complex transform the pairs shard, else the
+    frames, their count padded up to a multiple of the mesh axis size
+    (zero frames, or windows whose outputs fall past ``L_out``).  Returns
+    the (..., L) causal output (or L + Lh − 1 with ``causal=False``),
+    replicated — the framing and the tail assembly run outside the
+    ``shard_map`` body.
 
     Block tuning here is DETERMINISTIC by construction: with ``block=None``
     and ``tune`` ≠ "off" the block is the pure roofline pick
@@ -837,22 +839,18 @@ def pconv_os_sharded(
         B = ov.pick_block(Lh)
     else:
         B = tuning.modeled_block(L, Lh, batch, backend, chunk=chunk_hint)
-    overlap = Lh - 1
-    step = B - overlap
     L_out = L if causal else L + Lh - 1
-    nb = -(-L_out // step)
-    nb = -(-nb // d) * d  # whole blocks per shard; extras are zero frames
-    frames = ov.frame_signal(x, B, step, nb)
     Hr, Hi = ov.filter_spectrum(h, B, backend)  # computed once, replicated
-    fspec = P(*([None] * (frames.ndim - 2)), axis, None)
 
-    def body(fr, hr, hi):
-        return ov.conv_frames(fr, hr, hi, overlap=overlap, backend=backend)
+    def run(fn, data, hr, hi):
+        # pairs shard on their leading axis, frames on the block axis
+        spec = P(axis) if isinstance(data, tuple) else P(*([None] * (data.ndim - 2)), axis, None)
+        return jax.shard_map(
+            fn, mesh=_auto_mesh(mesh), in_specs=(spec, P(), P()), out_specs=spec,
+            check_vma=False,
+        )(data, hr, hi)
 
-    tails = jax.shard_map(
-        body, mesh=_auto_mesh(mesh), in_specs=(fspec, P(), P()), out_specs=fspec,
-        check_vma=False,
-    )(frames, Hr, Hi)
-    lead = tails.shape[:-2]
-    y = tails.reshape(*lead, nb * step)[..., :L_out]
+    y = ov.overlap_save(
+        x, Hr, Hi, block=B, overlap=Lh - 1, length=L_out, backend=backend, shards=d, run=run
+    )
     return y.astype(out_dtype)

@@ -11,9 +11,18 @@ Memory", PAPERS.md) show the alternative this module implements:
   tier — ``B = next_pow2(Lh)·OS_FACTOR``, capped at the fused-kernel regime
   (:data:`repro.core.plan.FUSED_MAX`), so every transform is a single
   HBM round trip;
-* run ONE cached rfft/irfft plan pair **batched over all blocks** (the
-  filter spectrum is computed once and broadcast) — exactly the shape the
-  pallas pass programs are fastest at: big batch × fused-regime N;
+* transform the blocks **two at a time as one complex signal**: with a
+  real filter, ``conv(a + i·b, h) = conv(a, h) + i·conv(b, h)``, so two
+  adjacent real frames of one channel go into the real and imaginary
+  planes of ONE cached complex ``fft``/``ifft`` plan pair of length B,
+  batched over all pairs and multiplied by the filter's full B-bin
+  spectrum (its half spectrum computed once, extended by Hermitian
+  symmetry and broadcast) — exactly the shape the pallas pass programs
+  are fastest at: big batch × fused-regime N, with no real-FFT even/odd
+  packing or Hermitian recombination around the frames.  A
+  single frame per signal (a streaming chunk), or a block past
+  ``FUSED_MAX``, keeps one cached rfft/irfft plan pair instead
+  (:func:`pairs_apply` decides from the shapes);
 * scatter each block's valid tail (the ``B − (Lh−1)`` samples whose history
   is fully inside the block) back into the output.
 
@@ -36,11 +45,14 @@ so the win is observable, not just asserted.
 
 from __future__ import annotations
 
+import collections
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import faults
 from repro.core import fft as fft_lib
@@ -53,9 +65,15 @@ Planes = Tuple[jax.Array, jax.Array]
 __all__ = [
     "OS_FACTOR",
     "pick_block",
+    "pairs_apply",
+    "pairing_log",
+    "clear_pairing_log",
+    "describe_conv",
     "frame_signal",
+    "frame_pairs",
     "filter_spectrum",
     "conv_frames",
+    "overlap_save",
     "fft_conv_os",
     "stream_lookahead",
     "StreamingConv",
@@ -120,6 +138,77 @@ def _resolve_block(
     return tuning.tuned_block(L, filter_len, batch, backend, mode, chunk=chunk)
 
 
+def pairs_apply(num_blocks: int, block: int) -> bool:
+    """Whether ``num_blocks`` frames of one signal run paired: two real
+    frames per complex transform of length ``block``.
+
+    Decided from the shapes alone.  With one frame per signal (a streaming
+    chunk) pairing would double the transformed work, and a block past
+    :data:`~repro.core.plan.FUSED_MAX` would take the complex transform out
+    of the fused regime (the real-FFT packing keeps it at ``block / 2``), so
+    both keep the rfft/irfft pair.
+    """
+    return num_blocks >= 2 and block <= plan_lib.FUSED_MAX
+
+
+#: Ring-buffer capacity of :func:`pairing_log`.
+PAIRING_LOG_MAX = 256
+
+#: One record per paired overlap-save call, newest last; written when the
+#: call is traced (so once per compile under ``jit``).
+_PAIRINGS: collections.deque = collections.deque(maxlen=PAIRING_LOG_MAX)
+
+
+def pairing_log() -> Tuple[dict, ...]:
+    """Process-global record of the paired overlap-save calls (bounded):
+    ``{"frames", "pairs", "pad_frames"}`` over all leading indices, the pad
+    frames being the transformed frames that carry no output (the zero
+    imaginary frame of an odd frame count, and the frames that round the
+    sharded variant's pairs up to the mesh).  ``pad_frames / (2·pairs)`` is
+    the share of transformed frames that is padding."""
+    return tuple(_PAIRINGS)
+
+
+def clear_pairing_log() -> None:
+    _PAIRINGS.clear()
+
+
+def _pairing(signals: int, frames: int, pairs: int) -> dict:
+    return {
+        "frames": signals * frames,
+        "pairs": signals * pairs,
+        "pad_frames": signals * (2 * pairs - frames),
+    }
+
+
+def _schedule(L: int, filter_len: int, block: int, causal: bool) -> Tuple[int, int]:
+    """``(L_out, frames)``: the output length of a length-``L`` signal
+    through a ``filter_len``-tap filter, and the overlap-save frames of
+    ``block`` that cover it."""
+    L_out = L if causal else L + filter_len - 1
+    return L_out, -(-L_out // (block - filter_len + 1))
+
+
+def describe_conv(
+    L: int, filter_len: int, block: int, *, causal: bool = True
+) -> str:
+    """One line naming the overlap-save schedule that :func:`fft_conv_os`
+    runs for a length-``L`` signal through a ``filter_len``-tap filter at
+    ``block``."""
+    _, nb = _schedule(L, filter_len, block, causal)
+    head = (
+        f"overlap-save block {block}, step {block - filter_len + 1}: "
+        f"{nb} frame(s) a signal"
+    )
+    if pairs_apply(nb, block):
+        p = _pairing(1, nb, -(-nb // 2))
+        return (
+            f"{head} as {p['pairs']} paired complex frames "
+            f"({p['pad_frames']} pad), fft/ifft n={block}"
+        )
+    return f"{head}, rfft/irfft n={block}"
+
+
 @jax.named_scope("os_frame")
 def frame_signal(
     x: jax.Array, block: int, step: int, num_blocks: int
@@ -146,6 +235,63 @@ def frame_signal(
     return jnp.take(xp, jnp.asarray(idx, np.int32), axis=-1, mode="clip")
 
 
+def _frame_scale(frames: jax.Array) -> jax.Array:
+    """The power of two at or above each frame's largest magnitude over the
+    last axis (1 for a zero frame).  Dividing by it is exact, and puts both
+    frames of a pair on one scale, so each keeps float32 rounding relative
+    to its own size rather than its partner's."""
+    _, e = jnp.frexp(jnp.max(jnp.abs(frames), axis=-1, keepdims=True))
+    return jnp.ldexp(jnp.float32(1), e)
+
+
+@jax.named_scope("os_pair")
+def frame_pairs(
+    x: jax.Array, block: int, step: int, num_blocks: int, pairs: Optional[int] = None
+) -> Tuple[Planes, Planes]:
+    """Overlap-save framing of the last axis straight into complex pairs.
+
+    The frames are :func:`frame_signal`'s, each divided by its
+    :func:`_frame_scale`; frame ``2j`` of each leading index goes to pair
+    ``j``'s real plane and frame ``2j + 1`` to its imaginary plane.
+    ``pairs`` (default ``⌈num_blocks / 2⌉``) pairs are made; frames past
+    ``num_blocks``, such as the last imaginary frame of an odd
+    ``num_blocks``, are zeros.  Returns
+    ``((re, im), (scale_re, scale_im))``, planes ``(pairs, *lead, block)``
+    and scales ``(pairs, *lead, 1)``: the pair axis leads, so the
+    transform's rows and the output's whole frames are tiles of the planes.
+    One loop over the pairs copies each pair's two windows of the
+    zero-padded signal into place, so the program is the same for any
+    frame count.
+    """
+    if num_blocks * step < x.shape[-1]:
+        raise faults.PlanError(
+            f"{num_blocks} blocks of step {step} cover only "
+            f"{num_blocks * step} < {x.shape[-1]} samples"
+        )
+    pairs = pairs or -(-num_blocks // 2)
+    pad = [(0, 0)] * (x.ndim - 1) + [(block - step, 2 * pairs * step - x.shape[-1])]
+
+    def window(f):
+        # padded inside the loop, XLA reads each window from x itself
+        # instead of first writing the whole padded signal
+        w = lax.dynamic_slice_in_dim(jnp.pad(x, pad), f * step, block, axis=-1)
+        w = jnp.where(f < num_blocks, w, 0)
+        s = _frame_scale(w)
+        return (w / s)[None], s[None]
+
+    def body(j, acc):
+        (wr, sr), (wi, si) = window(2 * j), window(2 * j + 1)
+        return tuple(
+            lax.dynamic_update_slice_in_dim(a, u, j, axis=0)
+            for a, u in zip(acc, (wr, wi, sr, si))
+        )
+
+    planes = jnp.zeros((pairs, *x.shape[:-1], block), x.dtype)
+    scales = jnp.zeros((pairs, *x.shape[:-1], 1), x.dtype)
+    zr, zi, sr, si = lax.fori_loop(0, pairs, body, (planes, planes, scales, scales))
+    return (zr, zi), (sr, si)
+
+
 @jax.named_scope("os_filter")
 def filter_spectrum(
     h: jax.Array, block: int, backend: Optional[str] = None
@@ -160,6 +306,35 @@ def filter_spectrum(
     return Hr[..., None, :], Hi[..., None, :]
 
 
+def _conv_pairs(
+    planes: Planes,
+    Hr: jax.Array,
+    Hi: jax.Array,
+    *,
+    backend: Optional[str] = None,
+) -> Planes:
+    """Circular convolution of :func:`frame_pairs`' complex pairs of real
+    frames with a real filter: ONE cached complex fft/ifft plan pair over
+    all pairs, the product taken against the filter's full spectrum.
+
+    ``Hr``/``Hi`` are :func:`filter_spectrum` planes at the block; the full
+    spectrum extends them by Hermitian symmetry (bin ``B − k`` is the
+    conjugate of bin ``k``).  The real plane of the result is the real
+    frames' convolution, the imaginary plane the imaginary frames'.  The
+    product is named ``os_product``, the transforms by their kinds.
+    """
+    zr, zi = planes
+    block = zr.shape[-1]
+    Fr = jnp.concatenate([Hr, jnp.flip(Hr[..., 1:-1], -1)], axis=-1)[..., 0, :]
+    Fi = jnp.concatenate([Hi, -jnp.flip(Hi[..., 1:-1], -1)], axis=-1)[..., 0, :]
+    fwd = fft_lib.plan(fft_lib.FFTSpec(n=block, kind="fft"), backend=backend)
+    inv = fft_lib.plan(fft_lib.FFTSpec(n=block, kind="ifft"), backend=backend)
+    Zr, Zi = fwd.apply_planes(zr, zi)
+    with jax.named_scope("os_product"):
+        Yr, Yi = cmul(Zr, Zi, Fr, Fi)
+    return inv.apply_planes(Yr, Yi)
+
+
 def conv_frames(
     frames: jax.Array,
     Hr: jax.Array,
@@ -169,15 +344,18 @@ def conv_frames(
     backend: Optional[str] = None,
 ) -> jax.Array:
     """Batched circular convolution of ``(..., nb, B)`` frames with the
-    broadcast filter spectrum, keeping each frame's valid tail.
+    broadcast filter spectrum, keeping each frame's valid tail: the
+    rfft/irfft branch of :func:`overlap_save`, for the shapes where
+    :func:`pairs_apply` does not hold (one frame a signal, or ``B`` past
+    ``FUSED_MAX``); the others pair frames through :func:`_conv_pairs`.
 
     ONE cached rfft/irfft plan pair over all blocks (batch = leading dims ×
     nb), pointwise spectrum multiply, and the overlap-save discard: the
     first ``overlap`` samples of each block alias history that belongs to
-    the previous block.  Returns ``(..., nb, B − overlap)``.  Also the body
-    of the sharded variant — it is collective-free, so blocks shard over a
-    mesh axis with no all-to-alls.  The product and the discard are named
-    ``os_product`` and ``os_discard``; the transforms by their kinds.
+    the previous block.  Returns ``(..., nb, B − overlap)``.  It is
+    collective-free, so blocks shard over a mesh axis with no all-to-alls.
+    The product and the discard are named ``os_product`` and
+    ``os_discard``; the transforms by their kinds.
     """
     block = frames.shape[-1]
     fwd = fft_lib.plan(fft_lib.FFTSpec(n=block, kind="rfft"), backend=backend)
@@ -188,6 +366,96 @@ def conv_frames(
     y = inv((Yr, Yi))
     with jax.named_scope("os_discard"):
         return y[..., overlap:]
+
+
+@jax.named_scope("os_unpair")
+def _unpair(planes: Planes, scales: Planes, overlap: int, length: int) -> jax.Array:
+    """The valid tails of :func:`frame_pairs`-ordered results, scaled back
+    and written in signal order a whole frame at a time: two
+    ``(pairs, *lead, B)`` planes → ``(*lead, length)``.  One loop writes
+    the pairs whose two frames are whole; the last one or two frames, the
+    last cut at ``length``, follow.  Frames are joined along the samples
+    axis, never stacked on a minor axis of size 2."""
+    step = planes[0].shape[-1] - overlap
+    nb = -(-length // step)
+
+    def row(a, j, start, size):  # pair j of ``a``, samples [start, start + size)
+        at = (j,) + (0,) * (a.ndim - 2) + (start,)
+        return lax.dynamic_slice(a, at, (1, *a.shape[1:-1], size))[0]
+
+    def tail(k, j, n):
+        # one dynamic slice, so XLA copies each tail straight into place
+        return row(planes[k], j, overlap, n) * row(scales[k], j, 0, 1)
+
+    def put(out, f, t):
+        return lax.dynamic_update_slice_in_dim(out, t, f * step, axis=-1)
+
+    def body(j, out):
+        return put(put(out, 2 * j, tail(0, j, step)), 2 * j + 1, tail(1, j, step))
+
+    whole = (nb - 1) // 2
+    out = jnp.zeros((*planes[0].shape[1:-1], length), planes[0].dtype)
+    out = lax.fori_loop(0, whole, body, out)
+    for f in range(2 * whole, nb):
+        out = put(out, f, tail(f % 2, f // 2, min(step, length - f * step)))
+    return out
+
+
+def _run(fn, data, Hr, Hi):
+    return fn(data, Hr, Hi)
+
+
+def overlap_save(
+    x: jax.Array,
+    Hr: jax.Array,
+    Hi: jax.Array,
+    *,
+    block: int,
+    overlap: int,
+    length: int,
+    backend: Optional[str] = None,
+    shards: int = 1,
+    run=_run,
+) -> jax.Array:
+    """The overlap-save schedule of the last axis of ``x`` through the
+    filter spectrum ``Hr``/``Hi`` (:func:`filter_spectrum` planes at
+    ``block``): the first ``length`` outputs, shaped ``(*lead, length)``,
+    ``lead`` being the leading shapes of ``x`` and the filter broadcast.
+
+    With :func:`pairs_apply` (``nb ≥ 2`` frames a signal, ``block ≤
+    FUSED_MAX``), frames ``2j`` and ``2j + 1`` of each leading index run as
+    one complex signal: :func:`frame_pairs` (``os_pair``), :func:`_conv_pairs`,
+    and the tails written back whole frames at a time (``os_unpair``); each
+    such call is recorded in :func:`pairing_log`.  ``x`` is framed at its
+    own leading shape, so a bank of filters transforms the signal once.  A
+    non-finite sample in a paired frame reaches its partner frame's output.
+    Otherwise :func:`frame_signal`, :func:`conv_frames` and the ``os_tail``
+    reshape run one cached rfft/irfft plan pair over all frames.
+
+    ``run(fn, data, Hr, Hi)`` applies the collective-free transform stage
+    (``_conv_pairs`` to the pairs, ``conv_frames`` to the frames); the
+    sharded variant shards it over a mesh axis, after rounding the pairs
+    (zero frames) or frames (windows past ``length``) up to a multiple of
+    ``shards``.
+    """
+    step = block - overlap
+    nb = -(-length // step)
+    lead = np.broadcast_shapes(x.shape[:-1], Hr.shape[:-2])
+    if pairs_apply(nb, block):
+        pairs = -(-nb // 2)
+        pairs = -(-pairs // shards) * shards
+        _PAIRINGS.append(_pairing(int(np.prod(lead)), nb, pairs))
+        x = x.reshape((1,) * (len(lead) + 1 - x.ndim) + x.shape)
+        planes, scales = frame_pairs(x, block, step, nb, pairs)
+        y = run(functools.partial(_conv_pairs, backend=backend), planes, Hr, Hi)
+        return _unpair(y, scales, overlap, length)
+    nb = -(-nb // shards) * shards
+    frames = frame_signal(x, block, step, nb)
+    tails = run(
+        functools.partial(conv_frames, overlap=overlap, backend=backend), frames, Hr, Hi
+    )
+    with jax.named_scope("os_tail"):
+        return tails.reshape(*tails.shape[:-2], nb * step)[..., :length]
 
 
 def fft_conv_os(
@@ -204,10 +472,19 @@ def fft_conv_os(
 
     Matches :func:`repro.core.conv.fft_conv` outputs at tolerance while
     never planning a transform larger than the block (≤ ``FUSED_MAX`` by
-    default): the signal is framed into overlapping blocks, all blocks run
-    through one cached rfft/irfft plan pair, and the valid tails are
-    scattered back.  ``h`` broadcasts against ``x`` with the convolution
-    axis moved last, exactly like ``fft_conv``.
+    default): the signal is framed into overlapping blocks, the blocks run
+    through one cached plan pair, and the valid tails are scattered back
+    (:func:`overlap_save`).  ``h`` broadcasts against ``x`` with the
+    convolution axis moved last, exactly like ``fft_conv``.
+
+    With two or more blocks per signal and ``block ≤ FUSED_MAX``
+    (:func:`pairs_apply`), adjacent blocks of one signal are paired into
+    one complex signal and run through one cached complex fft/ifft plan
+    pair against the filter's full spectrum, with no real-FFT packing per
+    block; a non-finite sample then reaches the output of its partner
+    block too, within the same signal.  Otherwise one cached rfft/irfft
+    plan pair runs over all blocks.  :func:`describe_conv` names the
+    schedule.
 
     With ``block=None`` the block size is a tuned decision
     (:mod:`repro.core.tuning`): ``tune="off"`` keeps the fixed
@@ -224,19 +501,12 @@ def fft_conv_os(
     L, Lh = x.shape[-1], h.shape[-1]
     batch = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 1
     B = _resolve_block(Lh, block, L, batch, backend, tune)
-    overlap = Lh - 1
-    step = B - overlap
-    L_out = L if causal else L + Lh - 1
-    nb = -(-L_out // step)
-    frames = frame_signal(x, B, step, nb)
+    L_out, _ = _schedule(L, Lh, B, causal)
     Hr, Hi = filter_spectrum(h, B, backend)
-    tails = conv_frames(frames, Hr, Hi, overlap=overlap, backend=backend)
-    with jax.named_scope("os_tail"):
-        lead = tails.shape[:-2]
-        y = tails.reshape(*lead, nb * step)[..., :L_out]
-        if axis != -1:
-            y = jnp.moveaxis(y, -1, axis)
-        return y.astype(out_dtype)
+    y = overlap_save(x, Hr, Hi, block=B, overlap=Lh - 1, length=L_out, backend=backend)
+    if axis != -1:
+        y = jnp.moveaxis(y, -1, axis)
+    return y.astype(out_dtype)
 
 
 def _stream_conv(
@@ -257,7 +527,8 @@ def _stream_conv(
     cached rfft/irfft pair — no framing gather at all.  Every kept output
     position ``p ≥ overlap ≥ j`` for all filter taps ``j``, so the circular
     convolution never wraps into the kept range and the single frame equals
-    the framed multi-block result.
+    the framed multi-block result.  Longer inputs take :func:`overlap_save`
+    (paired frames where :func:`pairs_apply` holds).
     """
     L = xin.shape[-1]
     if L <= block:
@@ -265,14 +536,10 @@ def _stream_conv(
         frames = jnp.pad(xin, pad)[..., None, :]
         y = conv_frames(frames, Hr, Hi, overlap=overlap, backend=backend)
         return y[..., 0, : L - overlap]
-    step = block - overlap
-    nb = -(-L // step)
-    frames = frame_signal(xin, block, step, nb)
-    tails = conv_frames(frames, Hr, Hi, overlap=overlap, backend=backend)
-    with jax.named_scope("os_tail"):
-        lead = tails.shape[:-2]
-        y = tails.reshape(*lead, nb * step)[..., :L]
-        return y[..., overlap:]
+    y = overlap_save(
+        xin, Hr, Hi, block=block, overlap=overlap, length=L, backend=backend
+    )
+    return y[..., overlap:]
 
 
 def stream_lookahead(

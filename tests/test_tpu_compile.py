@@ -138,7 +138,9 @@ _KERNEL = re.compile(
 )
 _OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
 _WHERE = re.compile(r"p\d+_(rows|cols)|recomb")
-OS_SCOPES = {"os_frame", "os_filter", "os_product", "os_discard", "os_tail"}
+OS_SCOPES = {"os_frame", "os_filter", "os_product", "os_discard", "os_tail", "os_pair", "os_unpair"}
+#: The convolution's stages at the cell's shape: 6 frames a signal, paired.
+CONV_SCOPES = {"os_pair", "os_filter", "os_product", "os_unpair"}
 
 
 def _planned(spec, shape, dtype, sharding):
@@ -203,7 +205,16 @@ def test_cell_program_names_kernels_and_passes(cell, one_chip, no_persistent_cac
             assert not family.startswith(("pencil_cols", "recomb_")), path
     assert seen == want, (cell, seen)
     named = {s for found in _OP_NAME.findall(text) for p in found.split(";") for s in p.split("/")}
-    assert OS_SCOPES <= named if cell == "conv_os_4097" else not OS_SCOPES & named
+    if cell == "conv_os_4097":
+        # paired complex frames: four-step leaves only; the one
+        # recombination is the filter's half spectrum, under os_filter
+        assert {re.sub(r"\.\d+$", "", name) for name, _ in kernels} == {"fft4step", "recomb_fwd"}
+        assert [p for n, p in kernels if n.startswith("recomb")] and all(
+            "/os_filter/" in p for n, p in kernels if n.startswith("recomb")
+        )
+        assert named & OS_SCOPES == CONV_SCOPES
+    else:
+        assert not OS_SCOPES & named
 
 
 

@@ -57,9 +57,11 @@ def _irfft_call(spec, backend, shape):
     return planned, ((z, z),)
 
 
-def _conv_call(backend):
+def _conv_call(backend, length=3000):
+    """7 frames a signal (paired complex frames) at the default length; one
+    frame (the rfft/irfft pair) at ``length`` ≤ 480."""
     fn = lambda x, h: overlap.fft_conv_os(x, h, block=512, backend=backend)  # noqa: E731
-    return fn, (jnp.zeros((2, 3000), jnp.float32), jnp.zeros((33,), jnp.float32))
+    return fn, (jnp.zeros((2, length), jnp.float32), jnp.zeros((33,), jnp.float32))
 
 
 # case → (maker of (callable, args), the kernel names its jaxpr holds)
@@ -94,7 +96,9 @@ CASES = {
         lambda: _complex_call(F.FFTSpec(n=40000), "pallas", (1, 40000)),
         {"bluestein_elem", "pencil_cols", "pencil_rows_natural"},
     ),
-    "conv_os": (lambda: _conv_call("pallas"), {"dft_direct", "recomb_fwd", "recomb_inv"}),
+    # paired frames; the one recomb_fwd is the filter's own spectrum
+    "conv_os": (lambda: _conv_call("pallas"), {"dft_direct", "recomb_fwd"}),
+    "conv_os_one_frame": (lambda: _conv_call("pallas", 400), {"dft_direct", "recomb_fwd", "recomb_inv"}),
     "gpu_direct": (lambda: _complex_call(F.FFTSpec(n=256), "pallas_gpu", (4, 256)), {"dft_direct_gpu"}),
     "gpu_fused4": (lambda: _complex_call(F.FFTSpec(n=4096), "pallas_gpu", (4, 4096)), {"fft4step_gpu"}),
     "gpu_split": (
@@ -160,10 +164,20 @@ def test_scopes_name_the_planned_fft2():
         assert path in text, path
 
 
-def test_scopes_name_the_overlap_save_stages():
-    fn, args = _conv_call("pallas")
-    text = _locations(fn, *args)
-    for path in (
+OS_PATHS = {
+    # the paired complex frames: no rfft packing of frames; the filter's
+    # half spectrum is the rfft's, recombined once
+    3000: (
+        "/os_pair/",
+        "/os_filter/rfft/p0_rows/dft_direct/pallas_call",
+        "/os_filter/rfft/recomb/recomb_fwd/pallas_call",
+        "/fft/p0_rows/dft_direct/pallas_call",
+        "/os_product/",
+        "/ifft/p0_rows/dft_direct/pallas_call",
+        "/os_unpair/",
+    ),
+    # one frame a signal: the rfft/irfft pair
+    400: (
         "/os_frame/",
         "/os_filter/rfft/p0_rows/dft_direct/pallas_call",
         "/os_filter/rfft/recomb/recomb_fwd/pallas_call",
@@ -173,8 +187,66 @@ def test_scopes_name_the_overlap_save_stages():
         "/irfft/p0_rows/dft_direct/pallas_call",
         "/os_discard/",
         "/os_tail/",
-    ):
+    ),
+}
+
+
+@pytest.mark.parametrize("length", sorted(OS_PATHS))
+def test_scopes_name_the_overlap_save_stages(length):
+    fn, args = _conv_call("pallas", length)
+    text = _locations(fn, *args)
+    for path in OS_PATHS[length]:
         assert path in text, path
+    paired = length > 480
+    gone = {"/os_frame/", "/os_discard/", "/os_tail/", "/irfft/", "recomb_inv"} if paired else {"/os_pair/", "/os_unpair/"}
+    assert not any(g in text for g in gone), length
+    if paired:  # the only recombination is the filter's
+        assert all("/os_filter/" in line for line in text.splitlines() if "/recomb/" in line)
+
+
+_OS_BARE_BODY = r"""
+import contextlib, importlib, json
+import jax, jax.numpy as jnp
+from repro.core import overlap as O
+
+
+class _Bare(contextlib.ContextDecorator):
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def program(mod):
+    fn = lambda x, h: mod.fft_conv_os(x, h, block=512, backend="pallas")
+    args = (jnp.zeros((2, 3000), jnp.float32), jnp.zeros((33,), jnp.float32))
+    lowered = jax.jit(fn).lower(*args)
+    return {"jaxpr": str(jax.make_jaxpr(fn)(*args)), "text": lowered.as_text(),
+            "located": lowered.as_text(debug_info=True)}
+
+
+scoped = program(O)
+jax.named_scope = lambda name: _Bare()  # the same code with no scopes
+print(json.dumps({"scoped": scoped, "bare": program(importlib.reload(O))}))
+"""
+
+
+def test_pair_scopes_change_no_equation():
+    """``os_pair`` and ``os_unpair`` are metadata: with every scope taken
+    away the paired program has the same equations and the same text."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _OS_BARE_BODY], env=env, capture_output=True, text=True, timeout=600
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    got = json.loads(out.stdout.splitlines()[-1])
+    scoped, bare = got["scoped"], got["bare"]
+    assert "/os_pair/" in scoped["located"] and "/os_unpair/" in scoped["located"]
+    assert "os_pair" not in bare["located"] and "os_unpair" not in bare["located"]
+    assert scoped["jaxpr"] == bare["jaxpr"]
+    assert scoped["text"] == bare["text"]
 
 
 def test_apply_planes_is_scoped_by_kind():
