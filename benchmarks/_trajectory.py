@@ -18,7 +18,7 @@ import jax
 def device_provenance() -> dict:
     """``{backend, device_kind}`` of the device this process would run on —
     recorded in every trajectory row so a number diffed across PRs is only
-    ever compared against the same silicon (an A100 row and a CPU row of
+    ever compared against the same silicon (a TPU row and a CPU row of
     the same suite are different baselines, not a regression)."""
     try:
         kind = jax.devices()[0].device_kind

@@ -35,8 +35,6 @@ def main() -> None:
     scratch = tempfile.mkdtemp(prefix="seed_gen_")
     os.environ["REPRO_TUNING_CACHE"] = os.path.join(scratch, "cache.json")
 
-    import jax
-
     from repro.core import fft as fft_lib
     from repro.core import tuning
 
@@ -45,19 +43,11 @@ def main() -> None:
         with open(SEED_PATH) as f:
             entries = json.load(f)
 
-    platform = jax.default_backend()
     for n, batch in ROSTER:
         spec = fft_lib.FFTSpec(n=n, kind="fft", batch_hint=batch)
-        for backend in ("pallas", "pallas_gpu"):
-            space = tuning.TuningSpace.for_plan(spec, backend)
-            cfg = space.decide("measure")
-            entries[f"{tuning.device_key()}|{space.key}"] = {
-                "config": cfg,
-                "mode": "measure",
-            }
-        xspace = tuning.TuningSpace.for_backend(spec, platform)
-        entries[f"{tuning.device_key()}|{xspace.key}"] = {
-            "config": xspace.decide("measure"),
+        space = tuning.TuningSpace.for_plan(spec, "pallas")
+        entries[f"{tuning.device_key()}|{space.key}"] = {
+            "config": space.decide("measure"),
             "mode": "measure",
         }
 

@@ -16,7 +16,6 @@ from repro.runtime.compile_cache import enable_compile_cache
 from benchmarks import (
     bench_bluestein,
     bench_fftconv,
-    bench_gpu,
     bench_pfft,
     bench_roofline,
     bench_sar,
@@ -33,7 +32,6 @@ SUITES = {
     "roofline": bench_roofline.main, # dry-run roofline summary
     "serve": bench_serve.main,       # prefill/insert/generate phase timings
     "pfft": bench_pfft.main,         # distributed pencil scaling (fake devices)
-    "gpu": bench_gpu.main,           # pallas_gpu vs xla crossover ledger
     "bluestein": bench_bluestein.main,  # non-pow2 vs padded-pow2 vs jnp.fft
 }
 
@@ -50,8 +48,6 @@ SMOKE_SUITES = {
     "serve": lambda: bench_serve.main(smoke=True),
     # one 16-fake-device point: numerics + packed collective counts
     "pfft": lambda: bench_pfft.main(smoke=True),
-    # Triton-path kernels under interpret: numerics + per-leaf claims
-    "gpu": lambda: bench_gpu.main(smoke=True),
     # gates chirp-conv leaves on numerics vs numpy before timing
     "bluestein": lambda: bench_bluestein.main(smoke=True),
 }

@@ -168,32 +168,7 @@ print("pfft matches jnp.fft:",
 print("d=1:", D.plan_pencil(4096, 1).describe().splitlines()[0])
 print("d=8:", D.plan_pencil(1 << 18, 8).describe().splitlines()[0])
 
-# ---- 14. the GPU backend: shared-memory-budgeted leaves, per-leaf fallback -
-# `pallas_gpu` runs the SAME linearized pass programs through Pallas-on-
-# Triton kernels, leaf by leaf.  Tiles are sized by the device's shared-
-# memory budget (`limits.memory_budget`: 164 KiB on A100, 228 KiB on H100,
-# 48 KiB for unknown GPUs — the paper's Fermi floor) instead of TPU VMEM;
-# passes the Triton leaf can't run natively (strided columns) fall back to
-# xla INSIDE the same plan — `pass_claims` names the executor per leaf, and
-# describe() adds the GPU account: modeled global-memory round trips and
-# peak shared-memory per block against the budget.  On this CPU host the
-# kernels run in Pallas interpret mode; a real GPU wins negotiation and
-# picks them up with zero code changes (tune="model"/"measure" decides the
-# pallas↔xla crossover per device, seeded by repro/data/tuning_seed.json).
-from repro.core import limits
-
-with F.use_backend("pallas_gpu"):
-    pg = F.plan(F.FFTSpec(n=131072))
-print("per-leaf claims:", pg.pass_claims)          # ('xla', 'pallas_gpu')
-print(pg.describe())                               # "...; gpu: N global round trips, ..."
-xg = jax.random.normal(jax.random.PRNGKey(3), (2, 131072))
-yg = pg(xg)                                        # real in → complex out
-print("pallas_gpu matches jnp.fft:",
-      bool(jnp.allclose(yg, jnp.fft.fft(xg), atol=1e-2)))
-print("smem budget here:", limits.memory_budget() // 1024, "KiB;",
-      "A100:", limits.memory_budget("NVIDIA A100-SXM4-40GB") // 1024, "KiB")
-
-# ---- 15. arbitrary lengths: the Bluestein chirp-conv leaf ------------------
+# ---- 14. arbitrary lengths: the Bluestein chirp-conv leaf ------------------
 # FFTSpec takes ANY n ≥ 1 — primes, 3·2^k, whatever the pulse dictates.
 # Non-pow2 lengths compile to Bluestein leaves: chirp pre-multiply, one
 # cached pow2 convolution of length next_pow2(2n-1), chirp post-multiply —
@@ -212,8 +187,8 @@ rep = rl.bluestein_report(2029)
 print("bluestein tax: pad %d (%.2fx), %.1fx flops vs mixed-radix"
       % (rep["pad"], rep["pad_ratio"], rep["flops_overhead"]))
 
-# ---- 16. fault tolerance: injection, per-leaf degradation, quarantine ------
-# Every claimed pallas leaf executes under a retry→quarantine→fallback
+# ---- 15. fault tolerance: injection, per-leaf degradation, quarantine ------
+# Every pallas leaf executes under a retry→quarantine→fallback
 # guard (`faults.run_leaf`): a leaf that fails twice is demoted to the
 # traced-XLA execution of the SAME pass, the (backend, pass-kind) pair is
 # quarantined for the process (warm re-plans skip the kernel entirely),

@@ -27,7 +27,6 @@ from typing import Optional
 __all__ = [
     "HW",
     "V5E",
-    "A100",
     "COLLECTIVE_LAUNCH_S",
     "collective_bytes",
     "roofline_terms",
@@ -38,9 +37,6 @@ __all__ = [
     "conv_report",
     "pencil_report",
     "prune_candidates",
-    "gpu_program_report",
-    "gpu_plan_report",
-    "xla_gpu_fft_bytes",
     "bluestein_report",
 ]
 
@@ -70,15 +66,6 @@ V5E = HW(
     hbm_bw=819e9,
     link_bw=50e9,
     hbm_bytes=16e9,
-)
-
-A100 = HW(
-    name="gpu-a100",
-    peak_flops_bf16=312e12,
-    peak_flops_f32=19.5e12,
-    hbm_bw=1.555e12,
-    link_bw=600e9,
-    hbm_bytes=40e9,
 )
 
 _DTYPE_BYTES = {
@@ -273,155 +260,6 @@ def bluestein_report(
         "flops_overhead": flops / mixed_flops,
         "hbm_overhead": total / mixed_bytes,
     }
-
-
-def _gpu_fallback_round_trips(p) -> int:
-    """Global-memory round trips of one *unclaimed* pass traced through the
-    XLA fallback: the transform itself plus every transpose the fallback
-    materializes (the fused kernels' whole advantage is not paying these)."""
-    if p.kind == "reorder":
-        return 1
-    pencils, stride, f = p.view_in if p.view_in else (1, 1, p.n)
-    if pencils == 1:
-        return 1
-    if stride == 1:
-        # Natural-order row fallback materializes its output transpose.
-        return 2 if p.view_out != p.view_in else 1
-    # Strided-column fallback: swapaxes in + transform + swapaxes out.
-    return 3
-
-
-def gpu_program_report(
-    passes,
-    claims,
-    *,
-    batch: int = 1,
-    batch_tiles: Optional[dict] = None,
-    shape2d: Optional[tuple] = None,
-    device_kind: Optional[str] = None,
-    hw: HW = A100,
-) -> dict:
-    """The paper's metric for a pass program on CUDA-class hardware:
-    per-pass **shared-memory bytes** (the per-block working set staged in
-    the SM's fast tier) and **global-memory round trips** (claimed leaves
-    touch the signal once; unclaimed passes pay the XLA fallback's
-    materialized transposes on top).
-
-    ``claims`` is the backend's per-leaf predicate
-    (:func:`repro.kernels.fft_gpu.gpu_claims` for the ``pallas_gpu``
-    backend); ``batch_tiles`` maps leaf length → batch tile (a plan's
-    negotiated tiles), defaulting to the shared-memory-budget pick.
-    """
-    from repro.core import limits, plan as plan_lib  # local: analysis stays lazy
-
-    budget = limits.memory_budget(device_kind)
-    rows = []
-    trips = 0
-    global_total = 0
-    smem_max = 0
-    for i, p in enumerate(passes):
-        claimed = bool(claims(p))
-        other = 1
-        if shape2d is not None:
-            n2, n = shape2d
-            other = n if p.axis == -2 else n2
-        gbytes = plan_lib.pass_hbm_bytes(p, batch, other)
-        if claimed:
-            tile = (batch_tiles or {}).get(p.n) or plan_lib.pick_batch_tile_gpu(
-                p, budget
-            )
-            smem = plan_lib.gpu_smem_bytes(p, tile)
-            t = 1
-        else:
-            tile, smem = None, 0  # XLA manages its own staging
-            t = _gpu_fallback_round_trips(p)
-            gbytes += (t - 1) * 2 * batch * other * p.n * 2 * 4  # transposes
-        rows.append(
-            {
-                "pass": i,
-                "kind": p.kind,
-                "axis": p.axis,
-                "n": p.n,
-                "claimed": claimed,
-                "backend": "pallas_gpu" if claimed else "xla",
-                "batch_tile": tile,
-                "smem_bytes": smem,
-                "global_bytes": gbytes,
-                "global_round_trips": t,
-            }
-        )
-        trips += t
-        global_total += gbytes
-        smem_max = max(smem_max, smem)
-    return {
-        "batch": batch,
-        "smem_budget": budget,
-        "passes": rows,
-        "claims": tuple(r["backend"] for r in rows),
-        "global_round_trips": trips,
-        "smem_bytes_max": smem_max,
-        "modeled_global_bytes": global_total,
-        "memory_s": global_total / hw.hbm_bw,
-    }
-
-
-def gpu_plan_report(
-    planned,
-    batch: int = 1,
-    *,
-    device_kind: Optional[str] = None,
-    hw: HW = A100,
-) -> dict:
-    """:func:`gpu_program_report` for a :class:`~repro.core.fft.PlannedFFT`
-    handle — pulls the pass program, the backend's claim surface and the
-    negotiated batch tiles off the plan (this is what ``describe()``/dryrun
-    surface for GPU plans)."""
-    claims = planned.backend.claims
-    if claims is None:
-        from repro.kernels import fft_gpu  # lazy: kernel layer
-
-        claims = fft_gpu.gpu_claims
-    spec = planned.spec
-    shape2d = (spec.n2, spec.n) if spec.n2 is not None else None
-    return gpu_program_report(
-        planned.passes,
-        claims,
-        batch=batch,
-        batch_tiles=dict(planned.batch_tiles),
-        shape2d=shape2d,
-        device_kind=device_kind,
-        hw=hw,
-    )
-
-
-def xla_gpu_fft_bytes(n: int, batch: int = 1) -> int:
-    """Modeled global-memory traffic of the plain-XLA four-step path on GPU
-    — the crossover comparison point for the backend tuner.
-
-    Per four-step level XLA materializes what the fused kernel keeps on-chip:
-    two GEMM round trips, a twiddle cmul round trip and an output transpose
-    — against the fused leaf's single round trip.  Direct-regime sizes are
-    one GEMM either way (the crossover only opens past ``DIRECT_MAX``).
-    """
-    from repro.core import plan as plan_lib  # local: analysis stays lazy
-
-    f32 = 4
-    sig = batch * n * 2 * f32
-    fft_plan = plan_lib.plan_fft(n)
-    total = 0
-    for p in fft_plan.passes:
-        luts = (
-            p.n * p.n * 2 * f32
-            if p.kind == "direct"
-            else (p.n1 * p.n1 + p.n2 * p.n2 + p.n1 * p.n2) * 2 * f32
-        )
-        if p.kind == "direct":
-            total += 2 * sig + luts
-        else:
-            total += 4 * 2 * sig + luts  # 2 GEMMs + cmul + transpose, r/w each
-        if p.twiddle_after is not None:
-            total += 2 * sig  # materialized inter-factor cmul
-    return total
 
 
 def prune_candidates(candidates: list, tol: float = 0.2, vmem_budget: Optional[int] = None) -> list:

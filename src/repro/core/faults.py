@@ -24,7 +24,7 @@ Fault-injection registry
     raises that site's typed error with ``injected=True``.
 
 Quarantine + degradation ledger
-    ``run_leaf`` wraps a claimed pallas/pallas_gpu leaf.  An injected
+    ``run_leaf`` wraps each pallas kernel leaf.  An injected
     fault gets one retry, then the ``(backend, pass-kind)`` pair is
     quarantined for the rest of the process and the leaf executes through
     its traced XLA fallback; each demotion is recorded on the owning plan's
@@ -121,7 +121,7 @@ class PlanError(ReproError, ValueError):
 
 
 class KernelError(ReproError, RuntimeError):
-    """A claimed pallas leaf failed to trace/compile/launch."""
+    """A pallas leaf failed to trace/compile/launch."""
 
 
 class TuningCacheError(ReproError, RuntimeError):
@@ -330,7 +330,7 @@ def run_leaf(
     degradations: Optional[list] = None,
     index: Optional[int] = None,
 ):
-    """Execute one claimed pallas leaf; demote it only for injected faults.
+    """Execute one pallas leaf; demote it only for injected faults.
 
     The happy path is ``attempt()`` guarded only by host-side Python — a
     dict probe and a try — so the traced jaxpr is identical to calling

@@ -24,9 +24,11 @@ name is forced per call or scoped with the :func:`use_backend` context
 manager.  Built-in entries:
 
 ``pallas``    fused Pallas TPU kernels (``repro.kernels``), one HBM round trip
-              per plan level.  Runs under ``interpret=True`` on CPU.
+              per plan level.  Runs under ``interpret=True`` on CPU.  A
+              leaf quarantined by a fault runs its traced-XLA twin
+              (``repro.kernels.ops``) inside the same program.
 ``xla``       pure-JAX four-step with the same factorisation (MXU matmuls on
-              TPU, portable everywhere).  Preferred on CPU/GPU.
+              TPU, portable everywhere).  Preferred on CPU and GPU hosts.
 ``stockham``  radix-2 butterfly reference (the paper's original formulation).
 
 Module functions ``fft/ifft/rfft/irfft/fft2/ifft2/rfft2/irfft2`` remain as
@@ -229,13 +231,6 @@ class Backend:
     second-to-last axis in place (the pencil column pass) — detected from the
     function signature at registration.
 
-    ``claims`` is the per-leaf capability surface: a predicate over program
-    :class:`~repro.core.plan.Pass` records saying which passes the backend
-    executes natively.  ``None`` means the backend claims whole plans (every
-    pass).  A backend with a partial claim surface must fall back to ``xla``
-    for unclaimed passes *inside its own fn* — the registry only records the
-    claim map so :attr:`PlannedFFT.pass_claims` can report it per leaf.
-
     ``seq`` is the registration sequence number — the negotiation tie-break
     (see :func:`_negotiate`).
     """
@@ -244,7 +239,6 @@ class Backend:
     fn: Callable  # (xr, xi, *, inverse: bool, planned: PlannedFFT) -> Planes
     capabilities: BackendCapabilities
     takes_axis: bool = False
-    claims: Optional[Callable] = None  # Pass -> bool; None = claims all
     seq: int = 0
 
 
@@ -265,7 +259,6 @@ def register_backend(
     capabilities: BackendCapabilities | None = None,
     *,
     overwrite: bool = False,
-    claims: Optional[Callable] = None,
 ) -> Backend:
     """Register ``fn`` as FFT backend ``name``.
 
@@ -273,8 +266,6 @@ def register_backend(
     split float32 planes, following ``planned.fft_plan``'s schedule (or its
     own, for reference backends).  If it also takes an ``axis`` keyword it
     will be handed ``axis=-2`` column transforms directly (no transpose glue).
-    ``claims`` declares a per-leaf capability surface (see
-    :class:`Backend`); leave ``None`` for whole-plan backends.
     Registering an existing name requires ``overwrite=True`` so a typo cannot
     silently shadow a built-in.
     """
@@ -285,7 +276,6 @@ def register_backend(
         fn,
         capabilities or BackendCapabilities(),
         takes_axis=_accepts_axis(fn),
-        claims=claims,
         seq=next(_REGISTRY_SEQ),
     )
     _REGISTRY[name] = entry
@@ -433,7 +423,7 @@ def _materialize_luts(
     otherwise); the returned references keep the arrays alive for the
     lifetime of the plan."""
     luts = []
-    if backend_name in ("pallas", "pallas_gpu"):
+    if backend_name == "pallas":
         from repro.kernels import ops as kernel_ops  # lazy: avoids cycle
 
         for p in fft_plan.passes:
@@ -465,22 +455,17 @@ def _materialize_luts(
     return tuple(luts)
 
 
-def _pick_tiles(
-    fft_plan: plan_lib.FFTPlan, batch_hint: Optional[int], *, gpu: bool = False
-) -> tuple:
+def _pick_tiles(fft_plan: plan_lib.FFTPlan, batch_hint: Optional[int]) -> tuple:
     """((leaf_n, batch_tile), ...) — budget-picked, capped by the batch hint.
 
-    ``gpu`` selects the shared-memory working-set model (LUTs staged, not
-    resident) against the device-resolved budget instead of the TPU VMEM
-    model.  The hint only applies to level-free plans: under a split level
-    each leaf runs with batch × co-factor rows, so capping by the user batch
+    The hint only applies to level-free plans: under a split level each
+    leaf runs with batch × co-factor rows, so capping by the user batch
     alone would collapse the tile (and explode the kernel grid) on large
     sizes.
     """
-    picker = plan_lib.pick_batch_tile_gpu if gpu else plan_lib.pick_batch_tile
     tiles = []
     for p in fft_plan.leaf_passes:
-        bt = picker(p)
+        bt = plan_lib.pick_batch_tile(p)
         if batch_hint is not None and not fft_plan.levels:
             cap = 1 << (batch_hint - 1).bit_length()  # next pow2 >= hint
             bt = max(1, min(bt, cap))
@@ -492,8 +477,6 @@ def _tuned_tiles(
     fft_plan: plan_lib.FFTPlan,
     batch_hint: Optional[int],
     cfg: Optional[dict],
-    *,
-    gpu: bool = False,
 ) -> tuple:
     """The heuristic tiles of :func:`_pick_tiles`, scaled per leaf by a
     tuned plan config.
@@ -502,14 +485,13 @@ def _tuned_tiles(
     know per-call batch hints), so it is applied as a scale on top of the
     hint-capped default — a tuned halving halves the capped tile too, and
     the modeled (no-op) pick leaves the hint behavior untouched."""
-    picker = plan_lib.pick_batch_tile_gpu if gpu else plan_lib.pick_batch_tile
-    tiles = dict(_pick_tiles(fft_plan, batch_hint, gpu=gpu))
+    tiles = dict(_pick_tiles(fft_plan, batch_hint))
     if cfg:
         for leaf_n, bt in cfg.get("batch_tiles", {}).items():
             n = int(leaf_n)
             if n not in tiles:
                 continue
-            base = picker(fft_plan.leaf_pass(n))
+            base = plan_lib.pick_batch_tile(fft_plan.leaf_pass(n))
             while base > int(bt) and tiles[n] > 1:
                 base //= 2
                 tiles[n] = max(1, tiles[n] // 2)
@@ -597,7 +579,7 @@ class PlannedFFT:
     def degradations(self) -> tuple:
         """Leaf demotions this plan has taken (snapshot, execution-recorded).
 
-        Each entry is ``{"backend", "kind", "pass", "reason"}``: a claimed
+        Each entry is ``{"backend", "kind", "pass", "reason"}``: a
         pallas leaf that failed twice, was quarantined, and now executes
         through the traced-XLA fallback.  Includes the children's ledgers
         for the real-packing / composed kinds.
@@ -633,22 +615,6 @@ class PlannedFFT:
             return cols.passes + ep + inner.passes
         return tuple(p for c in self.children for p in c.passes) + ep
 
-    @property
-    def pass_claims(self) -> tuple:
-        """Executing backend name per program pass, in :attr:`passes` order.
-
-        Whole-plan backends claim every pass.  A backend with a per-leaf
-        ``claims`` surface (``pallas_gpu``) reports its own name where the
-        pass runs through its kernels and ``"xla"`` where its executor falls
-        back — so a mixed program is observable leaf by leaf.
-        """
-        claims = self.backend.claims
-        if claims is None:
-            return tuple(self.backend.name for _ in self.passes)
-        return tuple(
-            self.backend.name if claims(p) else "xla" for p in self.passes
-        )
-
     def describe(self) -> str:
         spec = self.spec
         size = f"N={spec.n2}x{spec.n}" if spec.n2 is not None else f"N={spec.n}"
@@ -659,7 +625,6 @@ class PlannedFFT:
                 + plan_lib.describe_program(self.fft_plan)
                 + self._describe_tuned()
                 + self._describe_bluestein()
-                + self._describe_gpu()
                 + self._describe_degraded()
             )
         parts = [plan_lib.describe_program(c.fft_plan) for c in self.children
@@ -670,7 +635,6 @@ class PlannedFFT:
         return (
             s
             + self._describe_bluestein()
-            + self._describe_gpu()
             + self._describe_degraded()
         )
 
@@ -689,21 +653,6 @@ class PlannedFFT:
             f"; bluestein: pad {rep['pad']} ({rep['pad_ratio']:.2f}x), "
             f"{rep['flops_overhead']:.1f}x flops vs mixed-radix, "
             f"{rep['hbm_round_trips']} hbm round trips"
-        )
-
-    def _describe_gpu(self) -> str:
-        """Shared-memory bytes + global-memory round trips, appended for GPU
-        plans — the paper's metric on the paper's hardware."""
-        if self.backend.claims is None:
-            return ""
-        from repro.analysis import roofline as rl  # lazy: analysis layer
-
-        rep = rl.gpu_plan_report(self)
-        return (
-            f"; gpu: {rep['global_round_trips']} global round trips, "
-            f"{rep['smem_bytes_max'] / 1024:.0f} KiB peak smem/block "
-            f"(budget {rep['smem_budget'] / 1024:.0f} KiB), "
-            f"claims [{', '.join(rep['claims'])}]"
         )
 
     def _describe_degraded(self) -> str:
@@ -1232,13 +1181,6 @@ def _build_plan(
 
     if backend_name is None:
         entry = _negotiate(spec, platform)
-        if entry.claims is not None and tune != "off":
-            # A per-leaf backend won negotiation: let the tuner decide the
-            # pallas↔xla crossover for this device (modeled by default,
-            # measured under tune="measure"; cached either way).
-            pick = tuning.backend_pick(spec, platform, tune)
-            if pick is not None and pick != entry.name:
-                entry = get_backend(pick)
     else:
         entry = get_backend(backend_name)
         if not entry.capabilities.supports(spec, platform):
@@ -1247,7 +1189,6 @@ def _build_plan(
             )
 
     kind = spec.kind
-    gpu = entry.claims is not None
     if kind in _COMPLEX_KINDS:
         cfg = tuning.plan_config(spec, entry.name, tune)
         fft_plan = plan_lib.plan_fft(
@@ -1263,7 +1204,7 @@ def _build_plan(
             entry,
             fft_plan,
             luts=_materialize_luts(fft_plan, kind == "ifft", entry.name),
-            batch_tiles=_tuned_tiles(fft_plan, spec.batch_hint, cfg, gpu=gpu),
+            batch_tiles=_tuned_tiles(fft_plan, spec.batch_hint, cfg),
             tuned=cfg,
         )
 
@@ -1287,7 +1228,7 @@ def _build_plan(
             entry,
             fft_plan,
             luts=_materialize_luts(fft_plan, kind == "ifft2", entry.name),
-            batch_tiles=_tuned_tiles(fft_plan, None, cfg, gpu=gpu),
+            batch_tiles=_tuned_tiles(fft_plan, None, cfg),
             tuned=cfg,
         )
 
@@ -1405,29 +1346,6 @@ def _pallas_backend(xr, xi, *, inverse, planned, axis=-1):
     )
 
 
-def _pallas_gpu_backend(xr, xi, *, inverse, planned, axis=-1):
-    from repro.kernels import fft_gpu  # lazy: avoids import cycle
-
-    if axis == -2:
-        # Column transforms are not on the GPU claim surface yet — same
-        # transpose-free contraction / sandwich the xla backend uses.
-        return _xla_backend(xr, xi, inverse=inverse, planned=planned, axis=axis)
-    return fft_gpu.execute_plan_gpu(
-        xr,
-        xi,
-        planned.fft_plan,
-        inverse=inverse,
-        batch_tiles=planned.batch_tiles,
-        degradations=planned._degradations,
-    )
-
-
-def _pallas_gpu_claims(p) -> bool:
-    from repro.kernels import fft_gpu  # lazy: avoids import cycle
-
-    return fft_gpu.gpu_claims(p)
-
-
 register_backend(
     "stockham",
     _stockham_backend,
@@ -1450,21 +1368,6 @@ register_backend(
         bluestein=True,
     ),
 )
-# The paper's native hardware.  Registered after xla so the registration-
-# order tie-break resolves the shared gpu preference toward the Triton-shaped
-# kernels; cpu stays xla's (pallas_gpu does not prefer cpu — it merely runs
-# there under interpret mode, which is how CI proves its numerics).
-register_backend(
-    "pallas_gpu",
-    _pallas_gpu_backend,
-    BackendCapabilities(
-        platforms=frozenset({"cpu", "gpu"}),  # cpu = interpret mode
-        preferred_platforms=frozenset({"gpu"}),
-        bluestein=True,
-    ),
-    claims=_pallas_gpu_claims,
-)
-
 
 # ---------------------------------------------------------------------------
 # Plan-cached convenience wrappers (compatibility surface)

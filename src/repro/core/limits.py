@@ -24,10 +24,7 @@ __all__ = [
     "SUBLANES",
     "LANES",
     "row_group",
-    "GPU_SMEM_BUDGETS",
-    "GPU_SMEM_DEFAULT",
     "BLUESTEIN_MIN",
-    "memory_budget",
     "next_pow2",
     "next_fast_len",
     "bluestein_pad",
@@ -81,58 +78,6 @@ def row_group(n1: int, n2: int = LANES) -> int:
     than 8 on a v5e at n = 8192 … 2048, and within 1–10% of 32 at half
     its compile time."""
     return 1 if n1 >= LANES else max(2 * SUBLANES, n2 // n1)
-
-#: Per-SM shared-memory budgets (bytes) for CUDA-class devices, keyed by a
-#: lowercase substring of ``jax.devices()[0].device_kind``.  These are the
-#: opt-in dynamic-shared-memory carveouts (the paper's Fermi generation had
-#: 48 KB; modern parts expose far more), matched most-specific-first.
-GPU_SMEM_BUDGETS = (
-    ("h100", 228 * 1024),
-    ("h200", 228 * 1024),
-    ("b200", 228 * 1024),
-    ("a100", 164 * 1024),
-    ("a10", 164 * 1024),
-    ("l4", 100 * 1024),
-    ("v100", 96 * 1024),
-    ("t4", 64 * 1024),
-    ("p100", 64 * 1024),
-)
-
-#: Conservative fallback for unrecognized GPU device kinds: the 48 KB
-#: static shared-memory floor every CUDA generation since Fermi guarantees
-#: (the budget the source paper tiles against).
-GPU_SMEM_DEFAULT = 48 * 1024
-
-
-def memory_budget(device_kind: str | None = None) -> int:
-    """Fast-tier working-set budget (bytes) for ``device_kind``.
-
-    The regime map used to hard-code the TPU ``VMEM_BUDGET``; on CUDA-class
-    devices the same decisions (leaf batch tiles, pass chunk widths, tuner
-    feasibility) bind against per-SM shared memory instead.  ``device_kind``
-    defaults to the first visible jax device; TPU and CPU resolve to
-    ``VMEM_BUDGET`` (CPU hosts interpret-mode runs of the TPU schedule), GPU
-    kinds resolve through :data:`GPU_SMEM_BUDGETS`.
-    """
-    if device_kind is None:
-        try:
-            import jax
-
-            device = jax.devices()[0]
-            device_kind = device.device_kind
-            if device.platform not in ("gpu", "cuda", "rocm"):
-                return VMEM_BUDGET
-        except Exception:
-            return VMEM_BUDGET
-    kind = device_kind.lower()
-    if "tpu" in kind or kind in ("cpu", "", "interpreter"):
-        return VMEM_BUDGET
-    for tag, budget in GPU_SMEM_BUDGETS:
-        if tag in kind:
-            return budget
-    if any(t in kind for t in ("nvidia", "cuda", "gpu", "rtx", "geforce", "amd", "mi3")):
-        return GPU_SMEM_DEFAULT
-    return VMEM_BUDGET
 
 
 #: Smallest non-power-of-two length the Bluestein chirp-conv leaf accepts.

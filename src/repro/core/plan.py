@@ -54,7 +54,6 @@ from repro.core.limits import (
     VMEM_BUDGET,
     VMEM_LIMIT,
     bluestein_pad,
-    memory_budget,
     row_group,
 )
 
@@ -91,10 +90,10 @@ __all__ = [
 
 
 #: The name of every ``pallas_call`` under ``repro.kernels``, one per
-#: kernel family (``_gpu``: the Triton-shaped variants).  The compiled HLO
-#: names each kernel instruction after it, so a profiler trace says which
-#: kernel ran; the scopes of :func:`pass_scope` and the transform kinds
-#: say which pass of which transform.
+#: kernel family.  The compiled HLO names each kernel instruction after it,
+#: so a profiler trace says which kernel ran; the scopes of
+#: :func:`pass_scope` and the transform kinds say which pass of which
+#: transform.
 KERNEL_NAMES = (
     "dft_direct",
     "fft4step",
@@ -106,21 +105,14 @@ KERNEL_NAMES = (
     "bluestein_fwd",
     "bluestein_inv",
     "bluestein_elem",
-    "dft_direct_gpu",
-    "fft4step_gpu",
-    "pencil_rows_natural_gpu",
-    "bluestein_fwd_gpu",
-    "bluestein_inv_gpu",
-    "bluestein_elem_gpu",
 )
 
 
-def kernel_name(family: str, gpu: bool = False) -> str:
+def kernel_name(family: str) -> str:
     """The ``pallas_call`` name of a kernel family, from :data:`KERNEL_NAMES`."""
-    name = f"{family}_gpu" if gpu else family
-    if name not in KERNEL_NAMES:
-        raise ValueError(f"{name!r} is not a kernel name of KERNEL_NAMES")
-    return name
+    if family not in KERNEL_NAMES:
+        raise ValueError(f"{family!r} is not a kernel name of KERNEL_NAMES")
+    return family
 
 
 def _is_pow2(n: int) -> bool:
@@ -665,60 +657,6 @@ def pick_batch_tile(p: Pass, budget: int = VMEM_BUDGET) -> int:
     and at least ``SUBLANES`` rows (the TPU block's sublane tile)."""
     bt = 512
     while bt > SUBLANES and vmem_bytes(p, bt) > budget:
-        bt //= 2
-    return bt
-
-
-#: K-loop staging depth of the Triton GEMM pipeline: the leaf's LUT operands
-#: stream through shared memory in (GPU_LUT_STAGE x tile) stripes rather than
-#: residing whole, so only one stripe per operand is charged to the budget.
-GPU_LUT_STAGE = 32
-
-
-def gpu_smem_bytes(p: Pass, batch_tile: int) -> int:
-    """Modeled per-program shared-memory working set of the GPU row leaf.
-
-    Differs from :func:`vmem_bytes` in what counts as resident: on TPU the
-    whole DFT matrix / twiddle grid sits in VMEM for the block; on a CUDA SM
-    the signal tiles are resident but the LUT operands are software-pipelined
-    through shared memory one :data:`GPU_LUT_STAGE`-deep stripe at a time
-    (the Triton ``dot`` K loop).  Charging the full LUTs against a 48-228 KB
-    budget would force every tile to 1 and misreport the paper's metric.
-    """
-    f32 = 4
-    if p.kind == "bluestein":
-        # Pad-sized tiles; the inner pad-FFT's LUTs pipeline in stripes and
-        # the chirp planes are 1-row operands (charged whole, they're tiny
-        # next to the signal tiles).
-        m_pad = p.n1
-        sig = batch_tile * m_pad * 2 * f32
-        chirps = (p.n + m_pad) * 2 * f32
-        stripes = 0
-        if p.stage in ("fwd", "inv"):
-            inner = _leaf_pass(m_pad)
-            if inner.kind == "direct":
-                stripes = GPU_LUT_STAGE * m_pad * 2 * f32
-            else:
-                stripes = GPU_LUT_STAGE * (inner.n1 + 2 * inner.n2) * 2 * f32
-        return 3 * sig + stripes + chirps
-    if p.kind == "direct":
-        sig = batch_tile * p.n * 2 * f32
-        stripe = GPU_LUT_STAGE * p.n * 2 * f32
-        return 2 * sig + stripe                       # in, out + W stripe
-    sig = batch_tile * p.n * 2 * f32
-    stripes = GPU_LUT_STAGE * (p.n1 + p.n2) * 2 * f32  # W1, W2 stripes
-    tw = GPU_LUT_STAGE * p.n2 * 2 * f32                # twiddle-grid stripe
-    return 3 * sig + stripes + tw                      # in, mid, out
-
-
-def pick_batch_tile_gpu(p: Pass, budget: int | None = None) -> int:
-    """Largest power-of-two batch tile whose GPU shared-memory working set
-    fits ``budget`` (default: the resolved :func:`~repro.core.limits.memory_budget`
-    of the first visible device)."""
-    if budget is None:
-        budget = memory_budget()
-    bt = 512
-    while bt > 1 and gpu_smem_bytes(p, bt) > budget:
         bt //= 2
     return bt
 

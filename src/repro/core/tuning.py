@@ -59,7 +59,6 @@ __all__ = [
     "seed_cache",
     "device_key",
     "plan_config",
-    "backend_pick",
     "tuned_block",
     "modeled_block",
     "pencil_config",
@@ -452,24 +451,14 @@ class TuningSpace:
         keeps the historical plan on ties and deviates only where the
         model's HBM-byte account is strictly cheaper.
 
-        Candidate enumeration and feasibility bind against the *resolved*
-        device budget (:func:`repro.core.limits.memory_budget`): VMEM on
-        TPU/CPU, per-SM shared memory on CUDA-class devices — where the
-        ``pallas_gpu`` backend additionally swaps in the GPU working-set
-        model (LUTs staged through the GEMM pipeline, not resident).
+        Candidate enumeration and feasibility bind against
+        :data:`~repro.core.limits.VMEM_BUDGET`.
         """
         from repro.core import limits, plan as plan_lib
-        from repro.core.limits import DIRECT_MAX, FUSED_MAX
+        from repro.core.limits import DIRECT_MAX, FUSED_MAX, VMEM_BUDGET
 
         n, n2 = spec.n, getattr(spec, "n2", None)
-        budget = limits.memory_budget()
-        gpu = backend_name == "pallas_gpu"
-        if gpu:
-            pick_tile = lambda p: plan_lib.pick_batch_tile_gpu(p, budget)  # noqa: E731
-            tile_bytes = plan_lib.gpu_smem_bytes
-        else:
-            pick_tile = lambda p: plan_lib.pick_batch_tile(p, budget)  # noqa: E731
-            tile_bytes = plan_lib.vmem_bytes
+        budget = VMEM_BUDGET
 
         def build(fused_max, direct_max=DIRECT_MAX, pad=None):
             if n2 is not None:
@@ -494,7 +483,7 @@ class TuningSpace:
                 chunks[str(i)] = max(1, base >> chunk_shift)
             tiles = {}
             for p in plan.leaf_passes:
-                base = pick_tile(p)
+                base = plan_lib.pick_batch_tile(p, budget)
                 tiles[str(p.n)] = max(1, base >> tile_shift)
             cfg = {
                 "fused_max": fused_max,
@@ -525,12 +514,11 @@ class TuningSpace:
                     continue
                 c = config["chunks"].get(str(i))
                 if c is not None:
-                    if not gpu:  # chunked passes are the gpu xla fallback's
-                        worst = max(worst, plan_lib._pass_chunk_bytes(p, c))
+                    worst = max(worst, plan_lib._pass_chunk_bytes(p, c))
                 else:
                     t = config["batch_tiles"].get(str(p.n))
                     if t is not None:
-                        worst = max(worst, tile_bytes(p, t))
+                        worst = max(worst, plan_lib.vmem_bytes(p, t))
             return worst
 
         # Crossover and engine alternatives — only those that actually
@@ -584,20 +572,13 @@ class TuningSpace:
             rng = np.random.default_rng(0)
             shape = (b, n2, n) if n2 is not None else (b, n)
             xr = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-            if gpu:
-                from repro.kernels import fft_gpu
+            from repro.kernels import ops as kernel_ops
 
-                fn = jax.jit(
-                    lambda a: fft_gpu.execute_plan_gpu(a, a, plan, batch_tiles=tiles)
+            fn = jax.jit(
+                lambda a: kernel_ops.execute_plan(
+                    a, a, plan, batch_tiles=tiles, chunks=chunks
                 )
-            else:
-                from repro.kernels import ops as kernel_ops
-
-                fn = jax.jit(
-                    lambda a: kernel_ops.execute_plan(
-                        a, a, plan, batch_tiles=tiles, chunks=chunks
-                    )
-                )
+            )
             return _time(lambda: fn(xr))
 
         size = f"n={n}" + (f",n2={n2}" if n2 is not None else "")
@@ -606,59 +587,6 @@ class TuningSpace:
             f"batch={spec.batch_hint or 0}"
         )
         return cls("plan", key, cands, measure, budget=budget)
-
-    @classmethod
-    def for_backend(cls, spec, platform: str):
-        """The pallas↔xla backend crossover for one 1-D complex spec on a
-        GPU-class device — the registry's negotiation picks the Triton-shaped
-        backend wherever it prefers the platform; this space decides whether
-        that is actually a win *for this spec on this device*.
-
-        Modeled costs are global-memory bytes: the claimed pass program's
-        account (:func:`repro.analysis.roofline.gpu_program_report` — fused
-        leaves touch the signal once, unclaimed passes pay the fallback's
-        transposes) against the plain-XLA four-step account
-        (:func:`repro.analysis.roofline.xla_gpu_fft_bytes` — per level, two
-        GEMM round trips + twiddle + transpose).  ``tune="measure"`` times
-        both backends' planned calls and caches the winner per device_kind.
-        The pallas_gpu candidate leads, so modeled ties keep the negotiated
-        pick.
-        """
-        from repro.analysis import roofline as rl
-        from repro.core import limits, plan as plan_lib
-        from repro.kernels import fft_gpu
-
-        n = spec.n
-        batch = spec.batch_hint or 1
-        fft_plan = plan_lib.plan_fft(n)
-        gpu_rep = rl.gpu_program_report(
-            fft_plan.passes, fft_gpu.gpu_claims, batch=batch
-        )
-        cands = [
-            ({"backend": "pallas_gpu"}, gpu_rep["modeled_global_bytes"], 0),
-            ({"backend": "xla"}, rl.xla_gpu_fft_bytes(n, batch), 0),
-        ]
-
-        def measure(config):
-            import jax
-            import jax.numpy as jnp
-            import numpy as np
-            from repro.core import fft as F  # lazy: avoids cycle
-
-            planned = F.plan(spec, backend=config["backend"], tune="off")
-            rng = np.random.default_rng(0)
-            b = spec.batch_hint or 2
-            xr = jnp.asarray(rng.standard_normal((b, n)), jnp.float32)
-            fn = jax.jit(lambda a: planned.apply_planes(a, a))
-            return _time(lambda: fn(xr))
-
-        key = (
-            f"{platform}|backend_xover|{spec.kind}|n={n}|"
-            f"batch={spec.batch_hint or 0}"
-        )
-        return cls(
-            "backend_xover", key, cands, measure, budget=limits.memory_budget()
-        )
 
     @classmethod
     def for_pencil(
@@ -887,38 +815,9 @@ def plan_config(spec, backend_name: str, tune: Optional[str] = None) -> Optional
     mode = resolve_mode(tune)
     if mode == "off":
         return None
-    if backend_name == "pallas_gpu":
-        # The Triton-shaped executor is 1-D only (2-D specs compose per-axis
-        # child plans, which re-enter here with their 1-D specs).
-        if getattr(spec, "n2", None) is not None:
-            return None
-    elif backend_name != "pallas":
+    if backend_name != "pallas":
         # Only the pallas executors consume chunks/tiles; other backends
         # re-derive their own schedule, so there is nothing to tune yet.
         return None
     space = TuningSpace.for_plan(spec, backend_name)
     return space.decide(mode)
-
-
-def backend_pick(spec, platform: str, tune: Optional[str] = None) -> Optional[str]:
-    """The tuned pallas↔xla crossover pick for a plan whose negotiated
-    backend carries per-pass claims (i.e. ``pallas_gpu``), or ``None`` to
-    keep the negotiated backend.
-
-    ``off`` never overrides (no cache traffic, no measurement); ``model``
-    compares the claimed program's modeled global-memory bytes against the
-    plain-XLA four-step account; ``measure`` times both planned calls once
-    per ``(device_kind, spec)`` and caches the winner.  Only 1-D complex
-    specs participate — everything else keeps negotiation's answer.
-    """
-    mode = resolve_mode(tune)
-    if mode == "off":
-        return None
-    if spec.kind not in ("fft", "ifft") or getattr(spec, "n2", None) is not None:
-        return None
-    if spec.n & (spec.n - 1):
-        # Non-pow2 (Bluestein) specs keep negotiation's answer: the XLA
-        # yardstick models the pow2 four-step, not the chirp-conv program.
-        return None
-    space = TuningSpace.for_backend(spec, platform)
-    return str(space.decide(mode)["backend"])

@@ -11,7 +11,7 @@ regime (``M ≤ FUSED_MAX``) the whole pipeline is exactly TWO
 * ``bluestein_fwd_call`` — chirp pre-multiply, the zero-pad to ``M``
   (VMEM-internal ``concatenate``, never an HBM pad pass), the forward
   pad-length transform through the same :func:`~repro.kernels.dft_matmul.
-  dft_tile` / :func:`~repro.kernels.fft4step.four_step_tile` engines every
+  dft_tile` / :func:`~repro.kernels.fft4step.four_step_rows` engines every
   other leaf uses, and the ⊙B̂ chirp-spectrum multiply — one kernel;
 * ``bluestein_inv_call`` — the inverse pad-length transform (1/M folded in
   its LUTs), the slice back to ``n`` (VMEM-internal) and the chirp
@@ -23,10 +23,7 @@ Past the fused regime the pad length's own split program runs the conv and
 
 The chirp planes and the B̂ spectrum are host-cached float64 tables
 (:mod:`repro.core.twiddle`), pinned to block (0, 0) like every other LUT —
-computed once per interned plan, served at VMEM bandwidth.  ``gpu=True``
-swaps Mosaic ``dimension_semantics`` for Triton ``num_warps``/``num_stages``
-(or nothing under interpret), exactly the :mod:`repro.kernels.fft_gpu`
-convention, so both accelerator paths share one kernel body.
+computed once per interned plan, served at VMEM bandwidth.
 """
 
 from __future__ import annotations
@@ -36,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.pallas import triton as plt
 
 from repro.core.fft_xla import cmul
 from repro.core.limits import VMEM_LIMIT
@@ -53,18 +49,11 @@ __all__ = [
 ]
 
 
-def _params(gpu: bool, interpret: bool) -> dict:
-    """Per-lowering compiler params: Mosaic batch-parallel semantics on the
-    TPU path, Triton launch hints on the GPU path (none under interpret)."""
-    if gpu:
-        if interpret:
-            return {}
-        return {"compiler_params": plt.CompilerParams(num_warps=4, num_stages=2)}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
-        )
-    }
+def _params() -> pltpu.CompilerParams:
+    """Mosaic batch-parallel semantics and the VMEM ceiling."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT
+    )
 
 
 def _inner_specs(inner_luts) -> list:
@@ -95,7 +84,6 @@ def bluestein_fwd_call(
     in2: int = 0,
     batch_tile: int,
     interpret: bool = False,
-    gpu: bool = False,
 ) -> Planes:
     """Fused Bluestein forward half: x (B, n) → FFT_M(chirp·x ‖ 0) ⊙ B̂ (B, M).
 
@@ -132,7 +120,7 @@ def bluestein_fwd_call(
     in_specs += [spec, spec]
     fn = pl.pallas_call(
         kernel,
-        name=kernel_name("bluestein_fwd", gpu),
+        name=kernel_name("bluestein_fwd"),
         grid=(b // batch_tile,),
         in_specs=in_specs,
         out_specs=[sig_out, sig_out],
@@ -141,7 +129,7 @@ def bluestein_fwd_call(
             jax.ShapeDtypeStruct((b, m_pad), jnp.float32),
         ],
         interpret=interpret,
-        **_params(gpu, interpret),
+        compiler_params=_params(),
     )
     return tuple(fn(xr, xi, *(jnp.asarray(a) for a in luts)))
 
@@ -158,7 +146,6 @@ def bluestein_inv_call(
     in2: int = 0,
     batch_tile: int,
     interpret: bool = False,
-    gpu: bool = False,
 ) -> Planes:
     """Fused Bluestein inverse half: x (B, M) → chirp·IFFT_M(x)[:n] (B, n).
 
@@ -188,7 +175,7 @@ def bluestein_inv_call(
     in_specs += [chirp, chirp]
     fn = pl.pallas_call(
         kernel,
-        name=kernel_name("bluestein_inv", gpu),
+        name=kernel_name("bluestein_inv"),
         grid=(b // batch_tile,),
         in_specs=in_specs,
         out_specs=[sig_out, sig_out],
@@ -197,7 +184,7 @@ def bluestein_inv_call(
             jax.ShapeDtypeStruct((b, n), jnp.float32),
         ],
         interpret=interpret,
-        **_params(gpu, interpret),
+        compiler_params=_params(),
     )
     return tuple(fn(xr, xi, *(jnp.asarray(a) for a in luts)))
 
@@ -212,7 +199,6 @@ def bluestein_elem_call(
     m_pad: int,
     batch_tile: int,
     interpret: bool = False,
-    gpu: bool = False,
 ) -> Planes:
     """One elementwise chirp stage of the split-regime Bluestein program.
 
@@ -247,7 +233,7 @@ def bluestein_elem_call(
     lut = pl.BlockSpec((1, w_lut), lambda i: (0, 0))
     fn = pl.pallas_call(
         kernel,
-        name=kernel_name("bluestein_elem", gpu),
+        name=kernel_name("bluestein_elem"),
         grid=(b // batch_tile,),
         in_specs=[sig_in, sig_in, lut, lut],
         out_specs=[sig_out, sig_out],
@@ -256,6 +242,6 @@ def bluestein_elem_call(
             jax.ShapeDtypeStruct((b, w_out), jnp.float32),
         ],
         interpret=interpret,
-        **_params(gpu, interpret),
+        compiler_params=_params(),
     )
     return tuple(fn(xr, xi, *(jnp.asarray(a) for a in planes)))

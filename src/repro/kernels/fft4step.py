@@ -187,8 +187,8 @@ def four_step_tile(
 ):
     """The four-step dataflow on a (bt, n1·n2) tile of values.
 
-    Pure jnp — callable from a kernel body without scratch refs (the
-    Triton one) or traced directly (the XLA fallback).  Maps the row group
+    Pure jnp, traced directly by the XLA fallback
+    (``ops._row_transform_xla``).  Maps the row group
     of :func:`four_step_rows` over the tile's rows, zero-padding them to a
     whole number of groups.  Returns (yr, yi) of shape (bt, n1·n2), in
     natural or pencil (k1-major) order; the LUTs are the leaf's, as

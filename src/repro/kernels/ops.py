@@ -32,6 +32,11 @@ tile padding, LUT construction (host-cached, inverse scaling folded into W2 /
 W; the inter-factor twiddle grids cached per (bins, phases) pair), interpret-
 mode selection (auto on CPU), and per-pass chunk sizing against the VMEM
 budget.  ``ops.fft``/``ops.ifft`` remain as plan-deriving conveniences.
+
+Each kernel pass has a traced-XLA twin beside it (:func:`_xla_pass` for
+row passes, :func:`_cols_image_xla` for image columns): the same LUTs and
+scaling, run as plain XLA ops.  :func:`repro.core.faults.run_leaf` demotes
+a leaf to it only after an injected fault quarantines ``(pallas, kind)``.
 """
 
 from __future__ import annotations
@@ -45,11 +50,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import faults
+from repro.core import fft_xla
 from repro.core import plan as plan_lib
 from repro.core import twiddle as tw
+from repro.core.fft_xla import cmul
 from repro.core.limits import LANES, SUBLANES, row_group
-from repro.kernels.dft_matmul import dft_matmul_call
-from repro.kernels.fft4step import fft4step_call, lane_group_luts
+from repro.kernels.dft_matmul import dft_matmul_call, dft_tile
+from repro.kernels.fft4step import fft4step_call, four_step_tile, lane_group_luts
 from repro.kernels import pencil
 
 Planes = Tuple[jax.Array, jax.Array]
@@ -163,9 +170,7 @@ def _bluestein_luts(p: plan_lib.Pass, inverse: bool):
     return (*inner_luts, pr.reshape(1, n), pi.reshape(1, n))
 
 
-def _bluestein_pass(
-    xr, xi, p: plan_lib.Pass, inverse, interpret, bt, gpu: bool = False
-) -> Planes:
+def _bluestein_pass(xr, xi, p: plan_lib.Pass, inverse, interpret, bt) -> Planes:
     """One Bluestein program pass (any stage) as a single pallas_call."""
     from repro.kernels import bluestein as bk
 
@@ -173,7 +178,7 @@ def _bluestein_pass(
     bt = _batch_tile(bt, xr.shape[0])
     xr, xi, b, pad = _pad_batch(xr, xi, bt)
     luts = _bluestein_luts(p, inverse)
-    kw = dict(n=n, m_pad=m_pad, batch_tile=bt, interpret=interpret, gpu=gpu)
+    kw = dict(n=n, m_pad=m_pad, batch_tile=bt, interpret=interpret)
     if p.stage in ("fwd", "inv"):
         inner = plan_lib._leaf_pass(m_pad)
         call = bk.bluestein_fwd_call if p.stage == "fwd" else bk.bluestein_inv_call
@@ -269,22 +274,89 @@ def _apply_pass(
         "pallas",
         p.kind,
         lambda: _pass_kernel(xr, xi, p, inverse, interpret, batch_tiles, chunk),
-        lambda: _row_pass_xla(xr, xi, p, inverse),
+        lambda: _xla_pass(xr, xi, p, inverse),
         degradations=degradations,
         index=index,
     )
 
 
-def _row_pass_xla(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
-    """Traced-XLA execution of one row pass — the degradation target.
+def _row_transform_xla(xr2, xi2, p: plan_lib.Pass, luts, natural: bool = True):
+    """Traced last-axis transform of (R, f) planes — the fallback's engine
+    (the same pure-jnp tiles the kernels embed, just not inside a
+    pallas_call)."""
+    if p.kind == "direct":
+        return dft_tile(xr2, xi2, jnp.asarray(luts[0]), jnp.asarray(luts[1]))
+    w1r, w1i, tr, ti, w2r, w2i = (jnp.asarray(a) for a in luts)
+    return four_step_tile(xr2, xi2, w1r, w1i, tr, ti, w2r, w2i, p.n1, p.n2, natural)
 
-    Reuses the GPU backend's generic per-pass fallback (same LUT tables,
-    same scaling convention), imported lazily: ``fft_gpu`` imports this
-    module at load time.
+
+def _bluestein_xla_pass(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
+    """One Bluestein program stage, traced through XLA.
+
+    Same interned chirp/B̂ tables as the kernel path; the pad-length
+    transform runs through :func:`repro.core.fft_xla.four_step_fft`
+    (forward for ``fwd``, true inverse — 1/M folded — for ``inv``).
     """
-    from repro.kernels import fft_gpu
+    n, m_pad = p.n, p.n1
+    if p.stage in ("pre", "fwd"):
+        ar, ai = tw.bluestein_chirp(n, inverse)
+        xr, xi = cmul(xr, xi, jnp.asarray(ar)[None], jnp.asarray(ai)[None])
+        xr = jnp.pad(xr, ((0, 0), (0, m_pad - n)))
+        xi = jnp.pad(xi, ((0, 0), (0, m_pad - n)))
+        if p.stage == "pre":
+            return xr, xi
+        xr, xi = fft_xla.four_step_fft(xr, xi)
+    if p.stage in ("mul", "fwd"):
+        br, bi = tw.bluestein_spectrum(n, m_pad, inverse)
+        return cmul(xr, xi, jnp.asarray(br)[None], jnp.asarray(bi)[None])
+    if p.stage == "inv":
+        xr, xi = fft_xla.four_step_fft(xr, xi, inverse=True)
+    elif p.stage != "post":
+        raise ValueError(f"unknown bluestein stage {p.stage!r}")
+    pr, pi = tw.bluestein_postchirp(n, inverse)
+    return cmul(
+        xr[:, :n], xi[:, :n], jnp.asarray(pr)[None], jnp.asarray(pi)[None]
+    )
 
-    return fft_gpu._xla_pass(xr, xi, p, [], inverse)
+
+def _xla_pass(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
+    """Traced-XLA execution of one non-reorder row pass over (B, n) planes —
+    the degradation target of :func:`_apply_pass`.
+
+    Same host-cached LUT tables, same per-pass 1/f inverse folding and
+    twiddle-after convention as :func:`_pass_kernel`, but its transposes
+    are plain XLA ops.
+    """
+    b, n = xr.shape
+    if p.kind == "bluestein":
+        return _bluestein_xla_pass(xr, xi, p, inverse)
+    pencils, stride, f = p.view_in if p.view_in else (1, 1, p.n)
+    if pencils == 1:
+        natural = p.order == "natural"
+        luts = _transform_luts(p, inverse, natural)
+        return _row_transform_xla(xr, xi, p, luts, natural=natural)
+    luts = _transform_luts(p, inverse)
+    if stride == 1:
+        rr = xr.reshape(b * pencils, f)
+        ri = xi.reshape(b * pencils, f)
+        rr, ri = _row_transform_xla(rr, ri, p, luts)
+        if p.view_out != p.view_in:
+            # Natural-order write: (b, p, f) → (b, f, p), materialized.
+            rr = rr.reshape(b, pencils, f).swapaxes(-1, -2)
+            ri = ri.reshape(b, pencils, f).swapaxes(-1, -2)
+        return rr.reshape(b, n), ri.reshape(b, n)
+    # Strided-column pass: transform length f down axis -2 of the
+    # (b·groups, f, stride) view, then the inter-factor twiddle.
+    groups = pencils // stride
+    xr3 = xr.reshape(b * groups, f, stride).swapaxes(-1, -2)
+    xi3 = xi.reshape(b * groups, f, stride).swapaxes(-1, -2)
+    rr, ri = _row_transform_xla(xr3.reshape(-1, f), xi3.reshape(-1, f), p, luts)
+    yr3 = rr.reshape(b * groups, stride, f).swapaxes(-1, -2)
+    yi3 = ri.reshape(b * groups, stride, f).swapaxes(-1, -2)
+    if p.twiddle_after is not None:
+        tr, ti = _pass_twiddle_luts(*p.twiddle_after, inverse)
+        yr3, yi3 = cmul(yr3, yi3, jnp.asarray(tr)[None], jnp.asarray(ti)[None])
+    return yr3.reshape(b, n), yi3.reshape(b, n)
 
 
 def _pass_kernel(
@@ -382,8 +454,6 @@ def _cols_image_xla(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
     """Traced-XLA execution of an axis -2 column pass (degradation target):
     materialize the width transpose, run the generic 1-D fallback over the
     column axis, transpose back."""
-    from repro.kernels import fft_gpu
-
     b, rows, w = xr.shape
     pencils, stride, f = p.view_in if p.view_in else (1, 1, p.n)
     xt_r = jnp.swapaxes(xr, -1, -2).reshape(b * w, rows)
@@ -392,13 +462,13 @@ def _cols_image_xla(xr, xi, p: plan_lib.Pass, inverse) -> Planes:
         # Whole-column transform (incl. the distributed driver's synthetic
         # (q, q, n) pass): one natural-order row transform of length rows.
         natural = p.order == "natural"
-        yr, yi = fft_gpu._row_transform_xla(
+        yr, yi = _row_transform_xla(
             xt_r, xt_i, p, _transform_luts(p, inverse, natural), natural=natural
         )
     else:
         # Strip-mined column factor: the re-tagged 1-D split program of the
         # n2 axis applies verbatim on the transposed (B·w, n2) view.
-        yr, yi = fft_gpu._xla_pass(xt_r, xt_i, p, [], inverse)
+        yr, yi = _xla_pass(xt_r, xt_i, p, inverse)
     yr = yr.reshape(b, w, rows).swapaxes(-1, -2)
     yi = yi.reshape(b, w, rows).swapaxes(-1, -2)
     return yr, yi

@@ -13,7 +13,7 @@ natural-order transpose) happens inside their VMEM bodies:
     place through BlockSpecs that index ``(1, f, chunk)`` sub-blocks.  No
     materialized HBM ``swapaxes``: the (f, chunk) tile is transposed in VMEM,
     pushed through the shared tile engines (:func:`~repro.kernels.dft_matmul.
-    dft_tile` for f ≤ 1024, :func:`~repro.kernels.fft4step.four_step_tile`
+    dft_tile` for f ≤ 1024, :func:`~repro.kernels.fft4step.four_step_rows`
     beyond), transposed back, and multiplied by its chunk of the inter-factor
     twiddle grid (a host-cached LUT served chunk-by-chunk through its own
     BlockSpec — the paper's texture table, §2.3.1).
@@ -50,7 +50,7 @@ from repro.core.fft_xla import cmul
 from repro.core.limits import LANES, SUBLANES, VMEM_LIMIT
 from repro.core.plan import kernel_name
 from repro.kernels.dft_matmul import dft_tile
-from repro.kernels.fft4step import four_step_rows, four_step_tile
+from repro.kernels.fft4step import four_step_rows
 
 __all__ = [
     "cols_pass_call",
@@ -61,16 +61,13 @@ __all__ = [
 ]
 
 
-def _tile_transform(xr, xi, luts, kind: str, n1: int, n2: int, scratch=()):
+def _tile_transform(xr, xi, luts, kind: str, n1: int, n2: int, scratch):
     """Dispatch a (bt, f) VMEM tile to the shared direct/four-step engines.
-    On the TPU the four-step runs row group by row group in place over the
-    two ``scratch`` (bt, f) VMEM refs (see :func:`_scratch`); without
-    scratch (Triton has none) it runs on the whole tile's values."""
+    The four-step runs row group by row group in place over the two
+    ``scratch`` (bt, f) VMEM refs (see :func:`_scratch`)."""
     if kind == "direct":
         wr, wi = luts
         return dft_tile(xr, xi, wr, wi)
-    if not scratch:
-        return four_step_tile(xr, xi, *luts, n1, n2)
     s_r, s_i = scratch
     s_r[...] = xr
     s_i[...] = xi
@@ -90,9 +87,9 @@ def _scratch(kind: str, rows: int, f: int) -> list:
     return [pltpu.VMEM((rows, f), jnp.float32)] * 2
 
 
-def _split_rest(rest, n_luts: int, kind: str, scratch: bool = True):
+def _split_rest(rest, n_luts: int, kind: str):
     """(luts, extra inputs, outputs, scratch) out of a kernel's ``rest``."""
-    n_scr = 2 if scratch and kind != "direct" else 0
+    n_scr = 2 if kind != "direct" else 0
     body = rest[: len(rest) - n_scr]
     return (
         [r[...] for r in body[:n_luts]],
@@ -216,12 +213,11 @@ def cols_pass_call(
     return tuple(fn(*operands))
 
 
-def _make_rows_kernel(kind: str, n1: int, n2: int, n_luts: int, scratch=True):
-    """The rows-natural kernel body; ``scratch=False`` for a lowering
-    without scratch operands (the Triton one)."""
+def _make_rows_kernel(kind: str, n1: int, n2: int, n_luts: int):
+    """The rows-natural kernel body."""
 
     def kernel(x_r, x_i, *rest):
-        luts, _, (o_r, o_i), scr = _split_rest(rest, n_luts, kind, scratch)
+        luts, _, (o_r, o_i), scr = _split_rest(rest, n_luts, kind)
         c, f = x_r.shape[1], x_r.shape[2]
         xr = x_r[...].reshape(c, f)
         xi = x_i[...].reshape(c, f)

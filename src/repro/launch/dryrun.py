@@ -168,29 +168,9 @@ def _fft_plan_info(fft_shape, model_n: int) -> dict:
     per pass so the round-trip count is observable in every artifact, not
     just asserted by tests.  1-D pencil cells record the per-leaf pass
     programs (one plan per pencil factor); 2-D cells record the ONE joint
-    rows+columns program ``pfft2d`` now splits around its all-to-alls.
-    Each leaf also carries the GPU-shaped account (``gpu_reports``): per-pass
-    shared-memory bytes against the device budget and global-memory round
-    trips under the ``pallas_gpu`` claim set, so the pallas↔xla crossover is
-    auditable from the artifact alone."""
+    rows+columns program ``pfft2d`` now splits around its all-to-alls."""
     from repro.core import distributed as dist
     from repro.core import plan as plan_lib
-    from repro.kernels.fft_gpu import gpu_claims
-
-    def _gpu_report(m: int, batch: int) -> dict:
-        rep = rl.gpu_program_report(
-            plan_lib.plan_fft(m).passes, gpu_claims, batch=batch
-        )
-        return {
-            k: rep[k]
-            for k in (
-                "global_round_trips",
-                "smem_bytes_max",
-                "smem_budget",
-                "modeled_global_bytes",
-                "claims",
-            )
-        }
 
     def _bluestein_reports(lengths, batch: int) -> list:
         # Non-pow2 leaves route through the Bluestein chirp-conv program;
@@ -210,7 +190,6 @@ def _fft_plan_info(fft_shape, model_n: int) -> dict:
             "pass_programs": [
                 rl.fft_pass_report(n_row, batch=fft_shape.batch, n2=n_col)
             ],
-            "gpu_reports": [_gpu_report(n_row, fft_shape.batch * n_col)],
         }
         blu = _bluestein_reports([n_row], fft_shape.batch * n_col)
         if blu:
@@ -242,9 +221,6 @@ def _fft_plan_info(fft_shape, model_n: int) -> dict:
         "pass_programs": [
             rl.fft_pass_report(m, batch=fft_shape.batch * (total // m))
             for m in leaf_ns
-        ],
-        "gpu_reports": [
-            _gpu_report(m, fft_shape.batch * (total // m)) for m in leaf_ns
         ],
     }
     if fft_shape.kind == "fftconv":

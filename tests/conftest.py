@@ -27,6 +27,38 @@ def rng():
     return np.random.default_rng(0)
 
 
+#: A pseudo-backend for ``parametrize("backend", [..., DEMOTED], indirect=True)``:
+#: the case plans with ``pallas`` while every kernel launch fails, so each
+#: leaf is quarantined and runs ``repro.kernels.ops``' traced-XLA fallback.
+DEMOTED = "demoted"
+
+
+@pytest.fixture
+def backend(request):
+    """The registered backend a case plans with (indirect parametrization).
+
+    For :data:`DEMOTED` it yields ``"pallas"`` with ``kernel.launch``
+    armed for every attempt.  Quarantine is process-global, so the
+    quarantine, the armed faults, the degradation ledger and the plan
+    cache are cleared after the case.
+    """
+    from repro.core import faults
+    from repro.core import fft as F
+
+    if request.param != DEMOTED:
+        yield request.param
+        return
+    F._plan_cached.cache_clear()
+    try:
+        with faults.inject_fault("kernel.launch", times=10**9):
+            yield "pallas"
+    finally:
+        faults.clear_quarantine()
+        faults.clear_faults()
+        faults.clear_degradations()
+        F._plan_cached.cache_clear()
+
+
 def run_in_subprocess(code: str, devices: int = 8, timeout: int = 600) -> str:
     """Run python ``code`` with N fake CPU devices; returns stdout."""
     env = dict(os.environ)

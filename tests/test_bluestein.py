@@ -4,8 +4,9 @@ The tentpole's acceptance gates, made literal:
 
 * numerics — planned non-pow2 fft/ifft/rfft/irfft match ``numpy.fft`` at
   1e-3 across primes, 3·2^k, and the n=1 degenerate case;
-* purity — a Bluestein leaf executes as claimed pallas_calls + shape glue
-  only (jaxpr-asserted) on both the TPU and ``pallas_gpu`` interpret paths;
+* purity — a Bluestein leaf executes as pallas_calls + shape glue only
+  (jaxpr-asserted), and a demoted one as no pallas_call at all, one
+  degradation per pass;
 * interning — the chirp spectrum is computed once per interned plan: zero
   new plans on warm reuse (``plan_log()``-asserted), and the spectrum LUT
   is cache-identical across lookups.
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, st
+from conftest import DEMOTED
 
 from repro.analysis import roofline as rl
 from repro.core import fft as F
@@ -45,7 +47,7 @@ def _rand_c(rng, shape):
 
 
 @pytest.mark.parametrize("n", SIZES)
-@pytest.mark.parametrize("backend", ["pallas", "pallas_gpu", "xla"])
+@pytest.mark.parametrize("backend", ["pallas", DEMOTED, "xla"], indirect=True)
 def test_fft_ifft_match_numpy(n, backend, rng):
     x = _rand_c(rng, (3, n))
     tol = 1e-3 * max(np.abs(np.fft.fft(x)).max(), 1.0)
@@ -78,7 +80,7 @@ def test_fft2_non_pow2_rows(rng):
 
 
 # ---------------------------------------------------------------------------
-# jaxpr purity: claimed pallas_calls + shape glue only, both backends
+# jaxpr purity: pallas_calls + shape glue only; demoted, no pallas_call
 # ---------------------------------------------------------------------------
 
 _GLUE = {
@@ -107,16 +109,21 @@ def _collect_prims(jaxpr, acc):
 
 
 @pytest.mark.parametrize("n", [97, 2029])
-@pytest.mark.parametrize("backend", ["pallas", "pallas_gpu"])
-def test_bluestein_leaf_is_pallas_calls_plus_glue(n, backend):
+@pytest.mark.parametrize("backend", ["pallas", DEMOTED], indirect=True)
+def test_bluestein_leaf_is_pallas_calls_plus_glue(n, backend, request):
     p = F.plan(F.FFTSpec(n=n), backend=backend, tune="off")
     assert all(k.kind == "bluestein" for k in p.passes)
-    assert all(c == backend for c in p.pass_claims)
     # tile-aligned batch: no pad/unpad glue beyond the leaf's own framing
     bt = p.batch_tiles[n]
     xr = jnp.zeros((bt, n), jnp.float32)
     jaxpr = jax.make_jaxpr(lambda a, b: p.apply_planes(a, b))(xr, xr)
     prims = _collect_prims(jaxpr.jaxpr, [])
+    if request.node.callspec.params["backend"] == DEMOTED:
+        # Every stage runs ops' XLA fallback: no kernel, one demotion each.
+        assert "pallas_call" not in prims, prims
+        assert [d["pass"] for d in p.degradations] == list(range(len(p.passes)))
+        return
+    assert not p.degradations
     assert prims.count("pallas_call") == len(p.passes), prims
     stray = [q for q in prims if q != "pallas_call" and q not in _GLUE]
     assert not stray, f"Bluestein leaf leaked XLA math outside the kernel: {stray}"

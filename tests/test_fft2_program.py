@@ -13,13 +13,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import DEMOTED
 
 from repro.analysis import roofline as rl
 from repro.core import fft as F
 from repro.core import plan as P
 from repro.core.conv import fft_conv2d, toeplitz_conv_ref
 
-BACKENDS = ["stockham", "xla", "pallas"]
+BACKENDS = ["stockham", "xla", "pallas", DEMOTED]
 
 
 def _rand_c(rng, shape):
@@ -82,7 +83,7 @@ def test_strip_mined_joint_program_beats_fallback_bytes():
     assert rep["fallback_transpose_bytes"] > 0
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("backend", ["xla", "pallas", DEMOTED], indirect=True)
 def test_tall_image_plans_joint_and_matches_numpy(backend, rng):
     # Tall images now plan as ONE joint strip-mined program; non-native-2d
     # backends still execute it through per-axis composition, the pallas
@@ -153,7 +154,7 @@ def test_fft2_schedule_is_pure_pass_program(n2, n):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 @pytest.mark.parametrize("n2,n", [(16, 64), (64, 128), (32, 2048), (128, 32)])
 def test_fft2_matches_numpy(backend, n2, n, rng):
     x = _rand_c(rng, (2, n2, n))
@@ -162,7 +163,7 @@ def test_fft2_matches_numpy(backend, n2, n, rng):
     assert np.abs(y - ref).max() <= 1e-3 * np.abs(ref).max(), (backend, n2, n)
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("backend", ["xla", "pallas", DEMOTED], indirect=True)
 def test_fft2_split_regime_rows(backend, rng):
     x = _rand_c(rng, (1, 4, 2**17))
     y = np.asarray(F.fft2(jnp.asarray(x), backend=backend))
@@ -171,14 +172,14 @@ def test_fft2_split_regime_rows(backend, rng):
     assert rel < 5e-5, (backend, rel)
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 def test_fft2_ifft2_roundtrip(backend, rng):
     x = _rand_c(rng, (2, 32, 256))
     y = F.ifft2(F.fft2(jnp.asarray(x), backend=backend), backend=backend)
     np.testing.assert_allclose(np.asarray(y), x, atol=2e-4)
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 @pytest.mark.parametrize("n2,n", [(16, 64), (64, 256)])
 def test_rfft2_matches_numpy_and_roundtrips(backend, n2, n, rng):
     x = rng.standard_normal((2, n2, n)).astype(np.float32)
@@ -207,7 +208,7 @@ def test_rfft2_plan_carries_epilogue_and_trips():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 def test_apply_rows_cols_compose_to_fft2(backend, rng):
     planned = F.plan(F.FFTSpec(n=256, n2=128, kind="fft2"), backend=backend)
     x = _rand_c(rng, (2, 128, 256))

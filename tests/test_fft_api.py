@@ -4,10 +4,11 @@ scoping, axis-aware transforms, and the cross-backend acceptance sweep."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import DEMOTED
 
 from repro.core import fft as F
 
-BACKENDS = F.available_backends()
+BACKENDS = F.available_backends() + (DEMOTED,)
 # 262144 = 2¹⁸: the split regime's linearized pass program, on every backend.
 ACCEPTANCE_SIZES = [256, 4096, 131072, 262144]
 
@@ -109,6 +110,45 @@ def test_unknown_backend_rejected():
 def test_duplicate_registration_rejected():
     with pytest.raises(ValueError, match="already registered"):
         F.register_backend("xla", lambda *a, **k: None)
+
+
+@pytest.fixture
+def fresh_plans():
+    F._plan_cached.cache_clear()
+    yield
+    F._plan_cached.cache_clear()
+
+
+def test_negotiation_picks_xla_on_cpu_and_gpu():
+    spec = F.FFTSpec(n=4096)
+    # xla is the one backend that prefers cpu and gpu hosts
+    assert F._negotiate(spec, "gpu").name == "xla"
+    assert F._negotiate(spec, "cpu").name == "xla"
+
+
+def test_registered_preferred_backend_beats_default(fresh_plans):
+    spec = F.FFTSpec(n=1024)
+    calls = []
+
+    def fn(xr, xi, *, inverse, planned):
+        calls.append(planned.spec.n)
+        return F._xla_backend(xr, xi, inverse=inverse, planned=planned)
+
+    F.register_backend(
+        "scratch_cpu",
+        fn,
+        F.BackendCapabilities(preferred_platforms=frozenset({"cpu"})),
+    )
+    try:
+        # same score as the xla default on cpu — later registration wins
+        assert F._negotiate(spec, "cpu").name == "scratch_cpu"
+        p = F.plan(spec, tune="off")
+        x = jnp.zeros((2, 1024), jnp.float32)
+        p.apply_planes(x, x)
+        assert calls, "negotiation never routed to the registered backend"
+    finally:
+        F._REGISTRY.pop("scratch_cpu", None)
+        F._plan_cached.cache_clear()
 
 
 @pytest.fixture
@@ -225,11 +265,12 @@ def test_ifft_axis_roundtrip(rng):
 
 
 # ---------------------------------------------------------------------------
-# acceptance sweep: every registered backend, 1e-3, incl. a non-last axis
+# acceptance sweep: every registered backend and the demoted pallas plan
+# (every leaf on the XLA fallback), 1e-3, incl. a non-last axis
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 @pytest.mark.parametrize("n", ACCEPTANCE_SIZES)
 def test_planned_matches_jnp_all_backends(backend, n, rng):
     batch = 1 if n > 2**14 else 3
@@ -240,7 +281,7 @@ def test_planned_matches_jnp_all_backends(backend, n, rng):
     assert np.abs(y - ref).max() <= 1e-3 * np.abs(ref).max(), (backend, n)
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKENDS, indirect=True)
 def test_planned_matches_jnp_non_last_axis(backend, rng):
     x = _rand_c(rng, (2, 4096, 2))
     planned = F.plan(F.FFTSpec(n=4096, kind="fft", axis=1), backend=backend)

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import DEMOTED
 
 from repro.core import fft as fft_lib
 from repro.core import plan as plan_lib
@@ -107,7 +108,7 @@ def test_fft_conv_os_matches_one_shot(L, rng):
     np.testing.assert_allclose(y_os, y_one, atol=1e-3 * scale)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "xla", "stockham"])
+@pytest.mark.parametrize("backend", ["pallas", "xla", "stockham", DEMOTED], indirect=True)
 def test_fft_conv_os_backends_agree(backend, rng):
     # pallas runs interpret on CPU — the kernel path through the engine is
     # exercised in the pallas-interpret CI job; small block keeps it cheap.

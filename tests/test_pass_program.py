@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import DEMOTED
 
 from repro.analysis import roofline as rl
 from repro.core import fft as F
@@ -174,7 +175,7 @@ def test_rows_natural_fuses_transpose(rng):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["pallas", "xla", "stockham"])
+@pytest.mark.parametrize("backend", ["pallas", "xla", "stockham", DEMOTED], indirect=True)
 def test_axis_minus2_plan_matches_jnp(backend, rng):
     n, q = 512, 128
     xr, xi = _rand(rng, (2, n, q))

@@ -99,20 +99,6 @@ CASES = {
     # paired frames; the one recomb_fwd is the filter's own spectrum
     "conv_os": (lambda: _conv_call("pallas"), {"dft_direct", "recomb_fwd"}),
     "conv_os_one_frame": (lambda: _conv_call("pallas", 400), {"dft_direct", "recomb_fwd", "recomb_inv"}),
-    "gpu_direct": (lambda: _complex_call(F.FFTSpec(n=256), "pallas_gpu", (4, 256)), {"dft_direct_gpu"}),
-    "gpu_fused4": (lambda: _complex_call(F.FFTSpec(n=4096), "pallas_gpu", (4, 4096)), {"fft4step_gpu"}),
-    "gpu_split": (
-        lambda: _complex_call(F.FFTSpec(n=2**17), "pallas_gpu", (1, 2**17)),
-        {"pencil_rows_natural_gpu"},
-    ),
-    "gpu_bluestein": (
-        lambda: _complex_call(F.FFTSpec(n=2029), "pallas_gpu", (4, 2029)),
-        {"bluestein_fwd_gpu", "bluestein_inv_gpu"},
-    ),
-    "gpu_bluestein_split": (
-        lambda: _complex_call(F.FFTSpec(n=40000), "pallas_gpu", (1, 40000)),
-        {"bluestein_elem_gpu", "pencil_rows_natural_gpu"},
-    ),
 }
 
 
@@ -133,9 +119,6 @@ def test_the_cases_cover_every_kernel_name():
 
 def test_kernel_name_refuses_names_outside_the_vocabulary():
     assert P.kernel_name("fft4step") == "fft4step"
-    assert P.kernel_name("fft4step", gpu=True) == "fft4step_gpu"
-    with pytest.raises(ValueError):
-        P.kernel_name("pencil_cols", gpu=True)
     with pytest.raises(ValueError):
         P.kernel_name("fused4")
 

@@ -8,6 +8,7 @@ import os
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import run_in_subprocess
 
 from repro.analysis import roofline as rl
 from repro.core import fft as fft_lib
@@ -225,3 +226,44 @@ def test_plan_log_is_ring_buffer(monkeypatch):
 
 def test_plan_log_capacity_is_bounded():
     assert fft_lib._PLAN_LOG.maxlen == fft_lib.PLAN_LOG_MAX > 0
+
+
+# ---------------------------------------------------------------------------
+# the packaged seed cache
+# ---------------------------------------------------------------------------
+
+
+def test_seed_cache_layers_beneath_user_cache():
+    seed = tuning.seed_cache()
+    assert seed, "packaged tuning_seed.json missing or empty"
+    key = "cpu|pallas|plan|fft|n=8192|batch=2"
+    assert key in seed and seed[key]["mode"] == "measure"
+    # the user cache shadows the seed on put()
+    tuning.cache.put(key, {"config": {"sentinel": 1}, "mode": "measure"})
+    try:
+        assert tuning.cache.get(key)["config"] == {"sentinel": 1}
+    finally:
+        tuning.cache.clear()
+    # after clearing the user layer, the seed answers again
+    assert tuning.cache.get(key)["mode"] == "measure"
+
+
+_SEED_BODY = r"""
+from repro.core import fft as F
+from repro.core import tuning
+
+spec = F.FFTSpec(n=8192, kind="fft", batch_hint=2)
+p = F.plan(spec, backend="pallas", tune="measure")
+assert p.tuned is not None
+assert tuning.measure_log() == (), tuning.measure_log()
+print("SEED_ZERO_MEASURE_OK")
+"""
+
+
+def test_seeded_spec_measures_nothing_in_fresh_process():
+    # A FRESH process (cold interning cache, empty user tuning cache —
+    # conftest points REPRO_TUNING_CACHE at a tempdir) plans a seeded spec
+    # under tune="measure" with zero device measurements, because the
+    # packaged seed already has the measured winner.
+    out = run_in_subprocess(_SEED_BODY, devices=1)
+    assert "SEED_ZERO_MEASURE_OK" in out
