@@ -6,7 +6,7 @@ Sweeps the ``BENCH_conv.json`` / ``BENCH_fft.json`` long-conv shapes
 * ``fixed`` — the historical ``OS_FACTOR=8`` heuristic block
   (:func:`repro.core.overlap.pick_block`);
 * ``tuned`` — ``tune="measure"``: the roofline model prunes the block
-  candidates to the few within ~20% of modeled-minimum HBM bytes, the
+  candidates to the few within ~20% of the modeled-minimum device time, the
   measurement pass times them (fixed heuristic always included, so tuned
   can never lose), and the winner lands in the persistent tuning cache.
 

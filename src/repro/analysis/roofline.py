@@ -34,6 +34,12 @@ __all__ = [
     "summarize_cell",
     "fft_pass_report",
     "fft2_fallback_report",
+    "HIGHEST_PASSES",
+    "KARATSUBA_GEMMS",
+    "leaf_contraction",
+    "pass_mxu_s",
+    "pass_s",
+    "program_s",
     "conv_report",
     "pencil_report",
     "prune_candidates",
@@ -57,6 +63,9 @@ class HW:
     hbm_bw: float
     link_bw: float
     hbm_bytes: float
+    #: The MXU's contraction width: a GEMM's contracted dimension is padded
+    #: up to a multiple of it.
+    mxu_k: int = 128
 
 
 V5E = HW(
@@ -67,6 +76,14 @@ V5E = HW(
     link_bw=50e9,
     hbm_bytes=16e9,
 )
+
+#: bfloat16 MXU passes of one float32 GEMM at ``Precision.HIGHEST``, the
+#: precision of every pass kernel's GEMMs.
+HIGHEST_PASSES = 6
+
+#: Real GEMMs per complex GEMM: the kernels' Karatsuba split
+#: (``kernels/fft4step.cgemm_tile``, ``kernels/dft_matmul.dft_tile``).
+KARATSUBA_GEMMS = 3
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
@@ -155,6 +172,48 @@ def collective_bytes(hlo_text: str) -> dict:
 def model_flops(n_params_active: int, tokens: int) -> float:
     """6·N·D — the useful-FLOPs yardstick (N = active params)."""
     return 6.0 * n_params_active * tokens
+
+
+def leaf_contraction(p, hw: HW = V5E) -> int:
+    """The summed contraction lengths of a pass's GEMM stages, each padded
+    up to the MXU width: ``⌈n⌉`` for a direct DFT leaf (one stage),
+    ``⌈n1⌉ + ⌈n2⌉`` for a fused four-step leaf (a lane group, ``n1 <
+    128``, fills its first stage with ``128 / n1`` signals).  0 for a pass
+    that runs no GEMM of its own (reorder, Bluestein chirp stages)."""
+
+    def up(m: int) -> int:
+        return -(-m // hw.mxu_k) * hw.mxu_k
+
+    if p.kind == "direct":
+        return up(p.n)
+    if p.kind == "fused4":
+        return up(p.n1) + up(p.n2)
+    return 0
+
+
+def pass_mxu_s(p, batch: int = 1, hw: HW = V5E) -> float:
+    """Modeled MXU time of one program pass over ``batch`` rows: per point
+    ``KARATSUBA_GEMMS`` real GEMMs of 2 flops per contracted element, over
+    :func:`leaf_contraction`, each in ``HIGHEST_PASSES`` bfloat16 passes,
+    at the chip's bfloat16 peak."""
+    pencils, _stride, f = p.view_in if p.view_in else (1, 1, p.n)
+    flops = batch * pencils * f * KARATSUBA_GEMMS * 2 * leaf_contraction(p, hw)
+    return flops * HIGHEST_PASSES / hw.peak_flops_bf16
+
+
+def pass_s(p, batch: int = 1, hw: HW = V5E) -> float:
+    """Modeled device time of one program pass: the larger of its HBM time
+    (:func:`~repro.core.plan.pass_hbm_bytes` over the HBM bandwidth) and
+    its MXU time (:func:`pass_mxu_s`).  No efficiency factor: it would be
+    the same for every candidate a tuner ranks."""
+    from repro.core import plan as plan_lib  # local: analysis stays lazy
+
+    return max(plan_lib.pass_hbm_bytes(p, batch) / hw.hbm_bw, pass_mxu_s(p, batch, hw))
+
+
+def program_s(passes, batch: int = 1, hw: HW = V5E) -> float:
+    """Modeled device time of a 1-D pass program: its passes run in turn."""
+    return sum(pass_s(p, batch, hw) for p in passes)
 
 
 def fft_pass_report(
@@ -265,13 +324,15 @@ def bluestein_report(
 def prune_candidates(candidates: list, tol: float = 0.2, vmem_budget: Optional[int] = None) -> list:
     """Roofline pruning of a tuning space — the model half of the autotuner.
 
-    ``candidates``: ordered ``(config, modeled_hbm_bytes, vmem_bytes)``
-    triples, the fixed heuristic FIRST.  Keeps candidates whose working set
-    fits the VMEM budget and whose modeled HBM traffic is within ``tol`` of
-    the feasible minimum — the only ones a measurement pass could ever
-    crown — returned sorted by modeled bytes (stable, so the heuristic
-    wins modeled ties; where the model is strictly cheaper, the modeled
-    pick deviates from the heuristic by design).
+    ``candidates``: ordered ``(config, modeled_cost, vmem_bytes)`` triples,
+    the fixed heuristic FIRST.  The cost is one scalar in one unit across a
+    space: HBM bytes for plan configs, modeled seconds for overlap-save
+    blocks and pencil schedules.  Keeps candidates whose working set fits
+    the VMEM budget and whose modeled cost is within ``tol`` of the
+    feasible minimum — the only ones a measurement pass could ever crown —
+    returned sorted by modeled cost (stable, so the heuristic wins modeled
+    ties; where the model is strictly cheaper, the modeled pick deviates
+    from the heuristic by design).
     """
     from repro.core.limits import VMEM_BUDGET  # local: analysis stays lazy
 
@@ -319,40 +380,81 @@ def fft2_fallback_report(n: int, n2: int, batch: int = 1, hw: HW = V5E) -> dict:
     }
 
 
-def _rfft_conv_bytes(n: int, batch: int, plan_lib) -> int:
-    """Modeled HBM traffic of one rfft → ⊙H → irfft pair at length ``n``.
+def _rfft_conv(n: int, batch: int, plan_lib, hw: HW) -> tuple:
+    """Modeled HBM traffic and device time of one rfft → ⊙H → irfft pair
+    at length ``n``: ``(bytes, seconds)``.
 
     The packed complex programs (length n/2) at signal batch, the filter's
     forward transform once, the Hermitian recombination epilogues (read m /
     write m+1 planes per direction) and the spectrum multiply (two reads,
     one write).  Split-complex float32 — the same conventions as
-    :func:`~repro.core.plan.pass_hbm_bytes`.
+    :func:`~repro.core.plan.pass_hbm_bytes`.  The programs take
+    :func:`program_s`, the rest its bytes at HBM bandwidth.
     """
     f32 = 4
     m = n // 2
     prog = plan_lib.plan_fft(max(m, 1)).passes
-    sig_fwd = plan_lib.program_hbm_bytes(prog, batch)
-    sig_inv = plan_lib.program_hbm_bytes(prog, batch)
-    filt_fwd = plan_lib.program_hbm_bytes(prog, 1)
+    progs = 2 * plan_lib.program_hbm_bytes(prog, batch) + plan_lib.program_hbm_bytes(prog, 1)
     # Recombination read+write per transform: 2·batch signal passes + the
     # filter's one; spectrum multiply reads batch X planes + the broadcast
     # H once and writes batch Y planes.
     recomb = (2 * batch + 1) * (2 * m + 1) * 2 * f32
     cmul_b = (2 * batch + 1) * (m + 1) * 2 * f32
-    return sig_fwd + sig_inv + filt_fwd + recomb + cmul_b
+    seconds = 2 * program_s(prog, batch, hw) + program_s(prog, 1, hw)
+    return progs + recomb + cmul_b, seconds + (recomb + cmul_b) / hw.hbm_bw
 
 
-def conv_report(L: int, Lh: int, batch: int = 1, hw: HW = V5E, block=None) -> dict:
-    """One-shot vs overlap-save modeled HBM traffic for an FFT convolution.
+def _paired_conv(block: int, pairs: int, batch: int, L: int, plan_lib, hw: HW) -> tuple:
+    """Modeled HBM traffic and device time of the paired overlap-save path
+    (:func:`repro.core.overlap.overlap_save` where ``pairs_apply`` holds):
+    ``(bytes, seconds)``.
+
+    ``pairs`` complex frames of ``block`` a signal through one fft and one
+    ifft program of length ``block``, the product against the filter's
+    full spectrum (two reads, one write), the filter's one rfft, the
+    framing into pairs (``os_pair``: each transformed frame's window read
+    for its peak and for its scaled copy, then written) and the write-back
+    (``os_unpair``: the output's zero fill, then each of its ``L`` samples
+    read and written once).
+    """
+    f32 = 4
+    rows = batch * pairs
+    prog = plan_lib.plan_fft(block).passes
+    filt = plan_lib.plan_fft(block // 2).passes
+    progs = 2 * plan_lib.program_hbm_bytes(prog, rows) + plan_lib.program_hbm_bytes(filt, 1)
+    filt_recomb = (block + 1) * 2 * f32
+    product = (2 * rows + 1) * block * 2 * f32
+    framing = batch * (3 * 2 * pairs * block + 3 * L) * f32
+    glue = filt_recomb + product + framing
+    seconds = 2 * program_s(prog, rows, hw) + program_s(filt, 1, hw)
+    return progs + glue, seconds + glue / hw.hbm_bw
+
+
+def conv_report(
+    L: int, Lh: int, batch: int = 1, hw: HW = V5E, block=None, stream: bool = False
+) -> dict:
+    """One-shot vs overlap-save modeled HBM traffic and device time for an
+    FFT convolution.
 
     The one-shot path pads to ``next_pow2(L + Lh - 1)`` — beyond the fused
     regime that is a split-regime pass program per transform.  Overlap-save
     frames the signal into ``num_blocks`` blocks of ``block`` samples
-    (fused regime by construction) and batches them through one plan pair;
-    its extra costs — the framing gather, the tail scatter, and the
-    ``block/(block - Lh + 1)`` redundancy factor — are charged explicitly,
-    so the report shows where the crossover actually is rather than
-    asserting it.
+    (fused regime by construction) and is priced as the schedule that runs
+    (:func:`repro.core.overlap.pairs_apply`, from the shapes): two frames
+    of a signal per complex transform, fft/ifft programs of length
+    ``block`` over the pairs; or, with one frame a signal, the rfft/irfft
+    pair (packed programs of ``block / 2``) over every frame, with the
+    framing gather and tail scatter.  The ``block/(block - Lh + 1)``
+    redundancy is in the frame count, so the report shows where the
+    crossover actually is rather than asserting it.
+
+    ``hbm_bytes`` is the modeled traffic; ``modeled_s`` the modeled device
+    time, each pass program at the larger of its HBM and MXU time
+    (:func:`program_s`) and the rest at HBM bandwidth.  The fused leaves
+    wait on the MXU, whose work per point grows with the block, so the
+    tuner ranks blocks by ``modeled_s``.  ``stream``: ``L`` is one
+    :class:`~repro.core.overlap.StreamingConv` call (carried tail and
+    chunk), which runs as one frame whenever it fits one block.
     """
     from repro.core import overlap as ov  # local: analysis stays lazy
     from repro.core import plan as plan_lib
@@ -360,28 +462,38 @@ def conv_report(L: int, Lh: int, batch: int = 1, hw: HW = V5E, block=None) -> di
 
     f32 = 4
     n_one = next_pow2(L + Lh - 1)
-    one_bytes = _rfft_conv_bytes(n_one, batch, plan_lib)
+    one_bytes, one_s = _rfft_conv(n_one, batch, plan_lib, hw)
     one = {
         "n": n_one,
         "hbm_round_trips": 2 * plan_lib.plan_fft(n_one // 2).hbm_round_trips,
         "hbm_bytes": one_bytes,
         "memory_s": one_bytes / hw.hbm_bw,
+        "modeled_s": one_s,
     }
 
     B = ov.pick_block(Lh, block)
     step = B - (Lh - 1)
-    nb = -(-L // step)
-    os_bytes = _rfft_conv_bytes(B, batch * nb, plan_lib)
-    # Framing gather (read L, write nb·B) + tail scatter (read nb·step,
-    # write L), real float32.
-    os_bytes += batch * (L + nb * B + nb * step + L) * f32
+    nb = 1 if stream and L <= B else -(-L // step)
+    paired = ov.pairs_apply(nb, B)
+    if paired:
+        os_bytes, os_s = _paired_conv(B, -(-nb // 2), batch, L, plan_lib, hw)
+    else:
+        os_bytes, os_s = _rfft_conv(B, batch * nb, plan_lib, hw)
+        # Framing gather (read L, write nb·B) + tail scatter (read nb·step,
+        # write L), real float32.
+        framing = batch * (L + nb * B + nb * step + L) * f32
+        os_bytes += framing
+        os_s += framing / hw.hbm_bw
     osd = {
         "block": B,
         "num_blocks": nb,
         "valid_per_block": step,
         "max_plan_n": B,
+        "paired": paired,
+        "leaf_n": B if paired else B // 2,
         "hbm_bytes": os_bytes,
         "memory_s": os_bytes / hw.hbm_bw,
+        "modeled_s": os_s,
     }
     return {
         "L": L,

@@ -39,8 +39,9 @@ On top of the one-shot :func:`fft_conv_os`:
 * ``repro.core.conv.fft_conv`` auto-routes here whenever the one-shot
   padded length would leave the fused regime.
 
-``analysis.roofline.conv_report`` models the HBM traffic of both schedules
-so the win is observable, not just asserted.
+``analysis.roofline.conv_report`` models the HBM traffic and device time
+of both schedules so the win is observable, not just asserted; the block
+tuner ranks blocks by that time.
 """
 
 from __future__ import annotations

@@ -14,16 +14,19 @@ This module turns each of them into a searched decision:
    tile) for an :class:`~repro.core.fft.FFTSpec`;
 2. the roofline model prunes the space
    (:func:`repro.analysis.roofline.prune_candidates`): only candidates
-   within ~20% of the modeled-minimum HBM bytes, and whose per-grid-step
-   working set fits :data:`~repro.core.limits.VMEM_BUDGET`, survive;
+   within ~20% of the modeled-minimum cost (HBM bytes for plan configs,
+   device seconds for overlap-save blocks and pencil schedules), and whose
+   per-grid-step working set fits :data:`~repro.core.limits.VMEM_BUDGET`,
+   survive;
 3. ``tune="measure"`` times the survivors on device (min-of-reps,
    ``block_until_ready``) and records the winner in a **persistent JSON
    cache** keyed by ``(device_kind, backend, spec)`` — so the search runs
    once per device and shape, ever; ``tune="model"`` skips measurement and
    takes the modeled pick — the zero-measurement default, which keeps the
    fixed heuristic on modeled ties but DOES deviate when the model finds a
-   schedule with strictly fewer HBM bytes (e.g. swapping a direct leaf
-   whose n² DFT matrix dominates the stream for a fused four-step engine);
+   strictly cheaper schedule (e.g. swapping a direct leaf whose n² DFT
+   matrix dominates the stream for a fused four-step engine, or a smaller
+   overlap-save block whose leaves do less MXU work per point);
    ``tune="off"`` bypasses the tuner entirely and is the exact historical
    behavior.
 
@@ -72,9 +75,14 @@ TUNE_MODES = ("off", "model", "measure")
 #: with any other version is quarantined as foreign rather than guessed at.
 CACHE_SCHEMA_VERSION = 1
 
-#: Modeled-bytes tolerance of the roofline pruning: candidates more than
-#: 20% above the modeled-minimum HBM traffic are never worth measuring.
+#: Tolerance of the roofline pruning: candidates more than 20% above the
+#: modeled-minimum cost are never worth measuring.
 PRUNE_TOL = 0.2
+
+#: Version of the overlap-save block model, part of every ``os_block``
+#: cache key: a pick persisted under another model is not found, so it is
+#: never served in place of this model's pick.
+OS_BLOCK_MODEL = "t1"
 
 #: Timing discipline for the measurement pass.
 MEASURE_REPS = 5
@@ -326,7 +334,7 @@ def _time(fn, reps: int = MEASURE_REPS, warmup: int = MEASURE_WARMUP) -> float:
 class TuningSpace:
     """The candidate configs of ONE tunable decision.
 
-    ``candidates`` is an ordered list of ``(config, modeled_bytes,
+    ``candidates`` is an ordered list of ``(config, modeled_cost,
     vmem_bytes)`` triples — the fixed heuristic's pick FIRST, so modeled
     ties resolve to the historical behavior.  ``measure_fn(config)`` runs
     one on-device trial and returns seconds.
@@ -366,10 +374,15 @@ class TuningSpace:
         Candidates: every power of two from the fixed heuristic's floor
         (``2·next_pow2(Lh)`` — at least half of each block valid) up to
         :data:`~repro.core.limits.FUSED_MAX`, heuristic default first.
-        Modeled bytes come from :func:`repro.analysis.roofline.conv_report`
-        (framing redundancy + plan traffic per block), which is exactly the
-        trade the block size moves: small blocks re-transform more overlap,
-        large blocks pay bigger per-block programs.
+        Each candidate's cost is the modeled device seconds of
+        :func:`repro.analysis.roofline.conv_report` (``modeled_s``) for the
+        schedule that block runs, paired complex frames or one rfft frame a
+        signal: each leaf at the larger of its HBM and MXU time, the glue at
+        HBM bandwidth.  That is exactly the trade the block size moves:
+        small blocks re-transform more overlap, large blocks pay more MXU
+        work per point (the fused leaves' GEMMs contract over ``√block``).
+        The working set checked against the VMEM budget is that of the leaf
+        the schedule runs (``block`` paired, ``block / 2`` unpaired).
 
         ``chunk`` keys the decision to a *streaming call grain* (serving
         decode, strip ingest): the modeled signal becomes one chunked call
@@ -393,12 +406,12 @@ class TuningSpace:
         L_call = (chunk + Lh - 1) if chunk else L  # per-call signal length
         cands = []
         for blk in blocks:
-            modeled = rl.conv_report(L_call, Lh, batch=batch, block=blk)
-            leaf = plan_lib._leaf_pass(max(blk // 2, 1))
+            modeled = rl.conv_report(
+                L_call, Lh, batch=batch, block=blk, stream=bool(chunk)
+            )["overlap_save"]
+            leaf = plan_lib._leaf_pass(modeled["leaf_n"])
             vmem = plan_lib.vmem_bytes(leaf, plan_lib.pick_batch_tile(leaf))
-            cands.append(
-                ({"block": blk}, modeled["overlap_save"]["hbm_bytes"], vmem)
-            )
+            cands.append(({"block": blk}, modeled["modeled_s"], vmem))
 
         def measure(config):
             import jax
@@ -429,7 +442,10 @@ class TuningSpace:
             )
             return _time(lambda: fn(x, h))
 
-        key = f"{backend or 'auto'}|os_block|L={L},Lh={Lh},batch={batch}"
+        key = (
+            f"{backend or 'auto'}|os_block.{OS_BLOCK_MODEL}|"
+            f"L={L},Lh={Lh},batch={batch}"
+        )
         if chunk:
             key += f",chunk={chunk}"
         return cls("os_block", key, cands, measure)
@@ -703,11 +719,11 @@ class TuningSpace:
             default = self.candidates[0]
             if all(s is not default for s in survivors):
                 # The model may prune the fixed heuristic; measurement must
-                # still beat it on the clock, not just on modeled bytes.
+                # still beat it on the clock, not just on its modeled cost.
                 survivors = [default] + survivors
             times = [float("inf")] * len(survivors)
             for _round in range(MEASURE_ROUNDS):
-                for i, (config, _bytes, _vmem) in enumerate(survivors):
+                for i, (config, _cost, _vmem) in enumerate(survivors):
                     times[i] = min(times[i], self.measure_fn(config))
                     _MEASURE_LOG.append(
                         (self.decision, key, json.dumps(config, sort_keys=True))

@@ -316,7 +316,7 @@ def stream_plan_info(cfg, batch: int = 1) -> dict:
 
     lf = cfg.spectral_filter_len
     c, block = stream_grain(cfg)
-    report = rl.conv_report(lf - 1 + c, lf, batch=batch, block=block)
+    report = rl.conv_report(lf - 1 + c, lf, batch=batch, block=block, stream=True)
     return {
         "filter_len": lf,
         "chunk": c,

@@ -108,6 +108,92 @@ def test_tuned_block_model_is_deterministic_and_cached(fresh_cache):
     assert any("os_block" in k for k in entries)
 
 
+@pytest.mark.parametrize(
+    "n, contraction, ps_per_point",
+    [
+        (1024, 1024, 187.1),  # direct leaf: one stage of ⌈n⌉
+        (8192, 256, 46.8),  # lane group: 64 → 128, plus 128
+        (16384, 256, 46.8),
+        (32768, 384, 70.2),
+        (65536, 512, 93.6),
+    ],
+)
+def test_leaf_mxu_time_model(n, contraction, ps_per_point):
+    leaf = plan_lib._leaf_pass(n)
+    assert rl.leaf_contraction(leaf) == contraction
+    rows = 4096
+    mxu = rows * n * 3 * 2 * contraction * 6 / rl.V5E.peak_flops_bf16
+    assert rl.pass_mxu_s(leaf, rows) == pytest.approx(mxu)
+    assert rl.pass_mxu_s(leaf, rows) / (rows * n) * 1e12 == pytest.approx(
+        ps_per_point, abs=0.05
+    )
+    # every leaf here waits on the MXU, not on its HBM bytes
+    hbm = plan_lib.pass_hbm_bytes(leaf, rows) / rl.V5E.hbm_bw
+    assert hbm < mxu
+    assert rl.pass_s(leaf, rows) == pytest.approx(mxu)
+    assert rl.program_s(plan_lib.plan_fft(n).passes, rows) == pytest.approx(mxu)
+
+
+def test_os_block_model_ranks_blocks_by_modeled_seconds(fresh_cache):
+    L, Lh, batch = 2**19, 4097, 1024
+    space = tuning.TuningSpace.for_os_block(L, Lh, batch, None)
+    cost = {c[0]["block"]: c[1] for c in space.candidates}
+    assert sorted(cost, key=cost.get) == [16384, 32768, 65536]
+    for blk, seconds in cost.items():
+        rep = rl.conv_report(L, Lh, batch, block=blk)["overlap_save"]
+        assert seconds == rep["modeled_s"]
+        assert 0.01 < seconds < 1.0  # seconds a call, not bytes
+    assert tuning.tuned_block(L, Lh, batch, None, "model") == 16384
+    assert tuning.modeled_block(L, Lh, batch) == 16384
+    assert tuning.measure_log() == ()
+
+
+def test_os_block_prices_the_schedule_that_runs():
+    # many frames a signal: paired complex frames, leaves of n = block
+    L, Lh, batch = 2**19, 4097, 1024
+    for (cfg, seconds, vmem) in tuning.TuningSpace.for_os_block(L, Lh, batch, None).candidates:
+        blk = cfg["block"]
+        rep = rl.conv_report(L, Lh, batch, block=blk)["overlap_save"]
+        assert rep["paired"] and rep["leaf_n"] == blk
+        rows = batch * -(-rep["num_blocks"] // 2)
+        assert seconds > 2 * rl.program_s(plan_lib.plan_fft(blk).passes, rows)
+        leaf = plan_lib._leaf_pass(blk)
+        assert vmem == plan_lib.vmem_bytes(leaf, plan_lib.pick_batch_tile(leaf))
+    # a streaming chunk grain: one rfft frame where tail + chunk fit a block
+    chunk, Lh = 4096, 257
+    seen = set()
+    for (cfg, seconds, vmem) in tuning.TuningSpace.for_os_block(
+        chunk, Lh, 1, None, chunk=chunk
+    ).candidates:
+        blk = cfg["block"]
+        rep = rl.conv_report(chunk + Lh - 1, Lh, 1, block=blk, stream=True)["overlap_save"]
+        assert seconds == rep["modeled_s"]
+        one_frame = chunk + Lh - 1 <= blk
+        seen.add(one_frame)
+        assert rep["num_blocks"] == 1 if one_frame else rep["num_blocks"] >= 2
+        assert rep["paired"] is not one_frame
+        leaf = plan_lib._leaf_pass(blk // 2 if one_frame else blk)
+        assert rep["leaf_n"] == leaf.n
+        assert vmem == plan_lib.vmem_bytes(leaf, plan_lib.pick_batch_tile(leaf))
+    assert seen == {True, False}
+
+
+@pytest.mark.parametrize("old_mode", ["model", "measure"])
+def test_os_block_entry_of_the_older_model_is_not_served(fresh_cache, old_mode):
+    L, Lh, batch = 2**19, 4097, 1024
+    old = f"{tuning.device_key()}|auto|os_block|L={L},Lh={Lh},batch={batch}"
+    entry = {"config": {"block": 65536}, "mode": old_mode}
+    with open(fresh_cache, "w") as f:
+        json.dump({"version": tuning.CACHE_SCHEMA_VERSION, "entries": {old: entry}}, f)
+    assert tuning.cache.get(old) == entry
+    assert tuning.tuned_block(L, Lh, batch, None, "model") == 16384
+    entries = tuning.TuningCache()._load()
+    assert entries[old] == entry  # left where it was, never read
+    (new,) = [k for k in entries if k != old]
+    assert f"|os_block.{tuning.OS_BLOCK_MODEL}|" in new
+    assert entries[new] == {"config": {"block": 16384}, "mode": "model"}
+
+
 def test_measure_mode_caches_winner_zero_remeasure(fresh_cache, rng):
     L, Lh = 2**13, 129
     x = jnp.asarray(rng.standard_normal((1, L)), jnp.float32)
